@@ -18,6 +18,7 @@ from __future__ import annotations
 import json
 import os
 import time
+import warnings
 from typing import Any, Dict, Optional
 
 import jax
@@ -29,12 +30,11 @@ __all__ = ["enable_autotune", "disable_autotune", "autotune_status",
 
 
 def use_artifacts_cache(repo_root: str) -> str:
-    """Enable autotune against the repo's shared on-chip tile cache
-    (<root>/artifacts/autotune_tpu.json) — the one file bench_kernels.py
-    writes and bench.py consults — plus the shape-CLASS measured-defaults
-    table (measured_defaults.json, tools/seed_defaults.py). Returns the
-    cache path."""
-    import os
+    """Enable autotune against the checkout's on-chip tile cache
+    (<root>/artifacts/autotune_tpu.json, written by bench_kernels.py at
+    run time, git-ignored) plus the shape-CLASS measured-defaults table
+    (measured_defaults.json, tools/seed_defaults.py). Returns the cache
+    path."""
     path = os.path.join(repo_root, "artifacts", "autotune_tpu.json")
     enable_autotune()
     set_autotune_cache_file(path)
@@ -53,6 +53,11 @@ _CACHE_FILE: Optional[str] = None
 # (power-of-two seq buckets), finer than the hand heuristics.
 _DEFAULTS: Dict[str, str] = {}
 _STATS = {"hits": 0, "misses": 0, "measured": 0, "class_hits": 0}
+# cache key -> {impl: "ExcType: message"} for every candidate that raised
+# while being measured. A refused candidate (e.g. a tile too large for
+# VMEM) cannot win, but it is never dropped silently: a swallowed
+# AttributeError once disqualified EVERY Pallas candidate unnoticed.
+_FAILED: Dict[str, Dict[str, str]] = {}
 
 
 def shape_bucket(n: int) -> int:
@@ -130,7 +135,8 @@ def disable_autotune() -> None:
 def autotune_status() -> dict:
     """(parity: paddle.incubate.autotune status surface)"""
     return {"use_autotune": _flag_on(), "cache_size": len(_CACHE),
-            "defaults_size": len(_DEFAULTS), **_STATS}
+            "defaults_size": len(_DEFAULTS), **_STATS,
+            "failed": {k: dict(v) for k, v in _FAILED.items()}}
 
 
 def set_autotune_cache_file(path: Optional[str]) -> None:
@@ -148,6 +154,7 @@ def set_autotune_cache_file(path: Optional[str]) -> None:
 def clear_autotune_cache() -> None:
     _CACHE.clear()
     _DEFAULTS.clear()
+    _FAILED.clear()
     _STATS.update(hits=0, misses=0, measured=0, class_hits=0)
 
 
@@ -234,8 +241,13 @@ def pick_impl(name: str, impls: Dict[str, Any], arrays, call,
     for impl_name in impls:
         try:
             t, out = _measure(lambda *a: call(impl_name), arrays)
-        except Exception:
-            continue  # a candidate that crashes never wins
+        except Exception as e:  # noqa: BLE001 -- recorded and warned below
+            reason = f"{type(e).__name__}: {e}"[:300]
+            _FAILED.setdefault(k, {})[impl_name] = reason
+            warnings.warn(f"autotune: candidate {impl_name!r} of {name} "
+                          f"raised and cannot win: {reason}",
+                          RuntimeWarning, stacklevel=2)
+            continue
         _STATS["measured"] += 1
         if t < best_t:
             best_name, best_t, best_out = impl_name, t, out

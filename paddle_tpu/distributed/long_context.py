@@ -34,26 +34,18 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.8 top-level shard_map
-    from jax import shard_map as _shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        # check_vma=False: pallas_call outputs carry no varying-mesh-axes
-        # metadata, so the vma checker rejects any kernel launched inside
-        # the shard (both the ring chunk kernels and Ulysses' local flash)
-        return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                          out_specs=out_specs, check_vma=False)
-except ImportError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map as _old_shard_map
-
-    def shard_map(f, mesh, in_specs, out_specs):
-        return _old_shard_map(f, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-
 from ..core.dispatch import run_op
 
 __all__ = ["ring_attention", "ulysses_attention", "ring_attention_local",
            "ulysses_attention_local"]
+
+
+def shard_map(f, mesh, in_specs, out_specs):
+    # check_vma=False: pallas_call outputs carry no varying-mesh-axes
+    # metadata, so the vma checker rejects any kernel launched inside
+    # the shard (both the ring chunk kernels and Ulysses' local flash)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 _NEG_INF = float("-inf")
 
@@ -95,12 +87,7 @@ def _repeat_kv(k, hq):
 def _vary(xs, axis_name):
     """Mark replicated-constant scan carries device-varying over the mesh
     axis (required before they meet ppermute'd values in the carry)."""
-    if hasattr(jax.lax, "pcast"):
-        return tuple(jax.lax.pcast(x, (axis_name,), to="varying")
-                     for x in xs)
-    if hasattr(jax.lax, "pvary"):  # older jax
-        return tuple(jax.lax.pvary(x, (axis_name,)) for x in xs)
-    return tuple(xs)
+    return tuple(jax.lax.pcast(x, (axis_name,), to="varying") for x in xs)
 
 
 # ---------------------------------------------------------------------------

@@ -136,9 +136,9 @@ def create_multistep_train_step(model, optimizer, loss_fn=None,
                                 donate=False, steps=8, accumulate=1):
     """``steps`` optimizer steps inside ONE jitted program via
     ``lax.scan`` — the production-JAX training-loop shape: the host
-    dispatches once per K steps, so per-execute dispatch cost (remote
-    tunnels pay 30-50 ms; even local hosts pay ~0.1 ms × python loop
-    overhead) amortizes to dispatch/K and the device runs back-to-back.
+    dispatches once per K steps, so per-execute dispatch cost (python
+    loop overhead plus the runtime's launch latency) amortizes to
+    dispatch/K and the device runs back-to-back.
 
     Returns ``(step_K, params0, opt_state0)`` where
     ``step_K(params, opt_state, key, ids, labels, lr)`` takes stacked
@@ -279,9 +279,14 @@ def create_sharded_train_step(model, optimizer, mesh, param_spec_fn,
         return jax.device_put(arr, NamedSharding(mesh, spec))
 
     def sharded_step(params, opt_state, key, ids, labels, lr):
-        with mesh:
+        # the ambient mesh is how ops that must partition themselves
+        # (the Mosaic flash kernel: ops/pallas/flash_attention._per_shard)
+        # learn the axis names and sizes at trace time
+        with jax.set_mesh(mesh):
             return step(params, opt_state, key, ids, labels, lr)
 
+    # the jitted program itself, for lowering/inspection under the mesh
+    sharded_step.jitted = step
     return sharded_step, params, opt_state, shard_batch
 
 

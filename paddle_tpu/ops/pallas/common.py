@@ -8,16 +8,13 @@ Mosaic constraints handled here:
   (rows, 1) refs — rank-1 blocks that are neither full-dim nor a
   128-multiple are rejected on hardware;
 - interpret-mode selection lives in ONE place (:func:`pallas_interpret`)
-  so every kernel agrees on what "not on TPU" means (GL906), and the
-  ``compiler_params`` class-name drift across jax releases is absorbed
-  by :func:`mosaic_params`.
+  so every kernel agrees on what "not on TPU" means (GL906).
 """
 from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.pallas import tpu as pltpu
 
 _Z = np.int32(0)
 
@@ -31,20 +28,6 @@ def pallas_interpret() -> bool:
     """Whether pallas_call should run in interpret mode: the single
     source of truth every kernel's ``interpret=`` routes through."""
     return not on_tpu()
-
-
-# jax renamed the Mosaic params class (TPUCompilerParams in 0.4.x,
-# CompilerParams from 0.8): resolve whichever this jax provides once, at
-# import, so a kernel's compiler_params= can never AttributeError at
-# trace time (an AttributeError inside an autotune candidate is silently
-# swallowed by pick_impl and poisons every tiling measurement).
-_COMPILER_PARAMS_CLS = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
-
-def mosaic_params(**kwargs):
-    """Build the Mosaic ``compiler_params=`` value portably."""
-    return _COMPILER_PARAMS_CLS(**kwargs)
 
 
 def pad_rows(a, br):

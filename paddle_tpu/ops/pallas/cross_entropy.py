@@ -27,6 +27,10 @@ from .common import _Z, pad_rows, pallas_interpret
 __all__ = ["softmax_xent_pallas"]
 
 _ROW_BLOCK = 8
+# typed f32 zero: under jax_enable_x64 a bare Python float handed to
+# jnp.where enters the kernel jaxpr as an f64 scalar, and Mosaic has no
+# f64 -> f32 cast
+_F0 = np.float32(0.0)
 
 
 def _fwd_kernel(x_ref, lab_ref, loss_ref, lse_ref):
@@ -38,10 +42,10 @@ def _fwd_kernel(x_ref, lab_ref, loss_ref, lse_ref):
     m = jnp.max(x, axis=1, keepdims=True)                 # (br, 1)
     lse = m + jnp.log(jnp.sum(jnp.exp(x - m), axis=1, keepdims=True))
     cols = jax.lax.broadcasted_iota(jnp.int32, x.shape, 1)
-    picked = jnp.sum(jnp.where(cols == lab, x, 0.0), axis=1, keepdims=True)
+    picked = jnp.sum(jnp.where(cols == lab, x, _F0), axis=1, keepdims=True)
     # out-of-range label (e.g. ignore_index rows): loss 0 via picked=lse
     valid = (lab >= 0) & (lab < x.shape[1])
-    loss_ref[...] = jnp.where(valid, lse - picked, 0.0)
+    loss_ref[...] = jnp.where(valid, lse - picked, _F0)
     lse_ref[...] = lse
 
 

@@ -50,14 +50,19 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ...core import flags as _flags
 from ...core.dispatch import register_op_impl
-from .common import _Z, mosaic_params, pallas_interpret
+from .common import _Z, pallas_interpret
 
 
 __all__ = ["flash_attention_pallas", "flash_attention_ext",
            "flash_chunk_fwd", "flash_chunk_bwd",
            "dropout_keep_mask", "seed_from_key"]
 
-_NEG_INF = float("-inf")
+# typed f32 constants: under the package-wide jax_enable_x64 a bare Python
+# float handed to jnp.where/jnp.maximum enters the kernel jaxpr as an f64
+# scalar, and Mosaic has no f64 -> f32 cast ("Unsupported cast")
+_NEG_INF = np.float32("-inf")
+_F0 = np.float32(0.0)
+_F1 = np.float32(1.0)
 _LANES = 128
 
 
@@ -210,7 +215,7 @@ def _fwd_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, has_bias,
         m_new = jnp.maximum(m_prev, jnp.broadcast_to(s_max, m_prev.shape))
         # fully-masked-so-far rows keep m = -inf; use a safe exponent base so
         # exp() never sees (-inf) - (-inf)
-        m_safe = jnp.where(m_new == _NEG_INF, 0.0, m_new)
+        m_safe = jnp.where(m_new == _NEG_INF, _F0, m_new)
         alpha = jnp.exp(m_prev - m_safe)                         # (bq, LANES)
         p = jnp.exp(s - m_safe[:, :1])                           # (bq, bk)
         # l and lse come from the UNDROPPED probabilities (dropout applies
@@ -220,7 +225,7 @@ def _fwd_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, has_bias,
         if rate > 0.0:
             keep = _keep_block(_mix_seed(seed_ref[0], bh), q_start, k_start,
                                bq, bk, sk_real, _dropout_thresh(rate))
-            p_v = jnp.where(keep, p * np.float32(1.0 / (1.0 - rate)), 0.0)
+            p_v = jnp.where(keep, p * np.float32(1.0 / (1.0 - rate)), _F0)
         else:
             p_v = p
         v = v_ref[0]                                             # (bk, d)
@@ -233,14 +238,14 @@ def _fwd_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, has_bias,
     @pl.when(ki == nk - 1)
     def _fin():
         l = l_ref[:, :1]
-        safe_l = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0] = jnp.where(l > 0.0, acc_ref[...] / safe_l, 0.0
+        safe_l = jnp.where(l == 0.0, _F1, l)
+        o_ref[0] = jnp.where(l > 0.0, acc_ref[...] / safe_l, _F0
                              ).astype(o_ref.dtype)
         # lse rides as a (bq, 1) trailing-unit ref (Mosaic rejects (1, bq)
         # blocks whose sublane dim is neither full nor a multiple of 8)
         m = m_ref[:, :1]
         lse_ref[0] = jnp.where(l > 0.0,
-                               m + jnp.log(jnp.maximum(l, 1e-38)),
+                               m + jnp.log(jnp.maximum(l, np.float32(1e-38))),
                                _NEG_INF)
 
 
@@ -298,7 +303,7 @@ def _fwd(q3, k3, v3, bias3, seed, hq, hk, causal, scale, offset, sk_real,
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
-        compiler_params=mosaic_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)
@@ -421,7 +426,7 @@ def _dq_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, has_bias,
         v = v_ref[0]
         do = do_ref[0]
         lse = lse_ref[0]                                        # (bq, 1)
-        lse_safe = jnp.where(lse == _NEG_INF, 0.0, lse)
+        lse_safe = jnp.where(lse == _NEG_INF, _F0, lse)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if has_bias:
@@ -440,7 +445,7 @@ def _dq_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, has_bias,
         if rate > 0.0:
             keep = _keep_block(_mix_seed(seed_ref[0], bh), q_start, k_start,
                                bq, bk, sk_real, _dropout_thresh(rate))
-            dp = jnp.where(keep, dp * np.float32(1.0 / (1.0 - rate)), 0.0)
+            dp = jnp.where(keep, dp * np.float32(1.0 / (1.0 - rate)), _F0)
         ds = p * (dp - delta_ref[0])                            # (bq, bk)
         if emit_dbias:
             dbias_ref[0] = ds.astype(dbias_ref.dtype)
@@ -498,7 +503,7 @@ def _dkv_kernel(*refs, scale, causal, offset, bq, bk, nq, rep, sk_real,
         v = v_ref[0]
         do = do_ref[0]
         lse = lse_ref[0]                                        # (bq, 1)
-        lse_safe = jnp.where(lse == _NEG_INF, 0.0, lse)
+        lse_safe = jnp.where(lse == _NEG_INF, _F0, lse)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if has_bias:
@@ -516,7 +521,7 @@ def _dkv_kernel(*refs, scale, causal, offset, bq, bk, nq, rep, sk_real,
             keep = _keep_block(_mix_seed(seed_ref[0], bh), q_start, k_start,
                                bq, bk, sk_real, _dropout_thresh(rate))
             inv = np.float32(1.0 / (1.0 - rate))
-            p_v = jnp.where(keep, p * inv, 0.0)
+            p_v = jnp.where(keep, p * inv, _F0)
         else:
             p_v = p
         dv_acc[...] += jax.lax.dot_general(
@@ -525,7 +530,7 @@ def _dkv_kernel(*refs, scale, causal, offset, bq, bk, nq, rep, sk_real,
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if rate > 0.0:
-            dp = jnp.where(keep, dp * np.float32(1.0 / (1.0 - rate)), 0.0)
+            dp = jnp.where(keep, dp * np.float32(1.0 / (1.0 - rate)), _F0)
         ds = p * (dp - delta_ref[0])
         # s = scale * (q . k) with q unscaled on load, so dk = scale *
         # ds^T @ q carries the factor explicitly
@@ -610,7 +615,7 @@ def _bwd_impl(q3, kx, vx, do3, lse, delta, bias3, seed, causal, scale,
         out_specs=dq_out_specs if emit_dbias else dq_out_specs[0],
         out_shape=dq_out_shape if emit_dbias else dq_out_shape[0],
         scratch_shapes=scratch,
-        compiler_params=mosaic_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(*args)
@@ -679,7 +684,7 @@ def _bwd_impl(q3, kx, vx, do3, lse, delta, bias3, seed, causal, scale,
             jax.ShapeDtypeStruct((bhk, sk, d), q3.dtype),
         ],
         scratch_shapes=scratch2,
-        compiler_params=mosaic_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
@@ -728,14 +733,14 @@ def _dbias_broadcast(q3, kx, vx, do3, lse_p, delta, bias3, seed, maps,
             mask = mask & _seg_mask(qs, ks,
                                     maps.get("seg_causal", False))
         s = jnp.where(mask, s, _NEG_INF)
-        lse_safe = jnp.where(lse_b == _NEG_INF, 0.0, lse_b)
+        lse_safe = jnp.where(lse_b == _NEG_INF, _F0, lse_b)
         p = jnp.exp(s - lse_safe[:, None])
         dp = jnp.dot(dob.astype(jnp.float32), vb.astype(jnp.float32).T,
                      preferred_element_type=jnp.float32)
         if rate > 0.0:
             keep = _keep_block(_mix_seed(seed[0], bh), 0, 0, sq_pad, sk_pad,
                                sk_real, _dropout_thresh(rate))
-            dp = jnp.where(keep, dp * np.float32(1.0 / (1.0 - rate)), 0.0)
+            dp = jnp.where(keep, dp * np.float32(1.0 / (1.0 - rate)), _F0)
         ds = p * (dp - delta_b[:, None])
         red = ds[:bias3.shape[1]] if Sqb != 1 else \
             jnp.sum(ds, axis=0, keepdims=True)
@@ -1026,24 +1031,30 @@ def flash_chunk_bwd(q, k, v, do, lse, delta, causal, scale, block_q=128,
 def _attention_pallas(q, k, v, bias, causal, scale, dropout_p, dropout_key):
     """Pallas path for the training hot path, now including attention
     dropout and additive bias in-kernel (reference contract
-    paddle/phi/api/yaml/ops.yaml:978-989); falls back to the XLA reference
-    impl only for head_dim > 256, short sequences (XLA's fused attention
-    wins below ~2k kv length, measured on v5e), unsupported bias layouts,
+    paddle/phi/api/yaml/ops.yaml:978-989); routes to the XLA reference
+    impl only for head_dim > 256, short sequences, unsupported bias
+    layouts, bias or dropout under a device mesh (see ``_per_shard``),
     or CPU interpret mode."""
     from ...nn.functional.flash_attention import _attention_xla
     interpret = pallas_interpret()
     on_tpu = not interpret
-    # measured on v5e: XLA's fused attention wins below ~2k kv length
-    # (s=1024: 4.8ms vs 9.7ms fwd); the pallas streaming kernel wins once
-    # score materialization bites (s=4096: 14.9ms vs 18.4ms) — pick by
-    # shape, like the reference's kernel autotune cache
-    # (paddle/phi/kernels/autotune/)
+    # mesh axes GSPMD still owns at this point of the trace (inside a
+    # shard_map the manual axes are per-shard already)
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = {a: mesh.shape[a] for a in mesh.auto_axes}
+    meshed = any(n > 1 for n in auto.values())
+    # pick by shape, like the reference's kernel autotune cache
+    # (paddle/phi/kernels/autotune/): XLA's fused attention for short kv,
+    # the pallas streaming kernel once score materialization bites. Where
+    # the crossover (FLAGS_pallas_flash_min_seq) belongs is open — see the
+    # flag's help text (ROADMAP S5).
     min_seq = int(_flags.get_flag("pallas_flash_min_seq"))
     rate = float(dropout_p or 0.0)
     bias_ok = bias is None or bias_supported(
         bias, q.shape[0], q.shape[2], q.shape[1], k.shape[1])
     if (not bias_ok or q.shape[-1] > 256
             or (rate > 0.0 and dropout_key is None)
+            or (meshed and (bias is not None or rate > 0.0))
             or (on_tpu and k.shape[1] < min_seq)
             or (interpret and not _flags.get_flag("pallas_force_interpret"))):
         return _attention_xla(q, k, v, bias, causal, scale, dropout_p,
@@ -1060,9 +1071,46 @@ def _attention_pallas(q, k, v, bias, causal, scale, dropout_p, dropout_key):
         # backward beats the flash recompute backward (r3 capture 0.837)
         return _attention_xla(q, k, v, bias, causal, scale, dropout_p,
                               dropout_key)
-    return flash_attention_ext(q, k, v, bias, seed, None, None,
-                               bool(causal), float(scale), rate, bq, bk,
-                               interpret)
+    def kernel(q_, k_, v_):
+        return flash_attention_ext(q_, k_, v_, bias, seed, None, None,
+                                   bool(causal), float(scale), rate, bq, bk,
+                                   interpret)
+    if meshed:
+        kernel = _per_shard(kernel, auto, q, k)
+    return kernel(q, k, v)
+
+
+def _per_shard(kernel, auto, q, k):
+    """``kernel(q, k, v)`` as a shard_map over ``auto``, the {name: size}
+    of the ambient mesh's GSPMD-owned axes (``jax.set_mesh``, entered by
+    models.trainer's sharded step). GSPMD refuses a Mosaic call whose
+    operands are sharded ("Mosaic kernels cannot be automatically
+    partitioned. Please wrap the call in a shard_map"), and attention is
+    independent across batch and heads, so each device runs the kernel on
+    its own [B/dp, S, H/tp, D] block: batch over the data axis, heads over
+    the tensor-parallel axis (SpecLayout vocabulary). An axis the mesh
+    lacks, or that does not divide the dim, is left out of the spec — that
+    dim is then computed whole on every device of the axis. Bias-free,
+    dropout-free calls only: the in-kernel dropout hash and the bias index
+    maps are written against global (batch*head) indices."""
+    from jax.sharding import PartitionSpec
+
+    from ...distributed.spec_layout import default_layout
+    layout = default_layout()
+
+    def axis(name, *dims):
+        n = auto.get(name, 1)
+        return name if n > 1 and all(d % n == 0 for d in dims) else None
+
+    spec = PartitionSpec(axis(layout.data_axis, q.shape[0]), None,
+                         axis(layout.tp_axis, q.shape[2], k.shape[2]), None)
+    # every GSPMD-owned axis goes manual, named in the spec or not: one
+    # left automatic would still face the partitioner with a Mosaic call.
+    # check_vma=False: pallas_call outputs carry no varying-mesh-axes
+    # metadata (same as distributed/long_context.py)
+    return jax.shard_map(kernel, in_specs=(spec, spec, spec),
+                         out_specs=spec, axis_names=frozenset(auto),
+                         check_vma=False)
 
 
 # candidate (block_q, block_k) tilings; 128x128 is the safe default, the

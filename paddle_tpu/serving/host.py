@@ -129,6 +129,7 @@ def main(argv=None) -> int:
     backend_id = args.backend_id or f"host{os.getpid()}"
 
     # heavyweight imports AFTER arg parsing so --help stays instant
+    from paddle_tpu.core.compile_cache import enable_compile_cache
     from paddle_tpu.profiler import tracing
     from paddle_tpu.serving import Server, decode
     from paddle_tpu.serving.transport import BackendServer
@@ -142,6 +143,8 @@ def main(argv=None) -> int:
         tracing.start_trace_writer(
             os.path.join(trace_dir, f"{backend_id}.trace.json"))
 
+    # warmup compiles every bucket's executable: keep them across restarts
+    enable_compile_cache()
     model = _build_model(args)
     if args.checkpoint:
         from paddle_tpu.distributed.resilience import load_for_serving

@@ -126,18 +126,3 @@ class TestCli:
     def test_empty_report_exits_two(self, tmp_path):
         rep = _write(tmp_path / "r.json", _report({}))
         assert main([rep]) == 2
-
-
-class TestSeededArtifact:
-    def test_repo_bests_match_r05_report(self):
-        """The committed seed must agree with the committed bench report
-        (clean rows only) — guards accidental hand-edits of either."""
-        with open("artifacts/bench_report_full.json") as f:
-            report = json.load(f)
-        measured = report_ratios(report)
-        best = load_best("artifacts/kernel_ratios_best.json")
-        assert best, "seed artifact missing or empty"
-        for key, ratio in measured.items():
-            assert best[key] == pytest.approx(ratio, abs=5e-4), key
-        regs, _, _ = check(measured, best, tolerance=0.15)
-        assert regs == []

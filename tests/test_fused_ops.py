@@ -503,6 +503,34 @@ class TestAutotuneCache:
             autotune.disable_autotune()
             autotune.clear_autotune_cache()
 
+    def test_raising_candidate_is_recorded_and_warned(self):
+        """A candidate that raises cannot win, but it is never dropped
+        silently: the reason lands in autotune_status()["failed"] and a
+        RuntimeWarning names it."""
+        import jax.numpy as jnp
+
+        from paddle_tpu.core import autotune
+
+        def call(name):
+            if name == "broken":
+                raise AttributeError("no such compiler params class")
+            return jnp.ones((4,))
+
+        autotune.clear_autotune_cache()
+        autotune.enable_autotune()
+        try:
+            with pytest.warns(RuntimeWarning, match="'broken' of demo_op"):
+                choice, out = autotune.pick_impl(
+                    "demo_op", {"broken": None, "good": None},
+                    (jnp.ones((4,)),), call)
+            assert choice == "good" and out is not None
+            failed = autotune.autotune_status()["failed"]
+            assert list(failed.values()) == [
+                {"broken": "AttributeError: no such compiler params class"}]
+        finally:
+            autotune.disable_autotune()
+            autotune.clear_autotune_cache()
+
     def test_tile_key_is_batch_agnostic(self):
         """flash-attn TILE keys ignore batch (the tile optimum is
         (seq, heads, head-dim)-determined), so a b1-tuned entry serves

@@ -80,18 +80,7 @@ def test_kernel_modules_route_interpret_through_common():
         assert "pallas_interpret" in src
 
 
-# -- compiler-params version shim (the tile-key test breaker) ----------------
-
-def test_mosaic_params_constructs_on_this_jax():
-    """jax 0.4.x ships pltpu.TPUCompilerParams, newer jax renames it to
-    CompilerParams; mosaic_params() must resolve whichever exists instead
-    of raising AttributeError (which autotune's candidate loop used to
-    swallow, silently disqualifying every pallas candidate)."""
-    from paddle_tpu.ops.pallas.common import mosaic_params
-    p = mosaic_params(
-        dimension_semantics=("parallel", "arbitrary", "arbitrary"))
-    assert p is not None
-
+# -- Mosaic compiler params at every flash pallas_call site -----------------
 
 def test_flash_fwd_and_bwd_build_compiler_params():
     """End-to-end regression for the CompilerParams crash: all three

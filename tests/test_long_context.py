@@ -82,6 +82,23 @@ def test_ulysses_matches_local(causal):
                                rtol=2e-5, atol=2e-5)
 
 
+def test_ulysses_runs_the_flash_kernel_inside_its_own_shard_map():
+    """With the kernel route on (the chip's route), Ulysses' local
+    attention is already per-shard: the dispatch must see that the mesh
+    axes are manual there and not wrap the kernel in a second shard_map
+    (it does that only for axes GSPMD still owns)."""
+    q, k, v = _mk(1, 64, 4, 16, seed=4)
+    scale = 1.0 / math.sqrt(16)
+    ref = _attention_xla(q, k, v, None, True, scale, 0.0, None)
+    paddle.set_flags({"pallas_force_interpret": True})
+    try:
+        out = dist.ulysses_attention(q, k, v, mesh=_mesh(), causal=True)
+    finally:
+        paddle.set_flags({"pallas_force_interpret": False})
+    np.testing.assert_allclose(np.asarray(out.numpy()), np.asarray(ref),
+                               rtol=2e-5, atol=2e-5)
+
+
 def test_ulysses_gqa_expand():
     # 2 kv heads < 4 devices: GQA expansion before the head swap
     q, k, v = _mk(1, 64, 8, 16, hk=2, seed=5)

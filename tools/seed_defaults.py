@@ -8,7 +8,8 @@ entries (power-of-two seq/row buckets — the same classifier the call
 sites in ops/pallas/{flash_attention,cross_entropy,norms}.py compute) and
 writes ``artifacts/measured_defaults.json``; ``use_artifacts_cache``
 loads it, and a traced cold-cache call takes the class winner before the
-heuristic. Run after each fresh capture (tools/tpu_watch.py does).
+heuristic. Run after bench_kernels.py has tuned on the chip (both files
+are run-time products, git-ignored).
 
 Reference discipline: paddle/phi/kernels/autotune/ caches with serialized
 defaults so later processes skip measurement.
