@@ -26,8 +26,9 @@ __all__ = ["run_op", "OP_REGISTRY", "register_op_impl",
            "set_op_profile_hook"]
 
 # host-tracer hook (parity: the RecordEvent emitted by every generated op
-# fn, eager_gen.py:1802). None when no profiler is recording — one global
-# read of cost on the hot path.
+# fn, eager_gen.py:1802): ``hook(name)`` returns the span the op runs
+# under. None when no profiler is recording — one global read of cost on
+# the hot path.
 _op_profile_hook = None
 
 
@@ -115,13 +116,9 @@ def run_op(
     attributes, forwarded to its SPMD rule (the ops.yaml attr pack analog).
     """
     if _op_profile_hook is not None:
-        import time as _time
-        _t0 = _time.perf_counter()
-        try:
+        with _op_profile_hook(name):
             return _run_op_impl(name, jax_fn, operands, num_nondiff_outputs,
                                 out_stop_gradient, attrs)
-        finally:
-            _op_profile_hook(name, _t0, _time.perf_counter())
     return _run_op_impl(name, jax_fn, operands, num_nondiff_outputs,
                         out_stop_gradient, attrs)
 

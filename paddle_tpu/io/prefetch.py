@@ -44,14 +44,16 @@ class PipelineMetrics(MetricsBase):
     queue — input-bound), device_blocked_s (consumer waited inside a
     lagged ``device_get`` — compute-bound; fed by ``run_steps``),
     producer_blocked_s (producer waited on a full queue — healthy
-    backpressure), producer_busy_s (pull + stack + transfer work).
+    backpressure), producer_busy_s (pull + stack + transfer work),
+    dispatch_s (the consumer's own time inside the call of the step: key
+    fold, schedule, program launches; fed by ``run_steps``).
     """
 
     COUNTERS = ("batches_in", "batches_out", "stacks",
                 "producer_exceptions")
     HISTS = ("transfer_ms", "queue_depth")
     TIMES = ("host_blocked_s", "device_blocked_s", "producer_blocked_s",
-             "producer_busy_s")
+             "producer_busy_s", "dispatch_s")
 
     def snapshot(self) -> dict:
         with self._lock:

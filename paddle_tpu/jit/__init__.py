@@ -171,16 +171,9 @@ class StaticFunction:
                      for k, v in state.items()}
         key0 = jax.random.key(0)
         key_sds = jax.ShapeDtypeStruct(key0.shape, key0.dtype)
-        # compile watcher: every AOT compile is a counted, traceable
-        # event — "zero new compiles in steady state" becomes a live
-        # observable, not a test-only assertion
-        from ..profiler import tracing
-        target = self._fn if self._fn is not None else self._layer
-        label = getattr(target, "__name__", type(target).__name__)
-        tracing.record_compile(label)
-        with tracing.trace_span("jit::compile", cat="jit", fn=label,
-                                arity=len(sds)):
-            return self._build().lower(state_sds, key_sds, *sds).compile()
+        # the compile is counted and traced where every compile in the
+        # process is: profiler.tracing's jax.monitoring listener
+        return self._build().lower(state_sds, key_sds, *sds).compile()
 
     def cache_size(self) -> int:
         """Number of signatures traced by the live jit cache."""

@@ -121,8 +121,11 @@ def create_train_step(model, optimizer, loss_fn=None, donate=False):
     def train_step(params, opt_state, key, ids, labels, lr):
         loss, grads = jax.value_and_grad(
             lambda p: _loss_call(p, ids, labels, key))(params)
-        new_params, new_opt_state = optimizer.apply_gradients(
-            params, grads, opt_state, lr, wd_mask=wd_mask)
+        # with value_and_grad's jvp()/transpose(jvp()) this makes forward,
+        # backward and optimizer three disjoint prefixes of every op's name
+        with jax.named_scope("optimizer"):
+            new_params, new_opt_state = optimizer.apply_gradients(
+                params, grads, opt_state, lr, wd_mask=wd_mask)
         return loss, new_params, new_opt_state
 
     train_step = jax.jit(train_step,
@@ -202,8 +205,9 @@ def create_multistep_train_step(model, optimizer, loss_fn=None,
                 grads = jax.tree_util.tree_map(
                     lambda g: g / accumulate, gsum)
                 loss = lsum / accumulate
-            p, s = optimizer.apply_gradients(p, grads, s, lr,
-                                             wd_mask=wd_mask)
+            with jax.named_scope("optimizer"):
+                p, s = optimizer.apply_gradients(p, grads, s, lr,
+                                                 wd_mask=wd_mask)
             return (p, s), loss
         n = ids.shape[0]
         (params, opt_state), losses = jax.lax.scan(
@@ -360,7 +364,8 @@ def run_steps(step, params, opt_state, feed, *, key=None, lr=1e-3,
     Wait-time accounting lands in ``profiler.pipeline_stats()``: time
     blocked on ``feed`` counts as host_blocked (input-bound), time
     blocked inside the lagged ``device_get`` as device_blocked
-    (compute-bound). When ``feed`` is a ``DevicePrefetcher`` its own
+    (compute-bound), and the host's own time inside the call of ``step``
+    (key fold, schedule, dispatch) as dispatch_s. When ``feed`` is a ``DevicePrefetcher`` its own
     metrics object is reused (one snapshot answers for the whole
     pipeline); otherwise a fresh source named ``name`` (default
     ``"run_steps"``) is registered for the duration of the run.
@@ -435,6 +440,7 @@ def run_steps(step, params, opt_state, feed, *, key=None, lr=1e-3,
                 try:
                     batch = next(it)
                 except StopIteration:
+                    feed_span.drop()
                     break
                 feed_span.end()
                 if owns_metrics:
@@ -442,11 +448,15 @@ def run_steps(step, params, opt_state, feed, *, key=None, lr=1e-3,
                                      time.perf_counter() - t0)
                     metrics.inc("batches_out")
                 ids, labels = batch
-                with tracing.trace_span("train::dispatch", cat="train",
-                                        step=i):
+                # dispatch_s: the host's own work a step (the key fold,
+                # the schedule, flattening the trees, the launches),
+                # always on like host_blocked_s and device_blocked_s
+                t0 = time.perf_counter()
+                with tracing.trace_step("train::dispatch", i, cat="train"):
                     loss, params, opt_state = step(
                         params, opt_state, jax.random.fold_in(key, i),
                         ids, labels, lr_fn(i))
+                metrics.add_time("dispatch_s", time.perf_counter() - t0)
                 if checkpoint_manager is not None:
                     checkpoint_manager.maybe_save(
                         i, {"params": params, "opt_state": opt_state,

@@ -21,6 +21,13 @@ from ...core.dispatch import run_op, select_impl, register_op_impl
 __all__ = ["flash_attention", "scaled_dot_product_attention",
            "flash_attn_unpadded", "sdp_kernel"]
 
+# The name attention carries on the device, whatever implements it (Pallas
+# or XLA): entered INSIDE the function handed to ``run_op``, because the tape
+# may trace that function later, outside any scope of the caller. A trace's
+# reader finds attention by this name (benchmarks/metrics/
+# attn_device_ms_per_step.py), so it is part of the yardstick.
+ATTENTION_SCOPE = "attention"
+
 
 @register_op_impl("flash_attention", "xla")
 def _attention_xla(q, k, v, bias, causal, scale, dropout_p, dropout_key):
@@ -85,7 +92,9 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
     impl = select_impl("flash_attention")
 
     def fn(q, k, v):
-        return impl(q, k, v, None, causal, scale, dropout if training else 0.0, dk)
+        with jax.named_scope(ATTENTION_SCOPE):
+            return impl(q, k, v, None, causal, scale,
+                        dropout if training else 0.0, dk)
     out = run_op("flash_attention", fn, (query, key, value))
     return out, None
 
@@ -160,24 +169,25 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
         return same
 
     def fn(q, k, v):
-        q4, k4, v4 = q[None], k[None], v[None]
-        if use_kernel:
-            seed = (seed_from_key(dk) if rate > 0.0
-                    else jnp.zeros((1,), jnp.int32))
-            out4 = flash_attention_ext(q4, k4, v4, None, seed, seg_q,
-                                       seg_k, bool(causal), float(scale),
-                                       rate, 128, 128, not on_tpu)
-        else:
-            vis = _visibility()
-            bias = jnp.where(vis, 0.0, float("-inf"))[None, None]
-            out4 = _attention_xla(q4, k4, v4, bias, False, float(scale),
-                                  rate, dk)
-            # a q row with no visible key softmaxes -inf into NaN: zero it
-            # (the kernel path's l==0 handling) so packing don't-cares
-            # never poison real gradients
-            dead = ~jnp.any(vis, axis=-1)                  # (Tq,)
-            out4 = jnp.where(dead[None, :, None, None], 0.0, out4)
-        return out4[0]
+        with jax.named_scope(ATTENTION_SCOPE):
+            q4, k4, v4 = q[None], k[None], v[None]
+            if use_kernel:
+                seed = (seed_from_key(dk) if rate > 0.0
+                        else jnp.zeros((1,), jnp.int32))
+                out4 = flash_attention_ext(q4, k4, v4, None, seed, seg_q,
+                                           seg_k, bool(causal), float(scale),
+                                           rate, 128, 128, not on_tpu)
+            else:
+                vis = _visibility()
+                bias = jnp.where(vis, 0.0, float("-inf"))[None, None]
+                out4 = _attention_xla(q4, k4, v4, bias, False, float(scale),
+                                      rate, dk)
+                # a q row with no visible key softmaxes -inf into NaN: zero it
+                # (the kernel path's l==0 handling) so packing don't-cares
+                # never poison real gradients
+                dead = ~jnp.any(vis, axis=-1)                  # (Tq,)
+                out4 = jnp.where(dead[None, :, None, None], 0.0, out4)
+            return out4[0]
 
     out = run_op("flash_attention", fn, (query, key, value))
     return out, None
@@ -194,13 +204,15 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
     impl = select_impl("flash_attention")
     if attn_mask is not None:
         def fn(q, k, v, m):
-            return impl(q, k, v, m, is_causal, scale,
-                        dropout_p if training else 0.0, dk)
+            with jax.named_scope(ATTENTION_SCOPE):
+                return impl(q, k, v, m, is_causal, scale,
+                            dropout_p if training else 0.0, dk)
         return run_op("flash_attention", fn, (query, key, value, attn_mask))
 
     def fn(q, k, v):
-        return impl(q, k, v, None, is_causal, scale,
-                    dropout_p if training else 0.0, dk)
+        with jax.named_scope(ATTENTION_SCOPE):
+            return impl(q, k, v, None, is_causal, scale,
+                        dropout_p if training else 0.0, dk)
     return run_op("flash_attention", fn, (query, key, value))
 
 

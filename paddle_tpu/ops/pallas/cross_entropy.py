@@ -94,6 +94,7 @@ def _fwd(logits, labels, interpret):
         out_shape=[jax.ShapeDtypeStruct((rp, 1), jnp.float32),
                    jax.ShapeDtypeStruct((rp, 1), jnp.float32)],
         interpret=interpret,
+        name="softmax_ce_fwd",
     )(xp, lp)
     return loss[:r, 0], (logits, labels, lse[:r, 0])
 
@@ -133,6 +134,7 @@ def _bwd_rule(interpret, bwd, res, g):
         out_specs=pl.BlockSpec((br, v), lambda i: (i, _Z)),
         out_shape=jax.ShapeDtypeStruct((rp, v), logits.dtype),
         interpret=interpret,
+        name="softmax_ce_bwd",
     )(xp, lp, lsep, gp)
     return dx[:r], None
 

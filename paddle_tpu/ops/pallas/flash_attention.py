@@ -306,6 +306,7 @@ def _fwd(q3, k3, v3, bias3, seed, hq, hk, causal, scale, offset, sk_real,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_fwd",
     )(*args)
     return out, lse[..., 0]
 
@@ -618,6 +619,7 @@ def _bwd_impl(q3, kx, vx, do3, lse, delta, bias3, seed, causal, scale,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dq",
     )(*args)
     if emit_dbias:
         dq, dbias_blocks = dq_outs
@@ -688,6 +690,7 @@ def _bwd_impl(q3, kx, vx, do3, lse, delta, bias3, seed, causal, scale,
             dimension_semantics=("parallel", "parallel", "arbitrary",
                                  "arbitrary")),
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(*kq_args)
     return dq, dk, dv, dbias_blocks
 

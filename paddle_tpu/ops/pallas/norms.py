@@ -100,6 +100,7 @@ def _rms_fwd(x, w, eps, interpret):
             jax.ShapeDtypeStruct((rp, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="rms_norm_fwd",
     )(x2p, w.reshape(1, n))
     out = y[:r].reshape(x.shape)
     return out, (x, w, inv[:r, 0])
@@ -202,6 +203,7 @@ def _ln_fwd(x, w, b, eps, interpret):
             jax.ShapeDtypeStruct((rp, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="layer_norm_fwd",
     )(x2p, w.reshape(1, n), b.reshape(1, n))
     out = y[:r].reshape(x.shape)
     return out, (x, w, b, mu[:r, 0], rstd[:r, 0])

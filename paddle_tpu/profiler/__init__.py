@@ -3,11 +3,15 @@ Profiler:346, make_scheduler:117, export_chrome_tracing:215, RecordEvent;
 statistics tables in profiler_statistic.py).
 
 TPU-native design: the device side delegates to jax.profiler (XPlane —
-TensorBoard-consumable traces of XLA executions); the host side is a
-RecordEvent tracer fed by (a) user-annotated scopes and (b) every
+TensorBoard-consumable traces of XLA executions); the host side is
+``tracing``'s one span call: a recording Profiler is a sink of every
+``trace_span``/``trace_event``/``RecordEvent`` in the program and of every
 ``run_op`` dispatch via the core hook (the reference emits RecordEvent
-from every generated op function). The schedule(wait/warmup/active) state
-machine and chrome-trace export keep the reference API.
+from every generated op function). The same spans enter a
+``jax.profiler.TraceAnnotation``, so with a non-CPU target they sit in the
+``.xplane.pb`` beside the device's ops, on one clock. The
+schedule(wait/warmup/active) state machine and chrome-trace export keep the
+reference API.
 """
 from __future__ import annotations
 
@@ -22,8 +26,9 @@ import zlib
 from enum import Enum
 from typing import Callable, Iterable, List, Optional
 
-from .tracing import (TraceContext, trace_span, trace_event, new_trace_id,
-                      current_trace_id, enable_tracing, disable_tracing,
+from . import tracing as _tracing
+from .tracing import (TraceContext, trace_span, trace_step, trace_event,
+                      new_trace_id, current_trace_id, enable_tracing, disable_tracing,
                       tracing_enabled, snapshot_events, export_trace,
                       start_trace_writer, stop_trace_writer,
                       set_clock_offset, set_trace_metadata, record_compile,
@@ -43,8 +48,8 @@ __all__ = ["ProfilerState", "ProfilerTarget", "make_scheduler",
            "register_transport_source", "unregister_transport_source",
            "export_stats",
            # flight-recorder tracing (profiler.tracing re-exports)
-           "TraceContext", "trace_span", "trace_event", "new_trace_id",
-           "current_trace_id", "enable_tracing", "disable_tracing",
+           "TraceContext", "trace_span", "trace_step", "trace_event",
+           "new_trace_id", "current_trace_id", "enable_tracing", "disable_tracing",
            "tracing_enabled", "snapshot_events", "export_trace",
            "start_trace_writer", "stop_trace_writer", "set_clock_offset",
            "set_trace_metadata", "record_compile", "compile_count",
@@ -97,30 +102,49 @@ def _default_state_scheduler(step: int) -> ProfilerState:
 
 
 class _HostEvent:
-    __slots__ = ("name", "start", "end", "tid", "category")
+    """One record of ``tracing`` (a span, an op, an instant) as a
+    recording ``Profiler`` keeps it: ``start``/``end`` in seconds on the
+    wall clock, like the flight recorder's ring."""
 
-    def __init__(self, name, start, end, tid, category="op"):
-        self.name = name
-        self.start = start
-        self.end = end
+    __slots__ = ("rec", "tid")
+
+    def __init__(self, rec, tid):
+        self.rec = rec
         self.tid = tid
-        self.category = category
+
+    @property
+    def name(self):
+        return self.rec[0]
+
+    @property
+    def category(self):
+        return self.rec[1]
+
+    @property
+    def start(self):
+        return self.rec[3]
+
+    @property
+    def end(self):
+        return self.rec[3] + (self.rec[4] or 0.0)
 
 
 class _HostTracer:
-    """Collects host events; enabled only while a Profiler is RECORD-ing."""
+    """Collects host events; ``tracing``'s sink while a Profiler is
+    RECORD-ing."""
 
     def __init__(self):
         self.events: List[_HostEvent] = []
         self._lock = threading.Lock()
 
-    def add(self, name, t0, t1, category="op"):
-        ev = _HostEvent(name, t0, t1, threading.get_ident(), category)
+    def add(self, rec, tid):
+        ev = _HostEvent(rec, tid)
         with self._lock:
             self.events.append(ev)
 
 
-_current: Optional["Profiler"] = None
+def _op_span(name: str):
+    return trace_span(name, cat="op")
 
 
 class RecordEvent:
@@ -128,24 +152,24 @@ class RecordEvent:
 
         with profiler.RecordEvent("data_loading"):
             ...
+
+    A thin form of ``trace_span``: the scope lands in a recording
+    ``Profiler``, in the flight recorder's ring when tracing is enabled,
+    and in the profiler's ``.xplane.pb`` when a device trace runs.
     """
 
     def __init__(self, name: str, event_type: str = "UserDefined"):
         self.name = name
         self.event_type = event_type
-        self._t0 = None
+        self._span = None
 
     def begin(self):
-        self._t0 = time.perf_counter()
+        self._span = trace_span(self.name, cat=self.event_type)
 
     def end(self):
-        if self._t0 is None:
-            return
-        prof = _current
-        if prof is not None and prof._tracer is not None:
-            prof._tracer.add(self.name, self._t0, time.perf_counter(),
-                             "user")
-        self._t0 = None
+        span, self._span = self._span, None
+        if span is not None:
+            span.end()
 
     def __enter__(self):
         self.begin()
@@ -208,20 +232,15 @@ class Profiler:
 
     # -- lifecycle ---------------------------------------------------------
     def start(self):
-        global _current
-        _current = self
         self.current_state = self.scheduler(self.step_num)
         self._transition(ProfilerState.CLOSED, self.current_state)
         self._step_t0 = time.perf_counter()
         return self
 
     def stop(self):
-        global _current
         self._transition(self.current_state, ProfilerState.CLOSED,
                          final=True)
         self.current_state = ProfilerState.CLOSED
-        if _current is self:
-            _current = None
 
     def __enter__(self):
         return self.start()
@@ -257,8 +276,9 @@ class Profiler:
     def _begin_record(self):
         from ..core import dispatch as _dispatch
         self._tracer = _HostTracer()
+        _tracing.set_profiler_sink(self._tracer.add)
         if not self.timer_only:
-            _dispatch.set_op_profile_hook(self._tracer.add)
+            _dispatch.set_op_profile_hook(_op_span)
             self._maybe_device_trace(True)
 
     def _end_record(self):
@@ -266,10 +286,10 @@ class Profiler:
         if self._tracer is None:
             return
         _dispatch.set_op_profile_hook(None)
+        _tracing.set_profiler_sink(None)
         self._maybe_device_trace(False)
         self._all_events.extend(self._tracer.events)
-        tracer, self._tracer = self._tracer, None
-        del tracer
+        self._tracer = None
         if self.on_trace_ready is not None:
             self.on_trace_ready(self)
 
@@ -293,15 +313,10 @@ class Profiler:
 
     # -- results -----------------------------------------------------------
     def _export_chrome(self, path: str):
-        events = []
-        for ev in self._all_events or (self._tracer.events
-                                       if self._tracer else []):
-            events.append({
-                "name": ev.name, "ph": "X", "pid": os.getpid(),
-                "tid": ev.tid, "ts": ev.start * 1e6,
-                "dur": (ev.end - ev.start) * 1e6,
-                "cat": ev.category,
-            })
+        pid = os.getpid()
+        events = [_tracing.chrome_event(ev.rec, pid, ev.tid)
+                  for ev in self._all_events or (self._tracer.events
+                                                 if self._tracer else [])]
         with open(path, "w") as f:
             json.dump({"traceEvents": events,
                        "displayTimeUnit": "ms"}, f)

@@ -3,12 +3,21 @@ decode step, cheap enough to leave compiled in everywhere.
 
 Design (the three properties everything below serves):
 
-1. **Always compiled in, near-zero when disabled.** Every call site in
-   the serving/training hot path goes through ``trace_span(...)`` /
-   ``trace_event(...)`` unconditionally; when tracing is disabled those
-   are one global load + branch (``trace_span`` returns a shared no-op
-   singleton, ``trace_event`` returns immediately). There is no
-   decorator magic and no monkey-patching — the call sites are the
+1. **One span call, three sinks.** Every call site in the serving/
+   training hot path goes through ``trace_span(...)`` /
+   ``trace_event(...)`` unconditionally (``profiler.RecordEvent`` is a
+   thin form of the same span). Each span enters a
+   ``jax.profiler.TraceAnnotation``: inert without a profiler session,
+   and with one (``jax.profiler.start_trace``) the span lands in the
+   ``/host:CPU`` plane of the same ``.xplane.pb`` as the device's "XLA
+   Ops", on the profiler's clock, with its attributes as stats. The
+   other two sinks are the flight-recorder ring below (written only when
+   tracing is enabled) and a recording ``Profiler``'s host tracer. With
+   neither on, a span is one annotation enter/exit and one branch. (The
+   annotation renders attribute values with ``str``; one that holds
+   ``,`` or ``#`` cuts the stats after it short in the xplane, while the
+   ring keeps it whole.) There
+   is no decorator magic and no monkey-patching — the call sites are the
    documentation of the span taxonomy.
 
 2. **Flight recorder, not a start/stop profiler.** Enabled tracing
@@ -47,9 +56,13 @@ import json
 import os
 import threading
 import time
-from typing import Optional
+from typing import Callable, Optional
 
-__all__ = ["TraceContext", "trace_span", "trace_event", "new_trace_id",
+import jax
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
+
+__all__ = ["TraceContext", "trace_span", "trace_step", "trace_event",
+           "new_trace_id",
            "current_trace_id", "enable_tracing", "disable_tracing",
            "tracing_enabled", "snapshot_events", "export_trace",
            "start_trace_writer", "stop_trace_writer", "set_clock_offset",
@@ -58,8 +71,12 @@ __all__ = ["TraceContext", "trace_span", "trace_event", "new_trace_id",
 
 DEFAULT_RING_SIZE = 4096
 
-# the one flag the disabled hot path reads: module global, plain bool
+# _enabled: the ring is on. _sink: a recording Profiler's host tracer,
+# ``sink(rec, tid)``. _recording is the one flag the hot path reads:
+# either of the two wants the record (module global, plain bool)
 _enabled = False
+_sink: Optional[Callable] = None
+_recording = False
 _ring_size = DEFAULT_RING_SIZE
 
 # per-thread rings: each thread writes only its own ring (no writer
@@ -76,8 +93,9 @@ _meta_lock = threading.Lock()
 _metadata: dict = {}
 _clock_offsets: dict = {}
 
-# compile watcher: StaticFunction.compile_for reports here, making
-# "zero new compiles in steady state" a live observable
+# compile watcher: every compile request jax sends to the backend reports
+# here (``_on_jax_duration``), making "zero new compiles in steady state"
+# a live observable
 _compile_lock = threading.Lock()
 _compile_count = 0
 
@@ -173,17 +191,34 @@ def current_trace_id() -> Optional[str]:
 # record tuple: (name, cat, ph, ts, dur, trace_id, attrs)
 #   ph "X" = complete span (dur in seconds), "i" = instant (dur None)
 
+def _record(rec: tuple) -> None:
+    if _enabled:
+        _ring().push(rec)
+    sink = _sink
+    if sink is not None:
+        sink(rec, threading.get_ident())
+
+
 class _Span:
-    """Active span handle; records on ``__exit__``/``end``."""
+    """Active span handle. The profiler annotation is entered at
+    construction (the span's clock starts there, so a handle held across
+    statements measures them) and left by ``end``/``__exit__``, which
+    record, or by ``drop``, which does not."""
 
-    __slots__ = ("name", "cat", "trace_id", "attrs", "_t0")
+    __slots__ = ("name", "cat", "trace_id", "attrs", "_t0", "_ann")
 
-    def __init__(self, name, cat, trace_id, attrs):
+    def __init__(self, name, cat, trace_id, attrs, ann):
         self.name = name
         self.cat = cat
         self.trace_id = trace_id
         self.attrs = attrs
-        self._t0 = time.time()
+        self._ann = ann
+        ann.__enter__()
+        self._t0 = None
+        if _recording:
+            self._t0 = time.time()
+            if trace_id is None:
+                self.trace_id = getattr(_tls, "trace_id", None)
 
     def __enter__(self):
         return self
@@ -192,56 +227,60 @@ class _Span:
         self.end()
         return False
 
+    def drop(self) -> None:
+        """Close without recording: for a handle whose interval turned
+        out not to be one (the feed's StopIteration, a failover that
+        never re-admits). Leaves the profiler annotation balanced."""
+        ann = self._ann
+        if ann is not None:
+            self._ann = None
+            ann.__exit__(None, None, None)
+
     def end(self) -> None:
-        t0 = self._t0
-        if t0 is None:
+        ann = self._ann
+        if ann is None:
             return
-        self._t0 = None
-        _ring().push((self.name, self.cat, "X", t0, time.time() - t0,
-                      self.trace_id, self.attrs))
-
-
-class _NullSpan:
-    """Shared disabled-mode span: no state, no recording."""
-
-    __slots__ = ()
-
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-    def end(self) -> None:
-        pass
-
-
-_NULL_SPAN = _NullSpan()
+        self._ann = None
+        ann.__exit__(None, None, None)
+        t0 = self._t0
+        if t0 is not None:
+            _record((self.name, self.cat, "X", t0, time.time() - t0,
+                     self.trace_id, self.attrs or None))
 
 
 def trace_span(name: str, cat: str = "app", trace_id: Optional[str] = None,
                **attrs):
-    """Span context manager. Disabled: returns the shared no-op
-    singleton (one branch, zero allocation). Enabled: records a
-    complete ("X") event into the calling thread's ring on exit.
-    ``trace_id`` defaults to the thread's ``TraceContext``."""
-    if not _enabled:
-        return _NULL_SPAN
-    return _Span(name, cat,
-                 trace_id if trace_id is not None
-                 else getattr(_tls, "trace_id", None),
-                 attrs or None)
+    """Span, usable as a ``with`` block or as a handle closed by
+    ``.end()`` (records) or ``.drop()`` (does not). Always enters a
+    ``jax.profiler.TraceAnnotation(name, **attrs)``; records a complete
+    ("X") event into the calling thread's ring when tracing is enabled
+    and into a recording ``Profiler``. ``trace_id`` defaults to the
+    thread's ``TraceContext``."""
+    return _Span(name, cat, trace_id, attrs, TraceAnnotation(name, **attrs))
+
+
+def trace_step(name: str, step: int, cat: str = "app", **attrs):
+    """``trace_span`` for the loop's one span a step that dispatches the
+    device's work (``train::dispatch``, ``decode::step``): a
+    ``StepTraceAnnotation``, so that the profiler's own step markers
+    carry the program's step number. Recorded with ``step`` as an
+    attribute like any other."""
+    attrs["step"] = step
+    return _Span(name, cat, None, attrs,
+                 StepTraceAnnotation(name, step_num=step, **attrs))
 
 
 def trace_event(name: str, cat: str = "app",
                 trace_id: Optional[str] = None, **attrs) -> None:
-    """Instant event (chrome ph "i"). Disabled: immediate return."""
-    if not _enabled:
-        return
-    _ring().push((name, cat, "i", time.time(), None,
-                  trace_id if trace_id is not None
-                  else getattr(_tls, "trace_id", None),
-                  attrs or None))
+    """Instant event (chrome ph "i"): a zero-length profiler annotation,
+    and a record where ``trace_span`` would make one."""
+    with TraceAnnotation(name, **attrs):
+        pass
+    if _recording:
+        _record((name, cat, "i", time.time(), None,
+                 trace_id if trace_id is not None
+                 else getattr(_tls, "trace_id", None),
+                 attrs or None))
 
 
 # -- enable / disable --------------------------------------------------------
@@ -250,18 +289,28 @@ def enable_tracing(ring_size: Optional[int] = None) -> None:
     """Turn the flight recorder on. ``ring_size`` (events per thread)
     applies to rings created after this call; live rings keep their
     capacity."""
-    global _enabled, _ring_size
+    global _enabled, _recording, _ring_size
     if ring_size is not None:
         if ring_size < 1:
             raise ValueError(f"ring_size must be >= 1, got {ring_size}")
         _ring_size = int(ring_size)
-    _enabled = True
+    _enabled = _recording = True
 
 
 def disable_tracing() -> None:
     """Turn the flight recorder off. Recorded events stay readable."""
-    global _enabled
+    global _enabled, _recording
     _enabled = False
+    _recording = _sink is not None
+
+
+def set_profiler_sink(sink: Optional[Callable]) -> None:
+    """``sink(rec, tid)`` receives every record while a ``Profiler``
+    records (``rec`` is the ring's tuple, ``tid`` the recording thread);
+    None detaches it."""
+    global _sink, _recording
+    _sink = sink
+    _recording = _enabled or sink is not None
 
 
 def tracing_enabled() -> bool:
@@ -310,48 +359,74 @@ def clock_offsets() -> dict:
 
 # -- compile watcher ---------------------------------------------------------
 
-def record_compile(name: str) -> None:
-    """Called by ``StaticFunction.compile_for`` on every XLA compile:
-    bumps the live counter and drops an instant event, so "zero new
-    compiles in steady state" is observable from the trace itself."""
+_BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
+
+
+def record_compile(name: Optional[str], duration_s: float = 0.0) -> None:
+    """One compile request to the backend: bumps the live counter and
+    records a ``jit::compile`` span that ends now, so "zero new compiles
+    in steady state" is observable from the trace itself, and a compile
+    that does happen comes with the name of what compiled."""
     global _compile_count
     with _compile_lock:
         _compile_count += 1
-    trace_event("jit::compile", cat="jit", fn=name)
+    attrs = {"fn": name} if name else {}
+    with TraceAnnotation("jit::compile", duration_s=duration_s, **attrs):
+        pass
+    if _recording:
+        _record(("jit::compile", "jit", "X", time.time() - duration_s,
+                 duration_s, getattr(_tls, "trace_id", None),
+                 attrs or None))
+
+
+def _on_jax_duration(event: str, duration_secs: float, **kw) -> None:
+    # every jit in the process reports here, persistent-cache hit or not:
+    # the same event benchmarks/harness/watch.py counts. jax 0.9.0 passes
+    # the function's name as ``fun_name``
+    if event == _BACKEND_COMPILE:
+        record_compile(kw.get("fun_name"), duration_secs)
+
+
+jax.monitoring.register_event_duration_secs_listener(_on_jax_duration)
 
 
 def compile_count() -> int:
-    """XLA compiles recorded since process start (or reset)."""
+    """Compile requests recorded since process start (or reset)."""
     with _compile_lock:
         return _compile_count
 
 
 # -- export ------------------------------------------------------------------
 
+def chrome_event(rec: tuple, pid: int, tid: int) -> dict:
+    """One record as a chrome://tracing dict (ts/dur in µs, wall clock):
+    the one events-to-chrome function, shared by ``export_trace`` and
+    ``Profiler.export``."""
+    name, cat, ph, ts, dur, trace_id, attrs = rec
+    ev = {"name": name, "cat": cat, "ph": ph, "pid": pid, "tid": tid,
+          "ts": ts * 1e6}
+    if ph == "X":
+        ev["dur"] = dur * 1e6
+    else:
+        ev["s"] = "t"
+    args = {}
+    if trace_id is not None:
+        args["trace_id"] = trace_id
+    if attrs:
+        args.update(attrs)
+    if args:
+        ev["args"] = args
+    return ev
+
+
 def snapshot_events() -> list:
-    """Every recorded event as chrome://tracing dicts (ts/dur in µs,
-    wall-clock based). Does not disturb writers."""
+    """Every recorded event as chrome://tracing dicts. Does not disturb
+    writers."""
     with _registry_lock:
         rings = list(_rings)
     pid = os.getpid()
-    out = []
-    for ring in rings:
-        for rec in ring.snapshot():
-            name, cat, ph, ts, dur, trace_id, attrs = rec
-            ev = {"name": name, "cat": cat, "ph": ph, "pid": pid,
-                  "tid": ring.ident, "ts": ts * 1e6}
-            if ph == "X":
-                ev["dur"] = dur * 1e6
-            else:
-                ev["s"] = "t"
-            args = {}
-            if trace_id is not None:
-                args["trace_id"] = trace_id
-            if attrs:
-                args.update(attrs)
-            if args:
-                ev["args"] = args
-            out.append(ev)
+    out = [chrome_event(rec, pid, ring.ident)
+           for ring in rings for rec in ring.snapshot()]
     out.sort(key=lambda e: e["ts"])
     return out
 

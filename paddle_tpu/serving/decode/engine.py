@@ -121,14 +121,13 @@ class _StepExecutor:
         or arrays); True when this call compiled it."""
         import jax
 
-        from ...profiler import RecordEvent
         sds = [s if isinstance(s, jax.ShapeDtypeStruct)
                else jax.ShapeDtypeStruct(s.shape, s.dtype) for s in specs]
         key = self._sig(sds)
         with self._lock:
             if key in self._compiled:
                 return False
-            with RecordEvent("decode::compile", "Serving"):
+            with tracing.trace_span("decode::compile", cat="decode"):
                 self._compiled[key] = self._sf.compile_for(*sds)
             self._metrics.inc("compile_count")
             return True
@@ -137,12 +136,11 @@ class _StepExecutor:
         import jax
 
         from ...core import random as _random
-        from ...profiler import RecordEvent
         key = self._sig(arrays)
         with self._lock:
             compiled = self._compiled.get(key)
             if compiled is None:
-                with RecordEvent("decode::compile", "Serving"):
+                with tracing.trace_span("decode::compile", cat="decode"):
                     compiled = self._sf.compile_for(
                         *[jax.ShapeDtypeStruct(a.shape, a.dtype)
                           for a in arrays])
@@ -249,6 +247,7 @@ class DecodeServer(ServerLifecycleMixin):
                 f"({pages_per_seq} pages x {self.page_len})")
 
         self._metrics = DecodeMetrics(self.name)
+        self._step_no = 0       # decode::step's number on the trace
         self._pools = [a for pair in init_paged_cache(
             self._meta["num_layers"], num_pages, self.page_len,
             self._meta["num_kv_heads"], self._meta["head_dim"],
@@ -629,8 +628,9 @@ class DecodeServer(ServerLifecycleMixin):
         if not active:
             return
         t0 = time.monotonic()
-        step_span = tracing.trace_span("decode::step", cat="decode",
-                                       batch=len(active))
+        step_span = tracing.trace_step("decode::step", self._step_no,
+                                       cat="decode", batch=len(active))
+        self._step_no += 1
         bb, pb = self._sched.decode_shape()
         tokens = np.zeros((bb, 1), np.int32)
         positions = np.zeros((bb,), np.int32)

@@ -4,8 +4,9 @@ Every Server owns a ServingMetrics; the snapshot is retrievable through
 ``paddle_tpu.profiler.serving_stats()`` (the profiler is the framework's
 one observability surface — reference parity: the predictor's
 memory/latency stats also surface through the profiler tables). Batch
-executions additionally emit host RecordEvents when a Profiler is
-recording, so serving work shows up in chrome traces next to op events.
+executions are ``trace_span``s (``serving::execute``), which a recording
+Profiler receives, so serving work shows up in chrome traces next to op
+events.
 
 The thread-safe scaffolding (Histogram, counters/gauge plumbing) lives
 in ``paddle_tpu.profiler.metrics``, shared with the input-pipeline

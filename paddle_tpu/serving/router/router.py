@@ -70,7 +70,7 @@ class _RouterRequest:
 
     __slots__ = ("kind", "args", "key", "prompt", "max_new_tokens",
                  "eos_id", "deadline", "future", "stream", "t_submit",
-                 "settled", "trace_id")
+                 "settled", "trace_id", "fo_span")
 
     def __init__(self, kind: str, key: tuple, deadline: Optional[float]):
         self.kind = kind
@@ -85,6 +85,11 @@ class _RouterRequest:
         self.t_submit = time.monotonic()
         self.settled = False
         self.trace_id = None
+        # open while a decode failover is in progress: starts at the
+        # mid-stream death, ends at the successful re-admission
+        # elsewhere — the merged timeline shows the failover GAP as one
+        # explicit span. One that never re-admits is dropped unrecorded.
+        self.fo_span = None
 
     def expired(self, now: Optional[float] = None) -> bool:
         return (self.deadline is not None
@@ -540,6 +545,10 @@ class Router(ServerLifecycleMixin):
                     rr.settle_exc(
                         ServingError(f"router dispatch failed: {e!r}"))
                     self._metrics.inc("failed")
+            finally:
+                if rr.fo_span is not None:
+                    rr.fo_span.drop()
+                    rr.fo_span = None
 
     # -- placement ---------------------------------------------------------
     def _pick_backend(self, key: tuple,
@@ -846,10 +855,6 @@ class Router(ServerLifecycleMixin):
         last_exc = None
         overload_only = True
         waiting_since = None
-        # open while a failover is in progress: starts at the mid-stream
-        # death, ends at the successful re-admission elsewhere — the
-        # merged timeline shows the failover GAP as one explicit span
-        fo_span = None
         while True:
             if self._abort:
                 rr.settle_exc(ServerClosed("router aborted"))
@@ -926,9 +931,9 @@ class Router(ServerLifecycleMixin):
                                           attempt)
                     return
                 continue
-            if fo_span is not None:     # re-admitted: failover complete
-                fo_span.end()
-                fo_span = None
+            if rr.fo_span is not None:  # re-admitted: failover complete
+                rr.fo_span.end()
+                rr.fo_span = None
             with tracing.trace_span("router::relay", cat="router",
                                     trace_id=rr.trace_id,
                                     backend=entry.backend.backend_id):
@@ -980,7 +985,7 @@ class Router(ServerLifecycleMixin):
             self._metrics.inc("failovers")
             self._metrics.inc("decode_failovers")
             self._metrics.inc("tokens_resumed", rr.stream.token_count())
-            fo_span = tracing.trace_span(
+            rr.fo_span = tracing.trace_span(
                 "router::failover", cat="router", trace_id=rr.trace_id,
                 from_backend=entry.backend.backend_id,
                 tokens_resumed=rr.stream.token_count())

@@ -83,7 +83,6 @@ class _AotExecutor:
         import jax
 
         from ..core import random as _random
-        from ..profiler import RecordEvent
 
         key = tuple((a.shape, str(a.dtype)) for a in stacked)
         # The lock intentionally covers compile AND execute, not just the
@@ -96,7 +95,7 @@ class _AotExecutor:
         with self._lock:
             compiled = self._cache.get(key)
             if compiled is None:
-                with RecordEvent("serving::compile", "Serving"):
+                with tracing.trace_span("serving::compile", cat="serving"):
                     compiled = self._sf.compile_for(
                         *[jax.ShapeDtypeStruct(a.shape, a.dtype)
                           for a in stacked])
@@ -432,8 +431,6 @@ class Server(ServerLifecycleMixin):
                         self._metrics.inc("failed")
 
     def _execute(self, batch: List[Request]):
-        from ..profiler import RecordEvent
-
         n = len(batch)
         # invariant: n <= max_batch_size <= max bucket; a violation is a
         # bug and raises BucketOverflow loudly (the old silent
@@ -453,9 +450,8 @@ class Server(ServerLifecycleMixin):
             real += real_i
             padded += int(arr.size)
         try:
-            with RecordEvent(f"serving::execute[b{bb}]", "Serving"), \
-                    tracing.trace_span("serving::execute", cat="serving",
-                                       batch=n, bucket=bb):
+            with tracing.trace_span("serving::execute", cat="serving",
+                                    batch=n, bucket=bb):
                 outs = self._executor.run(stacked)
         except Exception as e:  # noqa: BLE001 — fail the batch, not the server
             for r in batch:

@@ -168,13 +168,18 @@ class TestFlightRecorder:
         tracing.reset_tracing()
         tracing.disable_tracing()
 
-    def test_disabled_mode_is_a_shared_noop(self):
+    def test_disabled_mode_records_nothing(self):
+        """Disabled, a span is its profiler annotation and one branch:
+        no clock read, nothing in the ring, usable as ``with`` or
+        handle."""
         from paddle_tpu.profiler import tracing
         s1 = tracing.trace_span("x")
         s2 = tracing.trace_span("y", cat="z", k=1)
-        assert s1 is s2                     # shared singleton, no alloc
+        assert s1._t0 is None and s2._t0 is None
         with s1:
             tracing.trace_event("e", k=2)
+        s2.end()
+        assert s1._ann is None and s2._ann is None   # annotations left
         assert tracing.snapshot_events() == []
 
     def test_span_and_event_record_with_context_trace_id(self):

@@ -74,7 +74,8 @@ HOT_ROOT_NAMES = {"run_steps", "_run_loop", "_execute", "_produce",
                   # points, the per-thread ring accessor, the ring
                   # store, and the span close (the background flusher's
                   # _write_loop is already a root above)
-                  "trace_span", "trace_event", "_ring", "push", "end"}
+                  "trace_span", "trace_step", "trace_event", "_record",
+                  "_ring", "push", "end", "drop"}
 
 # callables whose result is a jitted function / whose first unpacked
 # element is one — shared by device-placement and recompile-hazard so a
