@@ -32,6 +32,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import gc
 import json
 import sys
@@ -73,8 +74,14 @@ FOUR_CHIP_LOSS_RTOL = 1e-2
 # per-device bytes in use must agree within this share of the largest
 MEMORY_BALANCE_TOL = 0.10
 
+# the XLA twin keeps B*H*S*S float32 scores and probabilities for its
+# backward; over this many bytes of scores it goes through the batch a row
+# at a time
+XLA_TWIN_SCORE_BYTES = 2 << 30
+
 # -- presets ----------------------------------------------------------------
-# flash cases: (name, batch, seq, q heads, kv heads, head_dim, dropout)
+# flash cases: (name, batch, seq, q heads, kv heads, head_dim, dropout),
+# bf16 throughout; an eighth entry gives q and k another dtype
 
 CHIP = {
     "kernels": {
@@ -83,6 +90,12 @@ CHIP = {
             ("fa_gpt2_b8_s1024_h12_d64_dropout", 8, 1024, 12, 12, 64, 0.1),
             ("fa_llama_mha_b2_s2048_h32_d128", 2, 2048, 32, 32, 128, 0.0),
             ("fa_llama_gqa_b1_s4096_h32kv8_d128", 1, 4096, 32, 8, 128, 0.0),
+            # the benchmark cells' own attention shapes (BENCHMARK.json):
+            # gpt2s-train-s1024, and mistral7b-l2-train-s4096, whose q and
+            # k arrive float32 from RoPE's float32 tables
+            ("fa_cell_gpt2s_b32_s1024_h12_d64", 32, 1024, 12, 12, 64, 0.0),
+            ("fa_cell_mistral_b4_s4096_h32kv8_d128_f32qk", 4, 4096, 32, 8,
+             128, 0.0, "float32"),
         ],
         "norm_rows": 8192, "norm_cols": (768, 4096),
         "ce": [(8192, 50304), (8192, 32000)],
@@ -202,22 +215,31 @@ def kernel_cases(p, interpret):
     rng = np.random.RandomState(SEED)
     bf16 = jnp.bfloat16
 
-    for name, b, s, hq, hk, d, rate in p["flash"]:
-        q = jnp.asarray(rng.randn(b, s, hq, d) * 0.5, bf16)
-        k = jnp.asarray(rng.randn(b, s, hk, d) * 0.5, bf16)
+    for name, b, s, hq, hk, d, rate, *qk_dtype in p["flash"]:
+        qk_dtype = jnp.dtype(qk_dtype[0]) if qk_dtype else bf16
+        q = jnp.asarray(rng.randn(b, s, hq, d) * 0.5, qk_dtype)
+        k = jnp.asarray(rng.randn(b, s, hk, d) * 0.5, qk_dtype)
         v = jnp.asarray(rng.randn(b, s, hk, d) * 0.5, bf16)
         scale = float(d) ** -0.5
         key = jax.random.key(SEED)
         seed = seed_from_key(key)
+
+        # the XLA twin draws the same (seed, position)-hashed mask
+        def twin(q, k, v, _r=rate, _s=scale, _key=key):
+            return _attention_xla(q, k, v, None, True, _s, _r,
+                                  _key if _r > 0.0 else None)
+
+        if rate == 0.0 and b * hq * s * s * 4 > XLA_TWIN_SCORE_BYTES:
+            # attention is independent across the batch: the twin takes
+            # one row at a time, so its S x S scores fit beside the kernel
+            twin = functools.partial(_by_batch_row, twin)
         yield (name,
+               # no blocks given: the tiles are the kernels' own plan, as
+               # on the training path
                lambda q, k, v, _r=rate, _s=scale, _seed=seed:
                flash_attention_ext(q, k, v, None, _seed, None, None, True,
-                                   _s, _r, 128, 128, interpret),
-               # the XLA twin draws the same (seed, position)-hashed mask
-               lambda q, k, v, _r=rate, _s=scale, _key=key:
-               _attention_xla(q, k, v, None, True, _s, _r,
-                              _key if _r > 0.0 else None),
-               (q, k, v), 3)
+                                   _s, _r, None, None, interpret),
+               twin, (q, k, v), 3)
 
     rows = p["norm_rows"]
     for n in p["norm_cols"]:
@@ -240,6 +262,12 @@ def kernel_cases(p, interpret):
                    lambda lg, lb, _b=bwd: softmax_xent_pallas(
                        lg, lb, interpret, _b),
                    _softmax_xent_core_xla, (logits, labels), 1)
+
+
+def _by_batch_row(fn, q, k, v):
+    import jax
+    return jax.lax.map(lambda r: fn(r[0][None], r[1][None], r[2][None])[0],
+                       (q, k, v))
 
 
 def fwd_and_vjp(fn, n_diff):

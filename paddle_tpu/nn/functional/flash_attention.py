@@ -176,7 +176,7 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
                         else jnp.zeros((1,), jnp.int32))
                 out4 = flash_attention_ext(q4, k4, v4, None, seed, seg_q,
                                            seg_k, bool(causal), float(scale),
-                                           rate, 128, 128, not on_tpu)
+                                           rate, None, None, not on_tpu)
             else:
                 vis = _visibility()
                 bias = jnp.where(vis, 0.0, float("-inf"))[None, None]

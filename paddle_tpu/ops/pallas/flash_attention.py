@@ -12,10 +12,21 @@ pure function of (seed, batch*head, query index, key index), replayed
 bit-exactly by the backward kernels instead of being stored.
 
 Design (online-softmax, Dao et al. 2022, re-derived for the MXU):
+- tiles: ``tile_plan`` chooses each kernel's (block_q, block_k) from what a
+  call can see at trace time (lengths, head dim, item sizes, whether bias,
+  segment ids or dropout ride along, a VMEM budget): the largest tile that
+  fits, up to 1024 x 1024, since a grid step costs about half a microsecond
+  with nothing in it and 128 x 128 blocks spent the kernels' time there
+  (PERF.md, "PR 26"). A call whose estimate passes Mosaic's 16 MiB of
+  scoped VMEM asks for what it needs (``_call_params``). Explicit block
+  arguments and a warm autotune cache win over the plan. Every lowered call
+  leaves a ``flash::tile_plan`` trace event and a count in
+  ``TILE_PLAN_TALLY``.
 - forward: grid (batch*heads, q_blocks, k_blocks) with the k dimension
   innermost/sequential ("arbitrary"); VMEM scratch carries the running
   (acc, m, l) across k blocks; causal blocks above the diagonal are skipped
-  with pl.when.
+  with pl.when, and their index maps are clamped at the diagonal, so a
+  skipped step asks for the block it already holds and moves no data.
 - backward: one kernel for dq (+ dbias when bias is given), one for dk/dv
   (grid (batch*kv_heads, k_blocks, group_heads, q_blocks) — the last two
   dims sweep the kv head's q-head group with affine index maps);
@@ -38,8 +49,10 @@ Design (online-softmax, Dao et al. 2022, re-derived for the MXU):
 """
 from __future__ import annotations
 
+import collections
 import functools
 import math
+from typing import NamedTuple, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -64,6 +77,12 @@ _NEG_INF = np.float32("-inf")
 _F0 = np.float32(0.0)
 _F1 = np.float32(1.0)
 _LANES = 128
+# the names the device trace finds the kernels by (benchmarks/metrics)
+_KERNEL_NAMES = {"fwd": "flash_fwd", "dq": "flash_bwd_dq",
+                 "dkv": "flash_bwd_dkv"}
+# one count per lowered pallas_call, by (kernel name, bq, bk): trace time
+# only, nothing per step
+TILE_PLAN_TALLY: collections.Counter = collections.Counter()
 
 
 def _kv_index(bh, hq, hk):
@@ -160,8 +179,39 @@ def dropout_keep_mask(seed, bh_total, sq, sk, rate):
 # forward
 # ---------------------------------------------------------------------------
 
-def _fwd_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, has_bias,
-                has_seg, seg_causal, rate):
+def _hide(s, q_start, k_start, *, pad_keys, causal, offset, sk_real,
+          qseg_ref, kseg_ref, seg_causal):
+    """The (bq, bk) score block at (q_start, k_start) with what attention
+    may not see set to -inf: padded key columns, what lies above the
+    (Sk - Sq)-offset causal diagonal, other segments. A term is built only
+    where it can hide something."""
+    mask = None
+    if pad_keys or causal:
+        kidx = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    if pad_keys:
+        mask = kidx < sk_real
+    if causal:
+        qidx = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+        diag = kidx <= qidx + offset
+        mask = diag if mask is None else mask & diag
+    if qseg_ref is not None:  # varlen packing: never across sequences
+        seg = _seg_mask(qseg_ref[0], kseg_ref[0], seg_causal)
+        mask = seg if mask is None else mask & seg
+    return s if mask is None else jnp.where(mask, s, _NEG_INF)
+
+
+def _when_visible(body, q_start, k_start, bq, *, causal, offset):
+    """Run ``body`` unless the score block at (q_start, k_start) lies wholly
+    above the causal diagonal: its first key column beyond the horizon of
+    its last query row."""
+    run = True
+    if causal:
+        run = k_start <= q_start + bq - 1 + offset
+    pl.when(run)(body)
+
+
+def _fwd_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, pad_keys,
+                has_bias, has_seg, seg_causal, rate):
     scale = np.float32(scale)  # strong f64 scalars poison Mosaic under x64
     it = iter(refs)
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
@@ -184,13 +234,6 @@ def _fwd_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, has_bias,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # causal: the whole block is masked iff its first key column is beyond
-    # the last query row's horizon
-    run = True
-    if causal:
-        run = k_start <= q_start + bq - 1 + offset
-
-    @pl.when(run)
     def _body():
         # inputs stay in storage dtype (bf16 on the training path): the MXU
         # multiplies bf16 natively at 2x f32 rate, accumulating f32 via
@@ -201,14 +244,9 @@ def _fwd_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, has_bias,
                                 preferred_element_type=jnp.float32) * scale
         if has_bias:
             s = s + bias_ref[0].astype(jnp.float32)
-        kidx = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = kidx < sk_real                                    # pad keys off
-        if causal:
-            qidx = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            mask = mask & (kidx <= qidx + offset)
-        if has_seg:  # varlen packing: attention never crosses sequences
-            mask = mask & _seg_mask(qseg_ref[0], kseg_ref[0], seg_causal)
-        s = jnp.where(mask, s, _NEG_INF)
+        s = _hide(s, q_start, k_start, pad_keys=pad_keys, causal=causal,
+                  offset=offset, sk_real=sk_real, qseg_ref=qseg_ref,
+                  kseg_ref=kseg_ref, seg_causal=seg_causal)
 
         m_prev = m_ref[...]                                      # (bq, LANES)
         s_max = jnp.max(s, axis=1, keepdims=True)                # (bq, 1)
@@ -235,6 +273,8 @@ def _fwd_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, has_bias,
             p_v.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
+    _when_visible(_body, q_start, k_start, bq, causal=causal, offset=offset)
+
     @pl.when(ki == nk - 1)
     def _fin():
         l = l_ref[:, :1]
@@ -247,6 +287,64 @@ def _fwd_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, has_bias,
         lse_ref[0] = jnp.where(l > 0.0,
                                m + jnp.log(jnp.maximum(l, np.float32(1e-38))),
                                _NEG_INF)
+
+
+def _key_block(causal, bq, bk, offset, nk):
+    """``kblk(qi, ki)``: the key block step (qi, ki) of the fwd/dq grid
+    asks for. A causally skipped step asks for the last block its query
+    block sees, which it already holds, so it moves no data. All i32: index
+    maps lower through Mosaic."""
+    if not causal:
+        return lambda qi, ki: ki
+
+    def kblk(qi, ki):
+        last = jnp.maximum(qi * np.int32(bq) + np.int32(bq - 1 + offset),
+                           np.int32(0)) // np.int32(bk)
+        return jnp.minimum(ki, jnp.minimum(last, np.int32(nk - 1)))
+    return kblk
+
+
+def _query_block(causal, bq, bk, offset, nq):
+    """``qblk(ki, qi)``: the query block step (ki, qi) of the dkv grid asks
+    for. The steps before a key block's first visible query block ask for
+    that block already, so they move no data and the sweep starts loaded."""
+    if not causal:
+        return lambda ki, qi: qi
+
+    def qblk(ki, qi):
+        first = jnp.maximum(ki * np.int32(bk) - np.int32(offset),
+                            np.int32(0)) // np.int32(bq)
+        return jnp.maximum(qi, jnp.minimum(first, np.int32(nq - 1)))
+    return qblk
+
+
+def _call_params(kernel, semantics, q3, kx, vx, bias3, dbias, has_seg, rate,
+                 bq, bk, offset, causal):
+    """``CompilerParams`` of one lowered call of ``kernel`` ("fwd", "dq",
+    "dkv"), which it also records: a ``flash::tile_plan`` trace event and a
+    count in ``TILE_PLAN_TALLY``. Mosaic's scoped VMEM default is 16 MiB;
+    a tile whose estimate does not fit it asks for what it needs, with the
+    room the estimate leaves out (spills, the compiler's own scratch)."""
+    from ...profiler.tracing import trace_event
+    bhq, sq, d = q3.shape
+    nq, nk = sq // bq, kx.shape[1] // bk
+    vmem = _vmem_bytes(kernel, bq, bk, d, q3.dtype.itemsize,
+                       kx.dtype.itemsize, vx.dtype.itemsize,
+                       bias3.dtype.itemsize if bias3 is not None else 0,
+                       dbias, has_seg, rate > 0.0)
+    # blocks wholly above the diagonal: the steps causality skips
+    skipped = sum(1 for qi in range(nq) for ki in range(nk)
+                  if ki * bk > qi * bq + bq - 1 + offset) if causal else 0
+    name = _KERNEL_NAMES[kernel]
+    TILE_PLAN_TALLY[(name, bq, bk)] += 1
+    trace_event("flash::tile_plan", cat="kernel", kernel=name, bq=bq, bk=bk,
+                grid_steps=bhq * nq * nk, skipped_steps=bhq * skipped,
+                vmem_bytes=vmem)
+    limit = None
+    if vmem > _VMEM_DEFAULT_LIMIT * 3 // 4:
+        limit = min(int(vmem * 1.5), _VMEM_MAX_LIMIT)
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=limit)
 
 
 def _fwd(q3, k3, v3, bias3, seed, hq, hk, causal, scale, offset, sk_real,
@@ -262,30 +360,36 @@ def _fwd(q3, k3, v3, bias3, seed, hq, hk, causal, scale, offset, sk_real,
     has_bias = bias3 is not None
     has_seg = qseg3 is not None
 
+    kblk = _key_block(causal, bq, bk, offset, nk)
+
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, _Z)),
-        pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (kv_map(bh), ki, _Z)),
-        pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (kv_map(bh), ki, _Z)),
+        pl.BlockSpec((1, bk, d),
+                     lambda bh, qi, ki: (kv_map(bh), kblk(qi, ki), _Z)),
+        pl.BlockSpec((1, bk, d),
+                     lambda bh, qi, ki: (kv_map(bh), kblk(qi, ki), _Z)),
     ]
     args = [q3, k3, v3]
     if has_bias:
-        in_specs.append(_bias_spec(bias_maps, bq, bk))
+        in_specs.append(_bias_spec(bias_maps, bq, bk, kblk=kblk))
         args.append(bias3)
     if has_seg:
         in_specs.append(
             pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, _Z)))
         in_specs.append(
-            pl.BlockSpec((1, 1, bk), lambda bh, qi, ki: (bh, _Z, ki)))
+            pl.BlockSpec((1, 1, bk),
+                         lambda bh, qi, ki: (bh, _Z, kblk(qi, ki))))
         args += [qseg3, kseg3]
     if seed is not None:
         in_specs.append(pl.BlockSpec((1,), lambda bh, qi, ki: (_Z,), memory_space=pltpu.SMEM))
         args.append(seed)
 
+    rate = bias_maps["rate"]
     kernel = functools.partial(
         _fwd_kernel, scale=scale, causal=causal, offset=offset,
-        bq=bq, bk=bk, nk=nk, sk_real=sk_real, has_bias=has_bias,
-        has_seg=has_seg, seg_causal=bias_maps.get("seg_causal", False),
-        rate=bias_maps["rate"])
+        bq=bq, bk=bk, nk=nk, sk_real=sk_real, pad_keys=sk != sk_real,
+        has_bias=has_bias, has_seg=has_seg,
+        seg_causal=bias_maps.get("seg_causal", False), rate=rate)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -303,10 +407,11 @@ def _fwd(q3, k3, v3, bias3, seed, hq, hk, causal, scale, offset, sk_real,
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        compiler_params=_call_params(
+            "fwd", ("parallel", "parallel", "arbitrary"), q3, k3, v3, bias3,
+            False, has_seg, rate, bq, bk, offset, causal),
         interpret=interpret,
-        name="flash_fwd",
+        name=_KERNEL_NAMES["fwd"],
     )(*args)
     return out, lse[..., 0]
 
@@ -363,22 +468,23 @@ def _bias_row(maps, bh):
         (h if Hb > 1 else np.int32(0))
 
 
-def _bias_spec(maps, bq, bk, kq4_grid=False):
-    """Bias block spec for the fwd/dq (bh, qi, ki) grid; ``kq4_grid``
-    adapts to the dkv kernel's 4-D (bh, ki, r, qi) grid (bias + GQA
-    expands kv, so r is always 0 and the q-head row is bh itself)."""
+def _bias_spec(maps, bq, bk, kblk=None, qblk=None):
+    """Bias block spec. ``kblk(qi, ki)`` gives the fwd/dq (bh, qi, ki) grid
+    with its clamped key-block index; ``qblk(ki, qi)`` gives the dkv
+    kernel's 4-D (bh, ki, r, qi) grid (bias + GQA expands kv, so r is
+    always 0 and the q-head row is bh itself)."""
     Sqb = maps["Sqb"]
     bq_eff = 1 if Sqb == 1 else bq
 
-    if kq4_grid:
+    if qblk is not None:
         def idx4(bh, ki, r, qi):
             return (_bias_row(maps, bh),
-                    np.int32(0) if Sqb == 1 else qi, ki)
+                    np.int32(0) if Sqb == 1 else qblk(ki, qi), ki)
         return pl.BlockSpec((1, bq_eff, bk), idx4)
 
     def idx(bh, qi, ki):
         return (_bias_row(maps, bh),
-                np.int32(0) if Sqb == 1 else qi, ki)
+                np.int32(0) if Sqb == 1 else qi, kblk(qi, ki))
     return pl.BlockSpec((1, bq_eff, bk), idx)
 
 
@@ -386,8 +492,8 @@ def _bias_spec(maps, bq, bk, kq4_grid=False):
 # backward
 # ---------------------------------------------------------------------------
 
-def _dq_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, has_bias,
-               has_seg, seg_causal, emit_dbias, rate):
+def _dq_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, pad_keys,
+               has_bias, has_seg, seg_causal, emit_dbias, rate):
     scale = np.float32(scale)  # strong f64 scalars poison Mosaic under x64
     it = iter(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = (
@@ -415,11 +521,6 @@ def _dq_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, has_bias,
         # overwrite
         dbias_ref[0] = jnp.zeros_like(dbias_ref[0])
 
-    run = True
-    if causal:
-        run = k_start <= q_start + bq - 1 + offset
-
-    @pl.when(run)
     def _body():
         # storage-dtype MXU inputs, f32 accumulation (see _fwd_kernel note)
         q = q_ref[0]
@@ -432,14 +533,9 @@ def _dq_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, has_bias,
                                 preferred_element_type=jnp.float32) * scale
         if has_bias:
             s = s + bias_ref[0].astype(jnp.float32)
-        kidx = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = kidx < sk_real
-        if causal:
-            qidx = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            mask = mask & (kidx <= qidx + offset)
-        if has_seg:  # varlen packing: attention never crosses sequences
-            mask = mask & _seg_mask(qseg_ref[0], kseg_ref[0], seg_causal)
-        s = jnp.where(mask, s, _NEG_INF)
+        s = _hide(s, q_start, k_start, pad_keys=pad_keys, causal=causal,
+                  offset=offset, sk_real=sk_real, qseg_ref=qseg_ref,
+                  kseg_ref=kseg_ref, seg_causal=seg_causal)
         p = jnp.exp(s - lse_safe)                               # (bq, bk)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -453,13 +549,15 @@ def _dq_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, has_bias,
         dq_acc[...] += jax.lax.dot(ds.astype(k.dtype), k,
                                    preferred_element_type=jnp.float32) * scale
 
+    _when_visible(_body, q_start, k_start, bq, causal=causal, offset=offset)
+
     @pl.when(ki == nk - 1)
     def _fin():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(*refs, scale, causal, offset, bq, bk, nq, rep, sk_real,
-                has_bias, has_seg, seg_causal, rate):
+                pad_keys, has_bias, has_seg, seg_causal, rate):
     """Grid (B*Hk, nk, rep, nq): one kv-head block accumulates dk/dv over
     ALL rep q-heads of its group (GQA-native — no rep-expanded K/V in HBM
     and no post-kernel sum over q-head groups). rep == 1 is plain MHA.
@@ -491,12 +589,6 @@ def _dkv_kernel(*refs, scale, causal, offset, bq, bk, nq, rep, sk_real,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    run = True
-    if causal:
-        # block contributes iff some query row sees some key col
-        run = k_start <= q_start + bq - 1 + offset
-
-    @pl.when(run)
     def _body():
         # storage-dtype MXU inputs, f32 accumulation (see _fwd_kernel note)
         q = q_ref[0]
@@ -509,14 +601,9 @@ def _dkv_kernel(*refs, scale, causal, offset, bq, bk, nq, rep, sk_real,
                                 preferred_element_type=jnp.float32) * scale
         if has_bias:
             s = s + bias_ref[0].astype(jnp.float32)
-        kidx = k_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = kidx < sk_real
-        if causal:
-            qidx = q_start + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
-            mask = mask & (kidx <= qidx + offset)
-        if has_seg:  # varlen packing: attention never crosses sequences
-            mask = mask & _seg_mask(qseg_ref[0], kseg_ref[0], seg_causal)
-        s = jnp.where(mask, s, _NEG_INF)
+        s = _hide(s, q_start, k_start, pad_keys=pad_keys, causal=causal,
+                  offset=offset, sk_real=sk_real, qseg_ref=qseg_ref,
+                  kseg_ref=kseg_ref, seg_causal=seg_causal)
         p = jnp.exp(s - lse_safe)                               # (bq, bk)
         if rate > 0.0:
             keep = _keep_block(_mix_seed(seed_ref[0], bh), q_start, k_start,
@@ -539,6 +626,9 @@ def _dkv_kernel(*refs, scale, causal, offset, bq, bk, nq, rep, sk_real,
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale          # (bk, d)
 
+    # block contributes iff some query row sees some key col
+    _when_visible(_body, q_start, k_start, bq, causal=causal, offset=offset)
+
     @pl.when(jnp.logical_and(r == np.int32(rep - 1),
                              qi == np.int32(nq - 1)))
     def _fin():
@@ -546,23 +636,17 @@ def _dkv_kernel(*refs, scale, causal, offset, bq, bk, nq, rep, sk_real,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
-def _bwd_impl(q3, kx, vx, do3, lse, delta, bias3, seed, causal, scale,
-              offset, sk_real, bq, bk, bias_maps, interpret, qseg3=None,
-              kseg3=None, hq=None, hk=None):
-    """q3/do3/lse/delta per-q-head flattened (BHq, ...); kx/vx per-KV-head
-    (BHk, Sk, D) — the dq kernel reads its group's kv block via the same
-    index map the forward uses, and the dkv kernel accumulates over the
-    group's q-heads in-grid, so GQA never expands K/V in HBM. hq == hk is
-    plain MHA. Returns (dq, dk (BHk), dv (BHk), dbias_blocks)."""
+def _bwd_dq(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
+            offset, sk_real, bq, bk, bias_maps, interpret, qseg3, kseg3,
+            hq, hk):
+    """dq (and, for a full per-(batch, head) bias, its (bq, bk) dbias
+    tiles) on the forward's (bh, qi, ki) grid: q3/do3/lse3/delta3 per
+    q-head (BHq, ...), kx/vx per KV head (BHk, Sk, D), read through the
+    forward's index map so GQA never expands K/V in HBM."""
     bhq, sq, d = q3.shape
-    bhk, sk = kx.shape[0], kx.shape[1]
-    hq = hq if hq is not None else bhq
-    hk = hk if hk is not None else bhq
-    rep = hq // hk
+    sk = kx.shape[1]
     nq, nk = sq // bq, sk // bk
     kv_map = functools.partial(_kv_index, hq=hq, hk=hk)
-    lse3 = lse[..., None]                                   # (bhq, sq, 1)
-    delta3 = delta[..., None]
     has_bias = bias3 is not None
     has_seg = qseg3 is not None
     # in-kernel dbias tiles only when bias is full per-(batch, head): then
@@ -571,24 +655,28 @@ def _bwd_impl(q3, kx, vx, do3, lse, delta, bias3, seed, causal, scale,
     emit_dbias = has_bias and bias_maps["full"]
     rate = bias_maps["rate"]
 
-    base_specs = [
+    kblk = _key_block(causal, bq, bk, offset, nk)
+
+    in_specs = [
         pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, _Z)),
-        pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (kv_map(bh), ki, _Z)),
-        pl.BlockSpec((1, bk, d), lambda bh, qi, ki: (kv_map(bh), ki, _Z)),
+        pl.BlockSpec((1, bk, d),
+                     lambda bh, qi, ki: (kv_map(bh), kblk(qi, ki), _Z)),
+        pl.BlockSpec((1, bk, d),
+                     lambda bh, qi, ki: (kv_map(bh), kblk(qi, ki), _Z)),
         pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, _Z)),
         pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, _Z)),
         pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, _Z)),
     ]
     args = [q3, kx, vx, do3, lse3, delta3]
-    in_specs = list(base_specs)
     if has_bias:
-        in_specs.append(_bias_spec(bias_maps, bq, bk))
+        in_specs.append(_bias_spec(bias_maps, bq, bk, kblk=kblk))
         args.append(bias3)
     if has_seg:
         in_specs.append(
             pl.BlockSpec((1, bq, 1), lambda bh, qi, ki: (bh, qi, _Z)))
         in_specs.append(
-            pl.BlockSpec((1, 1, bk), lambda bh, qi, ki: (bh, _Z, ki)))
+            pl.BlockSpec((1, 1, bk),
+                         lambda bh, qi, ki: (bh, _Z, kblk(qi, ki))))
         args += [qseg3, kseg3]
     if rate > 0.0:
         in_specs.append(pl.BlockSpec((1,), lambda bh, qi, ki: (_Z,), memory_space=pltpu.SMEM))
@@ -603,60 +691,67 @@ def _bwd_impl(q3, kx, vx, do3, lse, delta, bias3, seed, causal, scale,
         dq_out_shape.append(
             jax.ShapeDtypeStruct((bhq, sq, sk), jnp.float32))
 
-    scratch = [pltpu.VMEM((bq, d), jnp.float32)]
     dq_outs = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
                           offset=offset, bq=bq, bk=bk, nk=nk,
-                          sk_real=sk_real, has_bias=has_bias,
-                          has_seg=has_seg,
+                          sk_real=sk_real, pad_keys=sk != sk_real,
+                          has_bias=has_bias, has_seg=has_seg,
                           seg_causal=bias_maps.get("seg_causal", False),
                           emit_dbias=emit_dbias, rate=rate),
         grid=(bhq, nq, nk),
         in_specs=in_specs,
         out_specs=dq_out_specs if emit_dbias else dq_out_specs[0],
         out_shape=dq_out_shape if emit_dbias else dq_out_shape[0],
-        scratch_shapes=scratch,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
+        compiler_params=_call_params(
+            "dq", ("parallel", "parallel", "arbitrary"), q3, kx, vx, bias3,
+            emit_dbias, has_seg, rate, bq, bk, offset, causal),
         interpret=interpret,
-        name="flash_bwd_dq",
+        name=_KERNEL_NAMES["dq"],
     )(*args)
-    if emit_dbias:
-        dq, dbias_blocks = dq_outs
-    else:
-        dq, dbias_blocks = dq_outs, None
+    return dq_outs if emit_dbias else (dq_outs, None)
 
-    # dkv grid: (kv-head, k-block, r, qi) — the (q-head-of-group, q-block)
-    # sweep as two AFFINE dims; all i32 (index maps lower through Mosaic).
-    # The earlier folded j = r*nq + qi form needed div/mod in every q-side
-    # index map, defeating Mosaic's cross-iteration DMA pipelining.
+
+def _bwd_dkv(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
+             offset, sk_real, bq, bk, bias_maps, interpret, qseg3, kseg3,
+             hq, hk):
+    """dk/dv per KV head on the (kv-head, k-block, r, qi) grid: the
+    (q-head-of-group, q-block) sweep as two AFFINE dims, all i32 (index
+    maps lower through Mosaic), accumulated in-grid over the group's
+    q-heads (no per-q-head dk/dv in HBM)."""
+    _, sq, d = q3.shape
+    bhk, sk = kx.shape[0], kx.shape[1]
+    rep = hq // hk
+    nq, nk = sq // bq, sk // bk
+    has_bias = bias3 is not None
+    has_seg = qseg3 is not None
+    rate = bias_maps["rate"]
     rep_i = np.int32(rep)
 
     def qrow(bh, r):
         return bh * rep_i + r
 
+    qblk = _query_block(causal, bq, bk, offset, nq)
+
+    def qside(last):
+        return pl.BlockSpec(
+            (1, bq, last),
+            lambda bh, ki, r, qi: (qrow(bh, r), qblk(ki, qi), _Z))
+
     kq_specs = [
-        pl.BlockSpec((1, bq, d),
-                     lambda bh, ki, r, qi: (qrow(bh, r), qi, _Z)),
+        qside(d),
         pl.BlockSpec((1, bk, d), lambda bh, ki, r, qi: (bh, ki, _Z)),
         pl.BlockSpec((1, bk, d), lambda bh, ki, r, qi: (bh, ki, _Z)),
-        pl.BlockSpec((1, bq, d),
-                     lambda bh, ki, r, qi: (qrow(bh, r), qi, _Z)),
-        pl.BlockSpec((1, bq, 1),
-                     lambda bh, ki, r, qi: (qrow(bh, r), qi, _Z)),
-        pl.BlockSpec((1, bq, 1),
-                     lambda bh, ki, r, qi: (qrow(bh, r), qi, _Z)),
+        qside(d), qside(1), qside(1),
     ]
     kq_args = [q3, kx, vx, do3, lse3, delta3]
     if has_bias:
         # bias rows are per q-head: callers expand K/V for bias + GQA, so
         # rep == 1 here and the bias map sees the plain q-head index
-        kq_specs.append(_bias_spec(bias_maps, bq, bk, kq4_grid=True))
+        kq_specs.append(_bias_spec(bias_maps, bq, bk, qblk=qblk))
         kq_args.append(bias3)
     if has_seg:
-        kq_specs.append(
-            pl.BlockSpec((1, bq, 1),
-                         lambda bh, ki, r, qi: (qrow(bh, r), qi, _Z)))
+        kq_specs.append(qside(1))
         kq_specs.append(
             pl.BlockSpec((1, 1, bk),
                          lambda bh, ki, r, qi: (qrow(bh, r), _Z, ki)))
@@ -666,13 +761,11 @@ def _bwd_impl(q3, kx, vx, do3, lse, delta, bias3, seed, causal, scale,
             (1,), lambda bh, ki, r, qi: (_Z,), memory_space=pltpu.SMEM))
         kq_args.append(seed)
 
-    scratch2 = [pltpu.VMEM((bk, d), jnp.float32),
-                pltpu.VMEM((bk, d), jnp.float32)]
-    dk, dv = pl.pallas_call(
+    return pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           offset=offset, bq=bq, bk=bk, nq=nq, rep=rep,
-                          sk_real=sk_real, has_bias=has_bias,
-                          has_seg=has_seg,
+                          sk_real=sk_real, pad_keys=sk != sk_real,
+                          has_bias=has_bias, has_seg=has_seg,
                           seg_causal=bias_maps.get("seg_causal", False),
                           rate=rate),
         grid=(bhk, nk, rep, nq),
@@ -685,13 +778,30 @@ def _bwd_impl(q3, kx, vx, do3, lse, delta, bias3, seed, causal, scale,
             jax.ShapeDtypeStruct((bhk, sk, d), q3.dtype),
             jax.ShapeDtypeStruct((bhk, sk, d), q3.dtype),
         ],
-        scratch_shapes=scratch2,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary",
-                                 "arbitrary")),
+        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
+                        pltpu.VMEM((bk, d), jnp.float32)],
+        compiler_params=_call_params(
+            "dkv", ("parallel", "parallel", "arbitrary", "arbitrary"), q3,
+            kx, vx, bias3, False, has_seg, rate, bq, bk, offset, causal),
         interpret=interpret,
-        name="flash_bwd_dkv",
+        name=_KERNEL_NAMES["dkv"],
     )(*kq_args)
+
+
+def _bwd_impl(q3, kx, vx, do3, lse, delta, bias3, seed, causal, scale,
+              offset, sk_real, plan, bias_maps, interpret, qseg3=None,
+              kseg3=None, hq=None, hk=None):
+    """Both backward kernels over operands padded to lengths that the dq
+    and the dkv tile of ``plan`` divide. hq == hk is plain MHA. Returns
+    (dq, dk (BHk), dv (BHk), dbias_blocks)."""
+    bhq = q3.shape[0]
+    hq = hq if hq is not None else bhq
+    hk = hk if hk is not None else bhq
+    common = (q3, kx, vx, do3, lse[..., None], delta[..., None], bias3,
+              seed, causal, scale, offset, sk_real)
+    rest = (bias_maps, interpret, qseg3, kseg3, hq, hk)
+    dq, dbias_blocks = _bwd_dq(*common, *plan.dq, *rest)
+    dk, dv = _bwd_dkv(*common, *plan.dkv, *rest)
     return dq, dk, dv, dbias_blocks
 
 
@@ -753,9 +863,123 @@ def _dbias_broadcast(q3, kx, vx, do3, lse_p, delta, bias3, seed, maps,
     return acc[:, :, :Sk]
 
 
-def _pick_block(s, target=128):
-    b = min(target, s)
-    return b
+# ---------------------------------------------------------------------------
+# the tile plan: how the three kernels cut attention into grid steps
+# ---------------------------------------------------------------------------
+
+class TilePlan(NamedTuple):
+    """(block_q, block_k) of each kernel. They need not agree: fwd and dq
+    hold one (bq, d) accumulator and sweep k, dkv holds two (bk, d)
+    accumulators and sweeps q."""
+    fwd: Tuple[int, int]
+    dq: Tuple[int, int]
+    dkv: Tuple[int, int]
+
+
+# Mosaic's scoped VMEM: 16 MiB unless the call asks for more (a v5e core
+# has 128 MiB)
+_VMEM_DEFAULT_LIMIT = 16 << 20
+_VMEM_MAX_LIMIT = 64 << 20
+# what a plan's estimate may take: 1024 x 1024 tiles of a float32 head of
+# 128 fit (24.5 MiB in dkv), and 1.5 times it stays under the limit above
+_VMEM_BUDGET = 32 << 20
+# the (bq, bk) float32 score-sized temporaries a body keeps alive
+_SCORE_TEMPS = {"fwd": 3, "dq": 4, "dkv": 4}
+_MAX_BLOCK = 1024
+
+
+def _round_up(n, m):
+    return -(-n // m) * m
+
+
+def _vmem_bytes(kernel, bq, bk, d, q_bytes, k_bytes, v_bytes, bias_bytes,
+                dbias, segments, dropout):
+    """Estimated VMEM of one call of ``kernel`` ("fwd", "dq", "dkv") at a
+    (bq, bk) tile: in/out blocks double-buffered by the pipeline, scratch,
+    and the score-sized temporaries of the body. A (bq, 1) column block
+    occupies whole 128-lane tiles."""
+    dl = _round_up(d, _LANES)
+    col = _round_up(bq, 8) * _LANES * 4
+    q_blk, k_blk, v_blk = bq * dl * q_bytes, bk * dl * k_bytes, \
+        bk * dl * v_bytes
+    if kernel == "fwd":       # q, k, v in; o (q's dtype), lse out
+        blocks = 2 * q_blk + k_blk + v_blk + col
+        scratch = bq * dl * 4 + 2 * col
+    elif kernel == "dq":      # q, k, v, do, lse, delta in; dq out
+        blocks = 3 * q_blk + k_blk + v_blk + 2 * col
+        scratch = bq * dl * 4
+    else:                     # q, k, v, do, lse, delta in; dk, dv out
+        blocks = 2 * q_blk + k_blk + v_blk + 2 * col + 2 * bk * dl * q_bytes
+        scratch = 2 * bk * dl * 4
+    temps = _SCORE_TEMPS[kernel] + (2 if dropout else 0)
+    if bias_bytes:
+        blocks += bq * bk * bias_bytes
+        temps += 1
+    if dbias:
+        blocks += bq * bk * 4
+    if segments:
+        blocks += col + 8 * _round_up(bk, _LANES) * 4
+        temps += 1
+    return 2 * blocks + scratch + temps * bq * bk * 4
+
+
+def _side_blocks(s):
+    """Block lengths one side of the score tile may take: the whole length
+    where it is short (decode has Sq = 1), else the multiples of 128, up to
+    ``_MAX_BLOCK``, that divide the length padded to a multiple of 128 —
+    so no block pads more than 128 does, and any two of them divide one
+    padded length (the backward's two kernels share their operands)."""
+    if s <= _LANES:
+        return (s,)
+    n = _round_up(s, _LANES) // _LANES
+    return tuple(_LANES * m for m in range(1, _MAX_BLOCK // _LANES + 1)
+                 if n % m == 0)
+
+
+def tile_plan(sq, sk, d, q_bytes=2, k_bytes=2, v_bytes=2, *, bias_bytes=0,
+              dbias=False, segments=False, dropout=False,
+              vmem_budget=_VMEM_BUDGET) -> TilePlan:
+    """The tiles of the three kernels, from what a call can see at trace
+    time: the lengths, the head dim, the operands' item sizes, whether a
+    bias block (and its dbias tile), segment ids or dropout ride along,
+    and a VMEM budget. Each kernel takes the largest tile whose sides suit
+    the lengths and whose estimate fits the budget (PERF.md, "PR 26": the
+    sweep on the chip). Causal or not does not enter: the order was
+    measured causal, where a large tile wastes most (the half of a
+    diagonal block above the diagonal), and it was the fastest there."""
+    qs, ks = _side_blocks(sq), _side_blocks(sk)
+
+    def pick(kernel):
+        fits = [(bq, bk) for bq in qs for bk in ks
+                if _vmem_bytes(kernel, bq, bk, d, q_bytes, k_bytes, v_bytes,
+                               bias_bytes, dbias and kernel == "dq",
+                               segments, dropout) <= vmem_budget]
+        # the largest tile, and of two as large the one wider in keys: a
+        # grid step's own cost and the (bq, 128) softmax statistics are paid
+        # once a step, so they shrink with the tile, the latter with bk
+        return max(fits, key=lambda t: (t[0] * t[1], t[1]),
+                   default=(min(qs), min(ks)))
+    return TilePlan(pick("fwd"), pick("dq"), pick("dkv"))
+
+
+def _blocks(block_q, block_k, q, k, v, bias, segments, rate):
+    """TilePlan of a call on q [B,Sq,Hq,D], k/v [B,Sk,Hk,D]: explicit
+    ``block_q`` and ``block_k`` (cut to the length) go to all three
+    kernels; both None, the plan chooses."""
+    B, Sq, Hq, D = q.shape
+    Sk = k.shape[1]
+    if (block_q is None) != (block_k is None):
+        raise ValueError("block_q and block_k are given together or not at "
+                         f"all, got {block_q!r} and {block_k!r}")
+    if block_q is not None:
+        t = (min(block_q, Sq), min(block_k, Sk))
+        return TilePlan(t, t, t)
+    return tile_plan(
+        Sq, Sk, D, q.dtype.itemsize, k.dtype.itemsize, v.dtype.itemsize,
+        bias_bytes=jnp.asarray(bias).dtype.itemsize if bias is not None
+        else 0,
+        dbias=bias is not None and _bias_shape4(bias) == (B, Hq, Sq, Sk),
+        segments=segments, dropout=rate > 0.0)
 
 
 def _pad_seq(x3, block):
@@ -829,7 +1053,8 @@ def _fa_fwd(q, k, v, bias, seed, q_seg, k_seg, causal, scale, dropout_rate,
             block_q, block_k, interpret):
     B, Sq, Hq, D = q.shape
     Sk, Hk = k.shape[1], k.shape[2]
-    bq, bk = _pick_block(Sq, block_q), _pick_block(Sk, block_k)
+    bq, bk = _blocks(block_q, block_k, q, k, v, bias, q_seg is not None,
+                     dropout_rate).fwd
     offset = Sk - Sq
 
     q3 = _pad_seq(q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, D), bq)
@@ -869,7 +1094,12 @@ def _fa_bwd(causal, scale, dropout_rate, block_q, block_k, interpret, res,
     B, Sq, Hq, D = q.shape
     Sk, Hk = k.shape[1], k.shape[2]
     rep = Hq // Hk
-    bq, bk = _pick_block(Sq, block_q), _pick_block(Sk, block_k)
+    plan = _blocks(block_q, block_k, q, k, v, bias, q_seg is not None,
+                   dropout_rate)
+    # the two kernels share their operands: pad each side to a length both
+    # of its blocks divide
+    bq, bk = math.lcm(plan.dq[0], plan.dkv[0]), \
+        math.lcm(plan.dq[1], plan.dkv[1])
     offset = Sk - Sq
 
     q3 = _pad_seq(q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, D), bq)
@@ -924,7 +1154,7 @@ def _fa_bwd(causal, scale, dropout_rate, block_q, block_k, interpret, res,
 
     dq3, dk3, dv3, dbias_blocks = _bwd_impl(
         q3, kx, vx, do3, lse_p, delta, bias3, seed_in, causal, scale,
-        offset, Sk, bq, bk, maps, interpret, qseg3, kseg3,
+        offset, Sk, plan, maps, interpret, qseg3, kseg3,
         hq=hq_eff, hk=hk_eff)
     dq = dq3[:, :Sq].reshape(B, Hq, Sq, D).transpose(0, 2, 1, 3)
     if expand_kv:  # per-q-head dk/dv: sum q-head groups onto their kv head
@@ -961,7 +1191,7 @@ flash_attention_ext.defvjp(_fa_fwd, _fa_bwd)
 
 
 def flash_attention_pallas(q, k, v, causal, scale, interpret,
-                           block_q=128, block_k=128):
+                           block_q=None, block_k=None):
     """Bias-free, dropout-free fast path (back-compat signature)."""
     return flash_attention_ext(q, k, v, None, jnp.zeros((1,), jnp.int32),
                                None, None, causal, scale, 0.0, block_q,
@@ -976,7 +1206,7 @@ def flash_attention_pallas(q, k, v, causal, scale, interpret,
 # a GLOBAL lse/delta. GQA-native: Hk may divide Hq, K/V never expand.
 # ---------------------------------------------------------------------------
 
-def flash_chunk_fwd(q, k, v, causal, scale, block_q=128, block_k=128,
+def flash_chunk_fwd(q, k, v, causal, scale, block_q=None, block_k=None,
                     interpret=False):
     """Partial attention of q [B,Sq,Hq,D] against one k/v chunk
     [B,Sc,Hk,D]. Returns (out [B,Sq,Hq,D], lse [B,Hq,Sq]) — normalized
@@ -985,7 +1215,7 @@ def flash_chunk_fwd(q, k, v, causal, scale, block_q=128, block_k=128,
     chunk); fully-visible chunks pass causal=False."""
     B, Sq, Hq, D = q.shape
     Sk, Hk = k.shape[1], k.shape[2]
-    bq, bk = _pick_block(Sq, block_q), _pick_block(Sk, block_k)
+    bq, bk = _blocks(block_q, block_k, q, k, v, None, False, 0.0).fwd
     q3 = _pad_seq(q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, D), bq)
     k3 = _pad_seq(k.transpose(0, 2, 1, 3).reshape(B * Hk, Sk, D), bk)
     v3 = _pad_seq(v.transpose(0, 2, 1, 3).reshape(B * Hk, Sk, D), bk)
@@ -995,8 +1225,8 @@ def flash_chunk_fwd(q, k, v, causal, scale, block_q=128, block_k=128,
     return out, lse[:, :Sq].reshape(B, Hq, Sq)
 
 
-def flash_chunk_bwd(q, k, v, do, lse, delta, causal, scale, block_q=128,
-                    block_k=128, interpret=False):
+def flash_chunk_bwd(q, k, v, do, lse, delta, causal, scale, block_q=None,
+                    block_k=None, interpret=False):
     """(dq, dk, dv) of one chunk's contribution, given the GLOBAL (all
     chunks merged) lse and delta = rowsum(do * out), both [B,Hq,Sq].
     With the global lse, p = exp(s - lse) is each chunk's true posterior
@@ -1004,7 +1234,9 @@ def flash_chunk_bwd(q, k, v, do, lse, delta, causal, scale, block_q=128,
     the flash-attention backward identity at ring granularity."""
     B, Sq, Hq, D = q.shape
     Sk, Hk = k.shape[1], k.shape[2]
-    bq, bk = _pick_block(Sq, block_q), _pick_block(Sk, block_k)
+    plan = _blocks(block_q, block_k, q, k, v, None, False, 0.0)
+    bq, bk = math.lcm(plan.dq[0], plan.dkv[0]), \
+        math.lcm(plan.dq[1], plan.dkv[1])
     q3 = _pad_seq(q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, D), bq)
     kx = _pad_seq(k.transpose(0, 2, 1, 3).reshape(B * Hk, Sk, D), bk)
     vx = _pad_seq(v.transpose(0, 2, 1, 3).reshape(B * Hk, Sk, D), bk)
@@ -1019,7 +1251,7 @@ def flash_chunk_bwd(q, k, v, do, lse, delta, causal, scale, block_q=128,
         delta2 = jnp.pad(delta2, ((0, 0), (0, pad_q)))
     dq3, dk3, dv3, _ = _bwd_impl(
         q3, kx, vx, do3, lse2, delta2, None, None, causal, scale,
-        Sk - Sq, Sk, bq, bk, {"rate": 0.0}, interpret, hq=Hq, hk=Hk)
+        Sk - Sq, Sk, plan, {"rate": 0.0}, interpret, hq=Hq, hk=Hk)
     dq = dq3[:, :Sq].reshape(B, Hq, Sq, D).transpose(0, 2, 1, 3)
     dk = dk3[:, :Sk].reshape(B, Hk, Sk, D).transpose(0, 2, 1, 3)
     dv = dv3[:, :Sk].reshape(B, Hk, Sk, D).transpose(0, 2, 1, 3)
@@ -1116,17 +1348,21 @@ def _per_shard(kernel, auto, q, k):
                          check_vma=False)
 
 
-# candidate (block_q, block_k) tilings; 128x128 is the safe default, the
-# larger tiles amortize grid overhead at long seq (tuned on-chip via
-# core/autotune.py — the analog of the reference's exhaustive-search cache,
-# paddle/phi/kernels/autotune/cache.h)
+# the (block_q, block_k) tilings autotune measures end to end (fwd + bwd, one
+# tile for all three kernels) when it is on (core/autotune.py — the analog
+# of the reference's exhaustive-search cache,
+# paddle/phi/kernels/autotune/cache.h). With autotune off, or a cold
+# cache, ``tile_plan`` chooses, per kernel
 _BLOCK_CANDIDATES = ((128, 128), (256, 256), (512, 256), (256, 512),
-                     (512, 512))
+                     (512, 512), (512, 1024), (1024, 1024))
 
 
 def _tuned_blocks(q, k, v, bias, seed, causal, scale, rate, interpret,
                   dropout_key=None):
-    """(impl, bq, bk, out) for this call — ``impl`` in {"pallas", "xla"}.
+    """(impl, bq, bk, out) for this call — ``impl`` in {"pallas", "xla"};
+    ``bq``/``bk`` None where nothing was measured: ``tile_plan`` then
+    chooses each kernel's tile from the shape (``flash_attention_ext``
+    with block arguments None).
 
     Consult the autotune cache (traced calls), or measure candidates
     fwd+bwd on concrete eager calls. The measured timing includes the
@@ -1203,11 +1439,11 @@ def _tuned_blocks(q, k, v, bias, seed, causal, scale, rate, interpret,
         # but the xla-vs-pallas choice is NOT: "xla" only returns when THIS
         # call's score matrix fits the HBM budget ("xla" in cands implies
         # xla_fits above) — a b2-cached "xla" must not OOM a b16 call
-        return "xla", 128, 128, out
+        return "xla", None, None, out
     if choice is None or choice not in cands:
         # choice unknown: autotune off / stale persisted entry from an
         # older candidate list / cached "xla" that this call excluded —
-        # degrade to the measured default heuristic
-        return default_impl, 128, 128, None
+        # the default route, with the tile plan's blocks
+        return default_impl, None, None, None
     bq, bk = cands[choice]
     return "pallas", bq, bk, out

@@ -1,0 +1,110 @@
+"""The flash kernels at the tile plan's tiles, compiled for a described v5e.
+
+Interpret mode cannot say whether Mosaic takes a tile: whether its blocks
+fit the scoped VMEM the call asks for (``_call_params``), whether a
+384-long block of an unaligned length tiles. The TPU's compiler is installed
+here and compiles for a chip that is described and not attached, so these
+cases compile forward and backward at real widths: what the chip's compiler
+would refuse, it refuses here. Nothing runs; no time is read.
+
+The topology is described inside a fixture (never while a module is
+imported): only the worker that is handed this file loads the TPU's library.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from paddle_tpu.ops.pallas import flash_attention as fa
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 -- any failure to describe: skip
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def production_numerics():
+    """conftest.py asks for "highest" matmuls everywhere; the kernels are
+    compiled as the training path compiles them. The persistent compile
+    cache is off: an entry written for a described chip cannot be read
+    back without one."""
+    from jax.experimental.compilation_cache import compilation_cache
+    before = (jax.config.jax_default_matmul_precision,
+              jax.config.jax_enable_compilation_cache)
+    jax.config.update("jax_default_matmul_precision", None)
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_default_matmul_precision", before[0])
+    jax.config.update("jax_enable_compilation_cache", before[1])
+    compilation_cache.reset_cache()
+
+
+# name: (b, s, hq, hk, d, q/k dtype, v dtype, bias, segments, dropout)
+_CASES = {
+    # the benchmark cells' own attention shapes
+    "gpt2s_cell": (32, 1024, 12, 12, 64, "bfloat16", "bfloat16", False,
+                   False, 0.0),
+    "mistral_cell_f32qk": (4, 4096, 32, 8, 128, "float32", "bfloat16",
+                           False, False, 0.0),
+    # everything that rides along, at once: a full bias (and its dbias
+    # tiles out of dq), and dropout
+    "bias_dropout_d128": (1, 2048, 4, 4, 128, "bfloat16", "bfloat16", True,
+                          False, 0.1),
+    "segments_d64": (1, 2048, 4, 4, 64, "bfloat16", "bfloat16", False, True,
+                     0.0),
+    # 1100 pads to 1152 = 9 x 128: the plan's 384-long blocks
+    "s1100_unaligned": (1, 1100, 4, 4, 64, "float32", "float32", False,
+                        False, 0.0),
+    # the widest head the kernels take, all float32: the budget shrinks
+    # the tile
+    "d256_f32": (1, 4096, 4, 2, 256, "float32", "float32", False, False,
+                 0.0),
+}
+
+
+@pytest.mark.parametrize("case", list(_CASES))
+def test_plan_tiles_compile_for_v5e(case, one_chip, production_numerics):
+    b, s, hq, hk, d, qk_dt, v_dt, with_bias, with_seg, rate = _CASES[case]
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+    q = arg((b, s, hq, d), qk_dt)
+    k = arg((b, s, hk, d), qk_dt)
+    v = arg((b, s, hk, d), v_dt)
+    bias = arg((b, hq, s, s), "float32") if with_bias else None
+    seg = arg((b, s), "int32") if with_seg else None
+    seed = arg((1,), "int32")
+    scale = float(d) ** -0.5
+
+    def loss(q, k, v, bias, seg, seed):
+        out = fa.flash_attention_ext(q, k, v, bias, seed, seg, seg, True,
+                                     scale, rate, None, None, False)
+        return out.astype(jnp.float32).sum()
+
+    before = dict(fa.TILE_PLAN_TALLY)
+    argnums = (0, 1, 2, 3) if with_bias else (0, 1, 2)
+    compiled = jax.jit(jax.grad(loss, argnums)).lower(
+        q, k, v, bias, seg, seed).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    lowered = {key for key, n in fa.TILE_PLAN_TALLY.items()
+               if n > before.get(key, 0)}
+    assert {key[0] for key in lowered} == {"flash_fwd", "flash_bwd_dq",
+                                           "flash_bwd_dkv"}
+    plan = fa.tile_plan(
+        s, s, d, jnp.dtype(qk_dt).itemsize, jnp.dtype(qk_dt).itemsize,
+        jnp.dtype(v_dt).itemsize, bias_bytes=4 if with_bias else 0,
+        dbias=with_bias, segments=with_seg, dropout=rate > 0.0)
+    assert lowered == {("flash_fwd",) + plan.fwd,
+                       ("flash_bwd_dq",) + plan.dq,
+                       ("flash_bwd_dkv",) + plan.dkv}

@@ -581,6 +581,44 @@ def test_gl905_modest_blocks_are_clean(tmp_path):
     assert _gl9(findings) == []
 
 
+_GL905_RAISED = """
+        def params(limit):
+            return pltpu.CompilerParams(
+                dimension_semantics=("parallel",), vmem_limit_bytes=limit)
+
+        def f(x):
+            return pl.pallas_call(
+                copy_kernel,
+                grid=(4,),
+                in_specs=[pl.BlockSpec((1024, 2048), lambda i: (i, 0))],
+                out_specs=pl.BlockSpec((1024, 2048), lambda i: (i, 0)),
+                out_shape=jax.ShapeDtypeStruct((4096, 2048),
+                                               jnp.float32),
+                compiler_params=%s,
+            )(x)
+    """
+
+
+@pytest.mark.parametrize("params,flagged", [
+    # 32 MiB of blocks: inside 75% of a 64 MiB limit, over 75% of 40 MiB
+    ("pltpu.CompilerParams(vmem_limit_bytes=64 * 1024 * 1024)", 0),
+    ("pltpu.CompilerParams(vmem_limit_bytes=40 * 1024 * 1024)", 1),
+    # no limit set: Mosaic's 16 MiB default
+    ("pltpu.CompilerParams(dimension_semantics=('parallel',))", 1),
+    ("pltpu.CompilerParams(vmem_limit_bytes=None)", 1),
+    # a limit the model cannot evaluate is no proof: silent
+    ("pltpu.CompilerParams(vmem_limit_bytes=estimate(x))", 0),
+    ("params(estimate(x))", 0),
+    ("params(64 * 1024 * 1024)", 0),
+])
+def test_gl905_reads_a_raised_vmem_limit(tmp_path, params, flagged):
+    findings, _ = _lint_src(tmp_path, _GL905_RAISED % params)
+    got = _gl9(findings, "GL905")
+    assert len(got) == flagged
+    if flagged and "40" in params:
+        assert "40 MiB" in got[0].message
+
+
 # -- GL906: interpret-mode drift ---------------------------------------------
 
 def test_gl906_local_backend_check_flagged(tmp_path):

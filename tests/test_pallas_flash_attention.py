@@ -470,3 +470,259 @@ def test_flash_attn_unpadded_xla_fallback_no_nan():
     out = np.asarray(out.numpy())
     assert np.isfinite(out[:4]).all()
     np.testing.assert_array_equal(out[4:], 0.0)   # dead rows zeroed
+
+
+# ---------------------------------------------------------------------------
+# the tile plan: blocks chosen from the shape (ISSUE 26). The parity cases
+# run at lengths where the plan leaves 128 x 128, under the plan's tiles
+# (block arguments None) and under explicit 128 x 128, against the XLA twin.
+# ---------------------------------------------------------------------------
+from paddle_tpu.ops.pallas import flash_attention as fa  # noqa: E402
+
+_TILINGS = [pytest.param(None, id="plan"), pytest.param(128, id="b128")]
+
+
+def _segment_bias(seg_q, seg_k):
+    """Additive form of segment ids for the XLA twin."""
+    same = seg_q[0][:, None] == seg_k[0][None, :]
+    return jnp.where(same, 0.0, -jnp.inf)[None, None]
+
+
+# name: (b, sq, sk, hq, hk, d, dtype, extra) -- extra in {None, "bias_full",
+# "bias_bcast", "segments"}
+_PLAN_CASES = {
+    "mha_d64_s1024_bf16": (1, 1024, 1024, 2, 2, 64, jnp.bfloat16, None),
+    "mha_d64_s1024_f32": (1, 1024, 1024, 2, 2, 64, jnp.float32, None),
+    "gqa_4_2_d128_s1024": (1, 1024, 1024, 4, 2, 128, jnp.float32, None),
+    "sq512_sk1024_offset": (1, 512, 1024, 2, 2, 64, jnp.float32, None),
+    "s1100_padding": (1, 1100, 1100, 2, 2, 64, jnp.float32, None),
+    "bias_full_s512": (1, 512, 512, 2, 2, 64, jnp.float32, "bias_full"),
+    "bias_bcast_s512": (2, 512, 512, 2, 2, 64, jnp.float32, "bias_bcast"),
+    "segments_s512": (1, 512, 512, 2, 2, 64, jnp.float32, "segments"),
+}
+
+
+@pytest.mark.parametrize("block", _TILINGS)
+@pytest.mark.parametrize("case", list(_PLAN_CASES))
+def test_plan_tiles_match_xla(case, block):
+    b, sq, sk, hq, hk, d, dtype, extra = _PLAN_CASES[case]
+    q, k, v = _mk(b, sq, sk, hq, hk, d, dtype=dtype, seed=21)
+    scale = 1.0 / math.sqrt(d)
+    rng = np.random.RandomState(22)
+    ct = jnp.asarray(rng.standard_normal((b, sq, hq, d)), jnp.float32)
+    bias = seg = twin_bias = None
+    if extra == "bias_full":
+        bias = jnp.asarray(rng.standard_normal((b, hq, sq, sk)),
+                           jnp.float32) * 0.5
+    elif extra == "bias_bcast":
+        bias = jnp.asarray(rng.standard_normal((1, 1, 1, sk)),
+                           jnp.float32) * 0.5
+    elif extra == "segments":
+        seg = jnp.asarray(np.repeat(np.arange(4, dtype=np.int32),
+                                    [100, 200, 150, 62])[None, :])
+        twin_bias = _segment_bias(seg, seg)
+    diff = (q, k, v) if bias is None else (q, k, v, bias)
+
+    def loss_pl(q, k, v, bias=None):
+        out = flash_attention_ext(q, k, v, bias, _SEED0, seg, seg, True,
+                                  scale, 0.0, block, block, True)
+        return jnp.sum(out.astype(jnp.float32) * ct), out
+
+    def loss_ref(q, k, v, bias=None):
+        out = _attention_xla(q, k, v, twin_bias if bias is None else bias,
+                             True, scale, 0.0, None)
+        return jnp.sum(out.astype(jnp.float32) * ct), out
+
+    argnums = tuple(range(len(diff)))
+    (_, out), gp = jax.value_and_grad(loss_pl, argnums, has_aux=True)(*diff)
+    (_, ref), gr = jax.value_and_grad(loss_ref, argnums, has_aux=True)(*diff)
+    fwd_tol, bwd_tol = (2e-2, 6e-2) if dtype == jnp.bfloat16 \
+        else (3e-5, 3e-4)
+    assert out.dtype == dtype
+    np.testing.assert_allclose(np.asarray(out, np.float32),
+                               np.asarray(ref, np.float32),
+                               rtol=fwd_tol, atol=fwd_tol)
+    for a, b_, name in zip(gp, gr, ("q", "k", "v", "bias")):
+        assert a.shape == b_.shape
+        np.testing.assert_allclose(np.asarray(a, np.float32),
+                                   np.asarray(b_, np.float32),
+                                   rtol=bwd_tol, atol=bwd_tol,
+                                   err_msg=f"{case}: grad of {name}")
+
+
+def test_dropout_same_mask_under_two_tilings():
+    """The keep-mask is a function of the global element index, so the
+    plan's tiles and 128 x 128 drop the same positions: outputs and
+    gradients agree to float32 rounding, and both agree with the dense
+    oracle under ``dropout_keep_mask``."""
+    b, s, h, d, rate = 1, 512, 2, 64, 0.25
+    q, k, v = _mk(b, s, s, h, h, d, seed=23)
+    scale = 1.0 / math.sqrt(d)
+    seed = jnp.asarray([1234], jnp.int32)
+
+    def run(block):
+        return jax.value_and_grad(lambda q, k, v: flash_attention_ext(
+            q, k, v, None, seed, None, None, True, scale, rate, block,
+            block, True).sum(), (0, 1, 2))(q, k, v)
+
+    assert fa._blocks(None, None, q, k, v, None, False, rate).fwd \
+        != (128, 128)
+    (lp, gp), (l128, g128) = run(None), run(128)
+    np.testing.assert_allclose(float(lp), float(l128), rtol=1e-5)
+    for a, b_ in zip(gp, g128):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=1e-4, atol=1e-4)
+    keep = dropout_keep_mask(seed, b * h, s, s, rate).reshape(b, h, s, s)
+    ref = _dense_oracle(q, k, v, scale, keep=keep, rate=rate)
+    out = flash_attention_ext(q, k, v, None, seed, None, None, True, scale,
+                              rate, None, None, True)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=3e-5, atol=3e-5)
+
+
+# (sq, sk, d, q/k bytes, v bytes, bias bytes, dbias, segments, dropout)
+_PLAN_TABLE = [
+    (1024, 1024, 64, 2, 2, 0, False, False, False),    # the GPT-2 cell
+    (4096, 4096, 128, 4, 2, 0, False, False, False),   # the Mistral cell
+    (2048, 2048, 128, 2, 2, 0, False, False, False),
+    (4096, 4096, 256, 4, 4, 0, False, False, False),   # widest head, float32
+    (512, 1024, 64, 4, 4, 0, False, False, False),     # ring chunk, Sq != Sk
+    (1100, 1100, 64, 4, 4, 0, False, False, False),    # padding
+    (1, 2048, 128, 2, 2, 0, False, False, False),      # decode: Sq = 1
+    (100, 100, 64, 4, 4, 0, False, False, False),      # short: whole length
+    (192, 192, 32, 4, 4, 0, False, False, False),
+    (1024, 1024, 64, 2, 2, 4, True, False, False),     # full bias + dbias
+    (1024, 1024, 128, 4, 4, 4, True, True, True),      # everything at once
+    (2048, 2048, 64, 2, 2, 0, False, True, False),     # segments
+    (1024, 1024, 64, 2, 2, 0, False, False, True),     # dropout
+]
+
+
+@pytest.mark.parametrize("row", _PLAN_TABLE, ids=lambda r: "x".join(
+    str(int(x)) for x in r))
+def test_tile_plan_is_legal(row):
+    sq, sk, d, qb, vb, bias_b, dbias, segs, drop = row
+    plan = fa.tile_plan(sq, sk, d, qb, qb, vb, bias_bytes=bias_b,
+                        dbias=dbias, segments=segs, dropout=drop)
+    assert plan == fa.tile_plan(sq, sk, d, qb, qb, vb, bias_bytes=bias_b,
+                                dbias=dbias, segments=segs, dropout=drop)
+    for kernel, (bq, bk) in zip(("fwd", "dq", "dkv"), plan):
+        for s, blk in ((sq, bq), (sk, bk)):
+            if s <= 128:
+                assert blk == s          # min(block, S): the whole length
+            else:
+                # Mosaic: a block's sublane dim a multiple of 8 (16 for
+                # bf16), its lane dim (bk of a bias or segment block) a
+                # multiple of 128. It divides the length padded to 128, so
+                # it pads no more than 128 x 128 did
+                assert blk % 128 == 0 and blk <= fa._MAX_BLOCK
+                assert (-(-s // 128) * 128) % blk == 0
+        assert fa._vmem_bytes(kernel, bq, bk, d, qb, qb, vb, bias_b,
+                              dbias and kernel == "dq", segs, drop) \
+            <= fa._VMEM_BUDGET
+    # the backward's two kernels share operands padded once: both tiles
+    # divide the padded lengths
+    for s, a, b_ in ((sq, plan.dq[0], plan.dkv[0]),
+                     (sk, plan.dq[1], plan.dkv[1])):
+        if s > 128:
+            assert (-(-s // 128) * 128) % math.lcm(a, b_) == 0
+
+
+def test_tile_plan_of_the_benchmark_cells():
+    """The tiles PERF.md ("PR 26", the sweep on the chip) records as the
+    fastest measured at the two cells' attention shapes."""
+    big = (1024, 1024)
+    # gpt2s-train-s1024: s1024, head 64, bf16
+    assert fa.tile_plan(1024, 1024, 64, 2, 2, 2) == fa.TilePlan(big, big, big)
+    # mistral7b-l2-train-s4096: s4096, head 128, q and k float32, v bf16
+    assert fa.tile_plan(4096, 4096, 128, 4, 4, 2) == \
+        fa.TilePlan(big, big, big)
+
+
+def test_tile_plan_shrinks_under_a_tight_budget():
+    roomy = fa.tile_plan(4096, 4096, 128, 4, 4, 2)
+    tight = fa.tile_plan(4096, 4096, 128, 4, 4, 2, vmem_budget=2 << 20)
+    for (bq, bk), (tq, tk) in zip(roomy, tight):
+        assert tq * tk < bq * bk
+    assert fa.tile_plan(4096, 4096, 128, 4, 4, 2, vmem_budget=1) == \
+        fa.TilePlan((128, 128), (128, 128), (128, 128))
+
+
+def test_tile_plan_event_and_tally_once_per_lowering():
+    """One ``flash::tile_plan`` event and one tally count for each lowered
+    pallas_call: trace time only, nothing when the compiled step runs."""
+    from paddle_tpu.profiler import tracing
+
+    q, k, v = _mk(1, 512, 512, 2, 2, 64, seed=24)
+    scale = 1.0 / math.sqrt(64)
+    plan = fa._blocks(None, None, q, k, v, None, False, 0.0)
+    step = jax.jit(jax.grad(lambda q, k, v: flash_attention_pallas(
+        q, k, v, True, scale, True).sum(), (0, 1, 2)))
+    tracing.reset_tracing()
+    tracing.enable_tracing()
+    before = dict(fa.TILE_PLAN_TALLY)
+    try:
+        jax.block_until_ready(step(q, k, v))
+        events = [e for e in tracing.snapshot_events()
+                  if e["name"] == "flash::tile_plan"]
+        jax.block_until_ready(step(q, k, v))     # compiled: no new event
+        again = [e for e in tracing.snapshot_events()
+                 if e["name"] == "flash::tile_plan"]
+    finally:
+        tracing.disable_tracing()
+        tracing.reset_tracing()
+    want = {"flash_fwd": plan.fwd, "flash_bwd_dq": plan.dq,
+            "flash_bwd_dkv": plan.dkv}
+    assert len(again) == len(events) == 3
+    for ev in events:
+        a = ev["args"]
+        bq, bk = want.pop(a["kernel"])
+        assert (a["bq"], a["bk"]) == (bq, bk)
+        nq, nk = 512 // bq, 512 // bk
+        assert a["grid_steps"] == 2 * nq * nk
+        assert a["skipped_steps"] == 2 * sum(
+            1 for i in range(nq) for j in range(nk)
+            if j * bk > i * bq + bq - 1)
+        assert 0 < a["vmem_bytes"] <= fa._VMEM_BUDGET
+        key = (a["kernel"], bq, bk)
+        assert fa.TILE_PLAN_TALLY[key] == before.get(key, 0) + 1
+    assert not want
+
+
+def test_explicit_blocks_win_over_the_plan():
+    q, k, v = _mk(1, 512, 512, 2, 2, 64, seed=25)
+    scale = 1.0 / math.sqrt(64)
+
+    def lowered(bq, bk):
+        before = dict(fa.TILE_PLAN_TALLY)
+        jax.jit(jax.grad(lambda q: flash_attention_pallas(
+            q, k, v, True, scale, True, bq, bk).sum())).lower(q)
+        return {key for key, n in fa.TILE_PLAN_TALLY.items()
+                if n > before.get(key, 0)}
+
+    assert lowered(256, 128) == {(name, 256, 128) for name in (
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
+    assert lowered(4096, 4096) == {(name, 512, 512) for name in (
+        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}   # min(block, S)
+    with pytest.raises(ValueError, match="together"):
+        flash_attention_pallas(q, k, v, True, scale, True, 256, None)
+
+
+def test_tuned_blocks_cold_returns_the_plan():
+    """Autotune off (the default): no measured tile, so the dispatch hands
+    None on and the kernels lower with the plan's."""
+    q, k, v = _mk(1, 512, 512, 2, 2, 64, seed=26)
+    impl, bq, bk, out = fa._tuned_blocks(q, k, v, None, _SEED0, True, 0.125,
+                                         0.0, True)
+    assert (impl, bq, bk, out) == ("pallas", None, None, None)
+    plan = fa._blocks(bq, bk, q, k, v, None, False, 0.0)
+    assert plan == fa.tile_plan(512, 512, 64, 4, 4, 4)
+    _flags.set_flags({"pallas_force_interpret": True})
+    before = dict(fa.TILE_PLAN_TALLY)
+    try:
+        jax.jit(lambda q: fa._attention_pallas(
+            q, k, v, None, True, 0.125, 0.0, None)).lower(q)
+    finally:
+        _flags.set_flags({"pallas_force_interpret": False})
+    assert fa.TILE_PLAN_TALLY[("flash_fwd",) + plan.fwd] == \
+        before.get(("flash_fwd",) + plan.fwd, 0) + 1

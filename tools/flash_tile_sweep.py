@@ -1,0 +1,225 @@
+"""flash_tile_sweep.py -- device time of the three flash kernels over tiles.
+
+    chiprun -- python3 tools/flash_tile_sweep.py            # needs a TPU
+    python3 tools/flash_tile_sweep.py --compile-only        # here: does Mosaic
+                                                            # take each tile?
+
+Times ``flash_fwd``, ``flash_bwd_dq`` and ``flash_bwd_dkv`` of
+``paddle_tpu/ops/pallas/flash_attention.py`` at the benchmark cells' own
+attention shapes, one layer's call each, over a grid of (block_q, block_k).
+Each (shape, kernel, tile) is one jitted program holding the one Mosaic call,
+named so that the profile's "XLA Modules" line tells the runs apart; the time
+is the device duration of the ``flash_*`` "XLA Ops" event inside each run (what
+the benchmark's readers sum), the median of ``--reps`` runs under
+``jax.profiler``. The
+table goes to stdout and, whole, to ``chiprun_out/flash_tile_sweep.json``.
+``tile_plan``'s preference order (PERF.md, "PR 26") was read off this table;
+run it again on a new chip generation before trusting that order there.
+
+``--compile-only`` compiles every tile for a described v5e (no chip, no
+times): what the chip's compiler refuses, it refuses here.
+"""
+from __future__ import annotations
+
+import argparse
+import json
+import os
+import statistics
+import sys
+import tempfile
+
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+# name: (B*Hq, B*Hk, heads q, heads kv, S, D, q/k dtype, v dtype)
+SHAPES = {
+    # gpt2s-train-s1024: b32, 12 heads of 64, bf16
+    "gpt2": (384, 384, 12, 12, 1024, 64, "bfloat16", "bfloat16"),
+    # mistral7b-l2-train-s4096: b4, 32/8 heads of 128; RoPE's float32 tables
+    # leave q and k float32, v stays bf16 (models/llama.py)
+    "mistral": (128, 32, 32, 8, 4096, 128, "float32", "bfloat16"),
+}
+TILES = ((128, 128), (256, 256), (256, 512), (512, 256), (512, 512),
+         (256, 1024), (1024, 256), (512, 1024), (1024, 512), (1024, 1024))
+KERNELS = ("fwd", "dq", "dkv")
+
+
+def build(shape, kernel, bq, bk):
+    """(fn, abstract args) of one kernel call at one tile."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.ops.pallas import flash_attention as fa
+
+    bhq, bhk, hq, hk, s, d, qk_dt, v_dt = SHAPES[shape]
+    scale = float(d) ** -0.5
+    qk_dt, v_dt = jnp.dtype(qk_dt), jnp.dtype(v_dt)
+    q = jax.ShapeDtypeStruct((bhq, s, d), qk_dt)
+    k = jax.ShapeDtypeStruct((bhk, s, d), qk_dt)
+    v = jax.ShapeDtypeStruct((bhk, s, d), v_dt)
+    col = jax.ShapeDtypeStruct((bhq, s, 1), jnp.float32)
+    maps = {"rate": 0.0}
+    if kernel == "fwd":
+        def fn(q, k, v):
+            return fa._fwd(q, k, v, None, None, hq, hk, True, scale, 0, s,
+                           bq, bk, maps, False)
+        return fn, (q, k, v)
+    impl = fa._bwd_dq if kernel == "dq" else fa._bwd_dkv
+
+    def fn(q, k, v, do, lse, delta):
+        return impl(q, k, v, do, lse, delta, None, None, True, scale, 0, s,
+                    bq, bk, maps, False, None, None, hq, hk)
+    return fn, (q, k, v, q, col, col)   # do has the output's dtype: q's
+
+
+def compile_only(shapes, tiles):
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    import jax
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    topo = topologies.get_topology_desc(platform="tpu",
+                                        topology_name="v5e:2x2")
+    chip = SingleDeviceSharding(topo.devices[0])
+    rows = []
+    for shape in shapes:
+        for kernel in KERNELS:
+            for bq, bk in tiles:
+                fn, args = build(shape, kernel, bq, bk)
+                args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
+                        for a in args]
+                row = {"shape": shape, "kernel": kernel, "bq": bq, "bk": bk}
+                try:
+                    jax.jit(fn).lower(*args).compile()
+                    row["compiles"] = True
+                except Exception as e:  # noqa: BLE001 -- the refusal is the result
+                    row["compiles"] = False
+                    row["error"] = str(e).strip().splitlines()[-1][:200]
+                rows.append(row)
+                print(json.dumps(row), flush=True)
+    return rows
+
+
+def measure(shapes, tiles, reps):
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from benchmarks.harness import trace as _trace
+
+    if jax.default_backend() != "tpu":
+        raise SystemExit(f"needs a TPU, found {jax.default_backend()!r} "
+                         "(use --compile-only here)")
+    rows = []
+    for shape in shapes:
+        bhq, bhk, hq, hk, s, d, qk_dt, v_dt = SHAPES[shape]
+        rng = np.random.RandomState(0)
+
+        def rand(n, dt):
+            return jnp.asarray(rng.standard_normal((n, s, d)) * 0.5,
+                               jnp.dtype(dt))
+        q, k, v, do = rand(bhq, qk_dt), rand(bhk, qk_dt), rand(bhk, v_dt), \
+            rand(bhq, qk_dt)
+        fwd0, _ = build(shape, "fwd", 128, 128)
+        out, lse = jax.jit(fwd0)(q, k, v)
+        delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
+                        axis=-1, keepdims=True)
+        lse3 = lse[..., None]
+        del out
+        args = {"fwd": (q, k, v), "dq": (q, k, v, do, lse3, delta),
+                "dkv": (q, k, v, do, lse3, delta)}
+        jitted = {}
+        for kernel in KERNELS:
+            for bq, bk in tiles:
+                fn, _ = build(shape, kernel, bq, bk)
+                fn.__name__ = f"sweep_{shape}_{kernel}_{bq}x{bk}"
+                row = {"shape": shape, "kernel": kernel, "bq": bq, "bk": bk}
+                rows.append(row)
+                try:
+                    j = jax.jit(fn)
+                    jax.block_until_ready(j(*args[kernel]))   # compile
+                    jitted[fn.__name__] = (j, kernel, row)
+                except Exception as e:  # noqa: BLE001 -- a refused tile is a row
+                    row["error"] = str(e).strip().splitlines()[-1][:200]
+        with tempfile.TemporaryDirectory() as tmp:
+            jax.profiler.start_trace(tmp)
+            for j, kernel, _ in jitted.values():
+                for _ in range(reps):
+                    r = j(*args[kernel])
+                jax.block_until_ready(r)
+            jax.profiler.stop_trace()
+            events = _trace.load_events(_trace.find_xplane(tmp))
+        # a run of a program is one "XLA Modules" event; the Mosaic call in
+        # it is the "XLA Ops" event named flash_* inside that interval
+        # (what the benchmark's readers sum). Both are kept: the module's
+        # time also holds what XLA adds around the call.
+        module_ms, kernel_ms = {}, {}
+        for key, evs in events.items():
+            if not key.endswith("|" + _trace.MODULE_LINE):
+                continue
+            ops = sorted((st, dur) for name, st, dur in events.get(
+                key[:-len(_trace.MODULE_LINE)] + _trace.OP_LINE, [])
+                if "flash_" in name.partition(" = ")[0])
+            for name, st, dur in evs:
+                name = name.split("(")[0]
+                module_ms.setdefault(name, []).append(dur / 1e6)
+                kernel_ms.setdefault(name, []).append(sum(
+                    d for s0, d in ops if st <= s0 and s0 + d <= st + dur)
+                    / 1e6)
+        for name, (_, _, row) in jitted.items():
+            got = kernel_ms.get("jit_" + name, [])
+            if got and min(got) > 0:
+                row["ms"] = statistics.median(got)
+                row["module_ms"] = statistics.median(module_ms["jit_" + name])
+                row["runs"] = len(got)
+        for row in rows:
+            if row["shape"] == shape:
+                print(json.dumps(row), flush=True)
+    return rows
+
+
+def table(rows):
+    out = []
+    for shape in sorted({r["shape"] for r in rows}):
+        out.append(f"\n{shape}: ms a call (device, median)")
+        out.append("tile        " + "".join(f"{k:>10}" for k in KERNELS))
+        tiles = []
+        for r in rows:
+            if r["shape"] == shape and (r["bq"], r["bk"]) not in tiles:
+                tiles.append((r["bq"], r["bk"]))
+        for bq, bk in tiles:
+            cells = []
+            for kernel in KERNELS:
+                ms = [r.get("ms") for r in rows if r["shape"] == shape
+                      and r["kernel"] == kernel
+                      and (r["bq"], r["bk"]) == (bq, bk)]
+                if ms and ms[0] is not None:
+                    cells.append(f"{ms[0]:10.3f}")
+                else:
+                    cells.append(f"{'-':>10}")
+            out.append(f"{bq:>5}x{bk:<5} " + "".join(cells))
+    return "\n".join(out)
+
+
+def main(argv=None):
+    ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
+    ap.add_argument("--shapes", default=",".join(SHAPES))
+    ap.add_argument("--tiles", default=",".join(f"{a}x{b}" for a, b in TILES))
+    ap.add_argument("--reps", type=int, default=5)
+    ap.add_argument("--compile-only", action="store_true")
+    ap.add_argument("--out", default="chiprun_out/flash_tile_sweep.json")
+    a = ap.parse_args(argv)
+    shapes = a.shapes.split(",")
+    tiles = [tuple(int(x) for x in t.split("x")) for t in a.tiles.split(",")]
+    if a.compile_only:
+        rows = compile_only(shapes, tiles)
+    else:
+        rows = measure(shapes, tiles, a.reps)
+        print(table(rows))
+    os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
+    with open(a.out, "w") as f:
+        json.dump(rows, f, indent=1)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

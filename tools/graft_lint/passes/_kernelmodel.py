@@ -142,6 +142,10 @@ class PallasCall:
     out_shapes: Optional[List[OutShape]] = None
     scratch: Optional[List[Scratch]] = None
     interpret: Optional[ast.expr] = None
+    # scoped-VMEM limit of the call: Mosaic's default, the int a
+    # ``CompilerParams(vmem_limit_bytes=...)`` provably sets, or None where
+    # a limit is set that cannot be evaluated
+    vmem_limit: Optional[int] = VMEM_BYTES
     operands: Optional[List[ast.expr]] = None   # args of the outer call
     enclosing: Optional[ast.AST] = None   # enclosing FunctionDef
     env: Dict[str, ast.expr] = field(default_factory=dict)
@@ -267,11 +271,39 @@ class ModuleKernelModel:
         pc.out_shapes = self._out_shapes(kw.get("out_shape"), env)
         pc.scratch = self._scratch(kw.get("scratch_shapes"), env)
         pc.interpret = kw.get("interpret")
+        pc.vmem_limit = self._vmem_limit(kw.get("compiler_params"), env)
 
         outer = self.parents.get(id(call))
         if isinstance(outer, ast.Call) and outer.func is call:
             pc.operands = list(outer.args)
         return pc
+
+    def _vmem_limit(self, expr: Optional[ast.expr],
+                    env: Dict[str, ast.expr]) -> Optional[int]:
+        """The call's scoped-VMEM limit (see ``PallasCall.vmem_limit``).
+        ``compiler_params=`` is read inline, through a local name, or
+        through a module-level helper that returns a ``CompilerParams``."""
+        expr = self._deref(expr, env)
+        if expr is None:
+            return VMEM_BYTES
+        if not isinstance(expr, ast.Call):
+            return None
+        if callee_name(expr) != "CompilerParams":
+            helper = self.defs.get(callee_name(expr) or "")
+            built = [r.value for r in ast.walk(helper)
+                     if isinstance(r, ast.Return)
+                     and isinstance(r.value, ast.Call)
+                     and callee_name(r.value) == "CompilerParams"] \
+                if helper is not None else []
+            if len(built) != 1:
+                return None
+            expr, env = built[0], self._env(helper)
+        limit = {k.arg: k.value for k in expr.keywords}.get(
+            "vmem_limit_bytes")
+        if limit is None or (isinstance(limit, ast.Constant)
+                             and limit.value is None):
+            return VMEM_BYTES
+        return self.eval_int(limit, env)
 
     def _resolve_kernel(self, expr: ast.expr
                         ) -> Tuple[str, Optional[ast.AST]]:

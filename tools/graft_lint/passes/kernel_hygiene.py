@@ -59,8 +59,9 @@ class KernelHygienePass(LintPass):
                  "sum/mean of a provably bf16/fp16 value) — accumulate "
                  "in float32",
         "GL905": "estimated VMEM footprint of the blocks (+scratch, "
-                 "in/out double-buffered) exceeds ~75% of the 16 "
-                 "MiB/core budget",
+                 "in/out double-buffered) exceeds ~75% of the call's "
+                 "scoped-VMEM limit (16 MiB unless CompilerParams sets "
+                 "vmem_limit_bytes)",
         "GL906": "interpret/backend selection computed locally in a "
                  "pallas_call module — route through the shared "
                  "paddle_tpu/ops/pallas/common.py helper "
@@ -522,14 +523,19 @@ class KernelHygienePass(LintPass):
             for d in sc.shape:
                 nbytes *= d
             total += nbytes
-        budget = int(VMEM_BYTES * 0.75)
-        if total > budget:
+        if pc.vmem_limit is None:
+            return          # a limit is set that the model cannot evaluate
+        if total > int(pc.vmem_limit * 0.75):
+            raised = pc.vmem_limit != VMEM_BYTES
             findings.append(self._finding(
                 "GL905", pc.path, pc.line,
                 f"estimated VMEM footprint {total / (1 << 20):.1f} MiB "
                 "(literal in/out blocks double-buffered + scratch) "
-                f"exceeds 75% of the 16 MiB/core budget — shrink the "
-                "block tiling",
+                "exceeds 75% of the "
+                + (f"{pc.vmem_limit / (1 << 20):.0f} MiB the call's "
+                   "vmem_limit_bytes sets" if raised
+                   else "16 MiB/core budget")
+                + " — shrink the block tiling",
                 symbol=f"{self._site(pc)}.pallas_call"))
 
     # -- GL906: interpret-mode drift -----------------------------------
