@@ -9,6 +9,12 @@ means, any failure making the exit code non-zero:
   kernels    every Pallas family compiled by Mosaic (interpret=False on the
              chip), forward and backward, against its XLA twin at the
              shapes the main path uses
+  laguna     Laguna-XS.2 as laguna-xs2-train-s8192 runs it (5 layers, 32 of
+             256 experts held, batch 2 x 8192) through create_train_step
+             driven by run_steps: the windowed flash kernels and the
+             grouped matmuls are in the lowered step, three steps on one
+             batch give a finite falling loss, and routing_stats of that
+             batch is printed
   train      GPT-2 small, seq 1024, batch 8, bf16 params, through
              create_train_step(donate=True) driven by run_steps: loss
              finite and falling, no compile after the first step, the
@@ -81,7 +87,8 @@ XLA_TWIN_SCORE_BYTES = 2 << 30
 
 # -- presets ----------------------------------------------------------------
 # flash cases: (name, batch, seq, q heads, kv heads, head_dim, dropout),
-# bf16 throughout; an eighth entry gives q and k another dtype
+# bf16 throughout; an eighth entry gives q and k another dtype, a ninth a
+# window
 
 CHIP = {
     "kernels": {
@@ -96,10 +103,18 @@ CHIP = {
             ("fa_cell_gpt2s_b32_s1024_h12_d64", 32, 1024, 12, 12, 64, 0.0),
             ("fa_cell_mistral_b4_s4096_h32kv8_d128_f32qk", 4, 4096, 32, 8,
              128, 0.0, "float32"),
+            # laguna-xs2-train-s8192: a window layer (flash_win_*) and a
+            # full layer
+            ("fa_cell_laguna_b2_s8192_h64kv8_d128_w512", 2, 8192, 64, 8,
+             128, 0.0, "bfloat16", 512),
+            ("fa_cell_laguna_b2_s8192_h48kv8_d128", 2, 8192, 48, 8, 128,
+             0.0),
         ],
         "norm_rows": 8192, "norm_cols": (768, 4096),
         "ce": [(8192, 50304), (8192, 32000)],
     },
+    "laguna": {"tiny": False, "batch": 2, "seq": 8192, "steps": 3,
+               "lr": 3e-4},
     "train": {"model": "gpt2_small", "batch": 8, "seq": 1024, "steps": 8,
               "lr": 3e-4},
     "serve": {"model": "gpt2_small", "max_slots": 4, "page_len": 128,
@@ -120,10 +135,12 @@ TINY = {
             ("fa_mha_tiny", 1, 256, 2, 2, 64, 0.0),
             ("fa_mha_tiny_dropout", 1, 128, 2, 2, 64, 0.1),
             ("fa_gqa_tiny", 1, 128, 4, 2, 128, 0.0),
+            ("fa_gqa_window_tiny", 1, 256, 6, 2, 64, 0.0, "bfloat16", 100),
         ],
         "norm_rows": 16, "norm_cols": (128, 256),
         "ce": [(16, 512), (16, 384)],
     },
+    "laguna": {"tiny": True, "batch": 2, "seq": 32, "steps": 3, "lr": 1e-2},
     "train": {"model": "gpt2_tiny", "batch": 2, "seq": 128, "steps": 4,
               "lr": 1e-2},
     "serve": {"model": "gpt2_tiny", "max_slots": 4, "page_len": 16,
@@ -215,8 +232,9 @@ def kernel_cases(p, interpret):
     rng = np.random.RandomState(SEED)
     bf16 = jnp.bfloat16
 
-    for name, b, s, hq, hk, d, rate, *qk_dtype in p["flash"]:
-        qk_dtype = jnp.dtype(qk_dtype[0]) if qk_dtype else bf16
+    for name, b, s, hq, hk, d, rate, *more in p["flash"]:
+        qk_dtype = jnp.dtype(more[0]) if more else bf16
+        window = more[1] if len(more) > 1 else None
         q = jnp.asarray(rng.randn(b, s, hq, d) * 0.5, qk_dtype)
         k = jnp.asarray(rng.randn(b, s, hk, d) * 0.5, qk_dtype)
         v = jnp.asarray(rng.randn(b, s, hk, d) * 0.5, bf16)
@@ -225,20 +243,24 @@ def kernel_cases(p, interpret):
         seed = seed_from_key(key)
 
         # the XLA twin draws the same (seed, position)-hashed mask
-        def twin(q, k, v, _r=rate, _s=scale, _key=key):
+        def twin(q, k, v, _r=rate, _s=scale, _key=key, _w=window):
             return _attention_xla(q, k, v, None, True, _s, _r,
-                                  _key if _r > 0.0 else None)
+                                  _key if _r > 0.0 else None, _w)
 
-        if rate == 0.0 and b * hq * s * s * 4 > XLA_TWIN_SCORE_BYTES:
+        if rate == 0.0 and hq * s * s * 4 > XLA_TWIN_SCORE_BYTES:
+            # nor does one row's S x S scores of every head fit: a head
+            # at a time
+            twin = functools.partial(_by_head, twin)
+        elif rate == 0.0 and b * hq * s * s * 4 > XLA_TWIN_SCORE_BYTES:
             # attention is independent across the batch: the twin takes
             # one row at a time, so its S x S scores fit beside the kernel
             twin = functools.partial(_by_batch_row, twin)
         yield (name,
                # no blocks given: the tiles are the kernels' own plan, as
                # on the training path
-               lambda q, k, v, _r=rate, _s=scale, _seed=seed:
+               lambda q, k, v, _r=rate, _s=scale, _seed=seed, _w=window:
                flash_attention_ext(q, k, v, None, _seed, None, None, True,
-                                   _s, _r, None, None, interpret),
+                                   _s, _r, None, None, interpret, _w),
                twin, (q, k, v), 3)
 
     rows = p["norm_rows"]
@@ -268,6 +290,26 @@ def _by_batch_row(fn, q, k, v):
     import jax
     return jax.lax.map(lambda r: fn(r[0][None], r[1][None], r[2][None])[0],
                        (q, k, v))
+
+
+def _by_head(fn, q, k, v):
+    """``fn`` one (row, query head) at a time, each with its kv head, and
+    nothing kept between them for the backward pass but the inputs."""
+    import jax
+    import jax.numpy as jnp
+    b, s, hq, d = q.shape
+    rep = hq // k.shape[2]
+
+    def heads(x, times):
+        return jnp.repeat(x.transpose(0, 2, 1, 3), times, axis=1).reshape(
+            b * hq, s, d)
+
+    def one(r):
+        qh, kh, vh = (x[None, :, None] for x in r)
+        return fn(qh, kh, vh)[0, :, 0]
+    out = jax.lax.map(jax.checkpoint(one),
+                      (heads(q, 1), heads(k, rep), heads(v, rep)))
+    return out.reshape(b, hq, s, d).transpose(0, 2, 1, 3)
 
 
 def fwd_and_vjp(fn, n_diff):
@@ -309,6 +351,71 @@ def leg_kernels(p) -> dict:
         raise AssertionError("kernels: " + "; ".join(failures))
     return {"interpret": interpret, "cases": len(errors),
             "rel_err": errors}
+
+
+# -- leg: laguna ------------------------------------------------------------
+
+def leg_laguna(p) -> dict:
+    """The decoder of laguna-xs2-train-s8192 (BENCHMARK.json) through the
+    trainer: published widths, 5 layers of the pattern, experts 0-31 of 256
+    and an eighth of the vocabulary here, the layer body recomputed."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models import (LagunaConfig, LagunaForCausalLM,
+                                   create_train_step, laguna_tiny, run_steps)
+
+    paddle.seed(SEED)
+    if p["tiny"]:
+        cfg = laguna_tiny(experts_held=(4, 4), use_recompute=True)
+    else:
+        cfg = LagunaConfig(
+            vocab_size=12544, layer_types=LagunaConfig.layer_types[:5],
+            mlp_layer_types=LagunaConfig.mlp_layer_types[:5],
+            num_heads_per_layer=LagunaConfig.num_heads_per_layer[:5],
+            experts_held=(0, 32), use_recompute=True)
+    model = LagunaForCausalLM(cfg).bfloat16()
+    model.train()
+    opt = paddle.optimizer.AdamW(learning_rate=p["lr"], weight_decay=0.01,
+                                 parameters=model.parameters())
+    rng = np.random.RandomState(SEED)
+    ids = jnp.asarray(rng.randint(0, cfg.vocab_size,
+                                  (p["batch"], p["seq"] + 1)), jnp.int32)
+    x, y = ids[:, :-1], ids[:, 1:]
+    # off the step's path, and before the step consumes the weights
+    routing = model.routing_stats(x)
+    sparse = sum(m == "sparse" for m in cfg.mlp_layer_types)
+    if len(routing) != sparse or any(
+            r["assignments_here"] < 1 for r in routing):
+        raise AssertionError(f"laguna: routing_stats {routing}")
+    step, params, opt_state = create_train_step(model, opt,
+                                                donate="consume")
+    key = jax.random.key(SEED)
+    text = step.lower(params, opt_state, key, x, y, p["lr"]).as_text()
+    names = {n: text.count(n) for n in
+             ("flash_win_fwd", "flash_win_bwd_dq", "flash_win_bwd_dkv",
+              "moe_gmm_fwd", "moe_gmm_bwd_x", "moe_gmm_bwd_w")}
+    if on_chip() and not all(names.values()):
+        raise AssertionError(f"laguna: kernels missing from the lowered "
+                             f"step: {names}")
+    params, opt_state, first = run_steps(
+        step, params, opt_state, [(x, y)], key=key, lr=p["lr"])
+    with compile_watch() as watch:
+        params, opt_state, rest = run_steps(
+            step, params, opt_state, [(x, y)] * (p["steps"] - 1), key=key,
+            lr=p["lr"], start_step=1)
+    losses = [float(v) for v in first + rest]
+    if watch["compiles"]:
+        raise AssertionError(f"laguna: {watch['compiles']} compile(s) "
+                             "after the first step")
+    if not (np.isfinite(losses).all() and losses[-1] < losses[0]
+            and max(losses[1:]) < losses[0]):
+        raise AssertionError(f"laguna: loss not finite and falling on one "
+                             f"repeated batch: {losses}")
+    return {"losses": [round(v, 4) for v in losses],
+            "kernels_in_step": names, "routing_stats": routing,
+            "mosaic_calls_in_step": text.count("tpu_custom_call")}
 
 
 # -- leg: train -------------------------------------------------------------
@@ -592,8 +699,8 @@ def leg_four_chip(p) -> dict:
 
 # -- driver -----------------------------------------------------------------
 
-LEGS = {"kernels": leg_kernels, "train": leg_train, "serve": leg_serve,
-        "four_chip": leg_four_chip}
+LEGS = {"kernels": leg_kernels, "laguna": leg_laguna, "train": leg_train,
+        "serve": leg_serve, "four_chip": leg_four_chip}
 
 
 def run_legs(preset, names) -> bool:

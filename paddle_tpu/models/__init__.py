@@ -4,6 +4,8 @@ from .gpt import (GPTConfig, GPTModel, GPTForCausalLM, create_train_step,
 from .llama import (LlamaConfig, LlamaForCausalLM, llama_7b, llama_13b,  # noqa: F401
                     llama_tiny, llama_param_spec, llama_fsdp_spec,
                     llama_pipeline_model)
+from .laguna import (LagunaConfig, LagunaForCausalLM,  # noqa: F401
+                     laguna_rope_tables, laguna_tiny)
 from .decode import (ContiguousKV, decode_attention,  # noqa: F401
                      init_contiguous_cache)
 from .trainer import (create_multistep_train_step,  # noqa: F401

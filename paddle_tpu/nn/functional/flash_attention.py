@@ -29,11 +29,25 @@ __all__ = ["flash_attention", "scaled_dot_product_attention",
 ATTENTION_SCOPE = "attention"
 
 
+def _visible(Sq, Sk, window):
+    """(Sq, Sk) bool: at or below the (Sk - Sq)-offset diagonal and, with a
+    ``window``, fewer than ``window`` keys behind it."""
+    mask = jnp.tril(jnp.ones((Sq, Sk), bool), k=Sk - Sq)
+    if window is not None:
+        mask &= ~jnp.tril(jnp.ones((Sq, Sk), bool), k=Sk - Sq - int(window))
+    return mask
+
+
 @register_op_impl("flash_attention", "xla")
-def _attention_xla(q, k, v, bias, causal, scale, dropout_p, dropout_key):
-    """Reference XLA attention: [B, S, H, D] layout, fp32 softmax."""
+def _attention_xla(q, k, v, bias, causal, scale, dropout_p, dropout_key,
+                   window=None):
+    """Reference XLA attention: [B, S, H, D] layout, fp32 softmax.
+    ``window`` (causal only): key j is visible to query i when ``j <= i``
+    and ``i - j < window``."""
     B, Sq, Hq, D = q.shape
     Sk, Hk = k.shape[1], k.shape[2]
+    if window is not None and not causal:
+        raise ValueError("a window goes with causal attention")
     if Hk != Hq:  # GQA: repeat kv heads
         rep = Hq // Hk
         k = jnp.repeat(k, rep, axis=2)
@@ -49,8 +63,7 @@ def _attention_xla(q, k, v, bias, causal, scale, dropout_p, dropout_key):
         logits = jnp.einsum("bqhd,bkhd->bhqk", q * scale, k,
                             preferred_element_type=jnp.float32)
         if causal:
-            mask = jnp.tril(jnp.ones((Sq, Sk), bool), k=Sk - Sq)
-            logits = jnp.where(mask, logits, -1e30)
+            logits = jnp.where(_visible(Sq, Sk, window), logits, -1e30)
         probs = jax.nn.softmax(logits, axis=-1)
         out = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(q.dtype), v,
                          preferred_element_type=jnp.float32)
@@ -58,8 +71,7 @@ def _attention_xla(q, k, v, bias, causal, scale, dropout_p, dropout_key):
     qf = q.astype(jnp.float32) * scale
     logits = jnp.einsum("bqhd,bkhd->bhqk", qf, k.astype(jnp.float32))
     if causal:
-        mask = jnp.tril(jnp.ones((Sq, Sk), bool), k=Sk - Sq)
-        logits = jnp.where(mask, logits, -1e30)
+        logits = jnp.where(_visible(Sq, Sk, window), logits, -1e30)
     if bias is not None:
         logits = logits + bias.astype(jnp.float32)
     probs = jax.nn.softmax(logits, axis=-1)
@@ -81,11 +93,13 @@ def _attention_xla(q, k, v, bias, causal, scale, dropout_p, dropout_key):
 
 def flash_attention(query, key, value, dropout=0.0, causal=False,
                     return_softmax=False, fixed_seed_offset=None, rng_name="",
-                    training=True, name=None):
+                    training=True, name=None, window=None):
     """q/k/v: [batch, seq, heads, head_dim] (the reference's flash-attn
     contract, ops.yaml:978). Returns (out, softmax_lse_placeholder) like the
     reference returns (out, softmax, softmax_lse, seed_offset) — softmax is
-    only returned when return_softmax (debug)."""
+    only returned when return_softmax (debug). ``window`` (None or a
+    length, causal only): a query sees itself and the ``window - 1`` keys
+    before it."""
     from ...core import random as _random
     scale = 1.0 / math.sqrt(query.shape[-1])
     dk = _random.default_generator.next_key() if (dropout > 0.0 and training) else None
@@ -94,9 +108,16 @@ def flash_attention(query, key, value, dropout=0.0, causal=False,
     def fn(q, k, v):
         with jax.named_scope(ATTENTION_SCOPE):
             return impl(q, k, v, None, causal, scale,
-                        dropout if training else 0.0, dk)
+                        dropout if training else 0.0, dk,
+                        **_window_arg(window))
     out = run_op("flash_attention", fn, (query, key, value))
     return out, None
+
+
+def _window_arg(window) -> dict:
+    """The keyword a windowed call adds: an implementation registered
+    without one keeps working for every call that has no window."""
+    return {} if window is None else {"window": int(window)}
 
 
 def _segments_from_cu(cu_seqlens, total):
@@ -195,9 +216,11 @@ def flash_attn_unpadded(query, key, value, cu_seqlens_q, cu_seqlens_k,
 
 def scaled_dot_product_attention(query, key, value, attn_mask=None,
                                  dropout_p=0.0, is_causal=False, training=True,
-                                 name=None):
+                                 name=None, window=None):
     """Parity: F.scaled_dot_product_attention (flash_attention.py:441) —
-    [B, S, H, D] layout, optional additive mask."""
+    [B, S, H, D] layout, optional additive mask. ``window`` (None or a
+    length, with ``is_causal``): key j is visible to query i when
+    ``j <= i`` and ``i - j < window``."""
     from ...core import random as _random
     scale = 1.0 / math.sqrt(query.shape[-1])
     dk = _random.default_generator.next_key() if (dropout_p > 0.0 and training) else None
@@ -206,13 +229,15 @@ def scaled_dot_product_attention(query, key, value, attn_mask=None,
         def fn(q, k, v, m):
             with jax.named_scope(ATTENTION_SCOPE):
                 return impl(q, k, v, m, is_causal, scale,
-                            dropout_p if training else 0.0, dk)
+                            dropout_p if training else 0.0, dk,
+                            **_window_arg(window))
         return run_op("flash_attention", fn, (query, key, value, attn_mask))
 
     def fn(q, k, v):
         with jax.named_scope(ATTENTION_SCOPE):
             return impl(q, k, v, None, is_causal, scale,
-                        dropout_p if training else 0.0, dk)
+                        dropout_p if training else 0.0, dk,
+                        **_window_arg(window))
     return run_op("flash_attention", fn, (query, key, value))
 
 

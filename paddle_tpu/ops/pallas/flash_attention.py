@@ -80,6 +80,10 @@ _LANES = 128
 # the names the device trace finds the kernels by (benchmarks/metrics)
 _KERNEL_NAMES = {"fwd": "flash_fwd", "dq": "flash_bwd_dq",
                  "dkv": "flash_bwd_dkv"}
+# a call with a window is another kernel to the trace's readers
+# (benchmarks/metrics/window_attn_roofline.py)
+_WIN_KERNEL_NAMES = {"fwd": "flash_win_fwd", "dq": "flash_win_bwd_dq",
+                     "dkv": "flash_win_bwd_dkv"}
 # one count per lowered pallas_call, by (kernel name, bq, bk): trace time
 # only, nothing per step
 TILE_PLAN_TALLY: collections.Counter = collections.Counter()
@@ -180,11 +184,12 @@ def dropout_keep_mask(seed, bh_total, sq, sk, rate):
 # ---------------------------------------------------------------------------
 
 def _hide(s, q_start, k_start, *, pad_keys, causal, offset, sk_real,
-          qseg_ref, kseg_ref, seg_causal):
+          qseg_ref, kseg_ref, seg_causal, window=None):
     """The (bq, bk) score block at (q_start, k_start) with what attention
     may not see set to -inf: padded key columns, what lies above the
-    (Sk - Sq)-offset causal diagonal, other segments. A term is built only
-    where it can hide something."""
+    (Sk - Sq)-offset causal diagonal, what lies ``window`` or more keys
+    below it, other segments. A term is built only where it can hide
+    something."""
     mask = None
     if pad_keys or causal:
         kidx = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -194,24 +199,84 @@ def _hide(s, q_start, k_start, *, pad_keys, causal, offset, sk_real,
         qidx = q_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         diag = kidx <= qidx + offset
         mask = diag if mask is None else mask & diag
+        if window is not None:
+            mask = mask & (kidx > qidx + np.int32(offset - window))
     if qseg_ref is not None:  # varlen packing: never across sequences
         seg = _seg_mask(qseg_ref[0], kseg_ref[0], seg_causal)
         mask = seg if mask is None else mask & seg
     return s if mask is None else jnp.where(mask, s, _NEG_INF)
 
 
-def _when_visible(body, q_start, k_start, bq, *, causal, offset):
+def _when_visible(body, q_start, k_start, bq, *, causal, offset, bk=None,
+                  window=None, inside=None):
     """Run ``body`` unless the score block at (q_start, k_start) lies wholly
-    above the causal diagonal: its first key column beyond the horizon of
-    its last query row."""
+    above the causal diagonal (its first key column beyond the horizon of
+    its last query row), wholly below the window's band (its last key
+    column ``window`` or more behind its first query row), or, on a band
+    grid, past the operand's end (``inside`` false)."""
     run = True
     if causal:
         run = k_start <= q_start + bq - 1 + offset
+    if window is not None:
+        run = jnp.logical_and(run, k_start + np.int32(bk - 1)
+                              > q_start + np.int32(offset - window))
+        run = jnp.logical_and(run, inside)
     pl.when(run)(body)
 
 
+def _band(bq, bk, offset, window, nq, nk, py=False):
+    """The band of a windowed causal call, in blocks: (k_first, k_last,
+    q_first, q_last). A query block ``qi`` sees the key blocks
+    ``k_first(qi) .. k_last(qi)``, a key block ``ki`` is seen by the query
+    blocks ``q_first(ki) .. q_last(ki)``. ``py`` gives them over Python
+    ints (the grid's extent, at trace time); otherwise all i32, for index
+    maps and kernel bodies, which lower through Mosaic."""
+    mx, mn = (max, min) if py else (jnp.maximum, jnp.minimum)
+    c = int if py else np.int32
+
+    def k_first(qi):
+        return mx(qi * c(bq) + c(offset - window + 1), c(0)) // c(bk)
+
+    def k_last(qi):
+        return mn(mx(qi * c(bq) + c(bq - 1 + offset), c(0)) // c(bk),
+                  c(nk - 1))
+
+    def q_first(ki):
+        return mx(ki * c(bk) - c(offset), c(0)) // c(bq)
+
+    def q_last(ki):
+        return mn(mx(ki * c(bk) + c(bk + window - 2 - offset), c(0))
+                  // c(bq), c(nq - 1))
+    return k_first, k_last, q_first, q_last
+
+
+def _band_extent(first, last, n):
+    """The most blocks any of the ``n`` outer blocks has in its band: the
+    inner extent of a windowed call's grid."""
+    return max(1, max(last(i) - first(i) + 1 for i in range(n)))
+
+
+def _band_grid(sweeps, window, bq, bk, offset, nq, nk):
+    """(inner extent of the grid, the kernels' names, the band's keywords of
+    the kernel body) of a call that ``sweeps`` "k" (fwd, dq: key blocks
+    inside a query block) or "q" (dkv): every block, or with a ``window`` the
+    band's."""
+    if window is None:
+        return (nk if sweeps == "k" else nq), _KERNEL_NAMES, {}
+    k_first, k_last, q_first, q_last = _band(bq, bk, offset, window, nq, nk,
+                                             py=True)
+    extent = _band_extent(k_first, k_last, nq) if sweeps == "k" \
+        else _band_extent(q_first, q_last, nk)
+    return extent, _WIN_KERNEL_NAMES, dict(window=window, nq_all=nq,
+                                           nk_all=nk)
+
+
 def _fwd_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, pad_keys,
-                has_bias, has_seg, seg_causal, rate):
+                has_bias, has_seg, seg_causal, rate, window=None, nq_all=None,
+                nk_all=None):
+    """``nk`` is the grid's extent over key blocks: all of them, or with a
+    ``window`` the band's (the step ``kj`` then works on key block
+    ``k_first(qi) + kj`` of ``nk_all``)."""
     scale = np.float32(scale)  # strong f64 scalars poison Mosaic under x64
     it = iter(refs)
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
@@ -224,11 +289,15 @@ def _fwd_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, pad_keys,
 
     bh = pl.program_id(0)
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    kj = pl.program_id(2)
+    ki, inside = kj, None
+    if window is not None:
+        ki = _band(bq, bk, offset, window, nq_all, nk_all)[0](qi) + kj
+        inside = ki < np.int32(nk_all)
     q_start = qi * bq
     k_start = ki * bk
 
-    @pl.when(ki == 0)
+    @pl.when(kj == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
@@ -246,7 +315,7 @@ def _fwd_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, pad_keys,
             s = s + bias_ref[0].astype(jnp.float32)
         s = _hide(s, q_start, k_start, pad_keys=pad_keys, causal=causal,
                   offset=offset, sk_real=sk_real, qseg_ref=qseg_ref,
-                  kseg_ref=kseg_ref, seg_causal=seg_causal)
+                  kseg_ref=kseg_ref, seg_causal=seg_causal, window=window)
 
         m_prev = m_ref[...]                                      # (bq, LANES)
         s_max = jnp.max(s, axis=1, keepdims=True)                # (bq, 1)
@@ -273,9 +342,10 @@ def _fwd_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, pad_keys,
             p_v.astype(v.dtype), v, preferred_element_type=jnp.float32)
         m_ref[...] = m_new
 
-    _when_visible(_body, q_start, k_start, bq, causal=causal, offset=offset)
+    _when_visible(_body, q_start, k_start, bq, causal=causal, offset=offset,
+                  bk=bk, window=window, inside=inside)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(kj == nk - 1)
     def _fin():
         l = l_ref[:, :1]
         safe_l = jnp.where(l == 0.0, _F1, l)
@@ -289,13 +359,17 @@ def _fwd_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, pad_keys,
                                _NEG_INF)
 
 
-def _key_block(causal, bq, bk, offset, nk):
+def _key_block(causal, bq, bk, offset, nk, window=None, nq=None):
     """``kblk(qi, ki)``: the key block step (qi, ki) of the fwd/dq grid
     asks for. A causally skipped step asks for the last block its query
-    block sees, which it already holds, so it moves no data. All i32: index
-    maps lower through Mosaic."""
+    block sees, which it already holds, so it moves no data. With a
+    ``window`` the grid's steps count from the band's first block. All i32:
+    index maps lower through Mosaic."""
     if not causal:
         return lambda qi, ki: ki
+    if window is not None:
+        k_first, k_last, _, _ = _band(bq, bk, offset, window, nq, nk)
+        return lambda qi, kj: jnp.minimum(k_first(qi) + kj, k_last(qi))
 
     def kblk(qi, ki):
         last = jnp.maximum(qi * np.int32(bq) + np.int32(bq - 1 + offset),
@@ -304,12 +378,17 @@ def _key_block(causal, bq, bk, offset, nk):
     return kblk
 
 
-def _query_block(causal, bq, bk, offset, nq):
+def _query_block(causal, bq, bk, offset, nq, window=None, nk=None):
     """``qblk(ki, qi)``: the query block step (ki, qi) of the dkv grid asks
     for. The steps before a key block's first visible query block ask for
-    that block already, so they move no data and the sweep starts loaded."""
+    that block already, so they move no data and the sweep starts loaded.
+    With a ``window`` the grid's steps count from that first block and end
+    with the band."""
     if not causal:
         return lambda ki, qi: qi
+    if window is not None:
+        _, _, q_first, q_last = _band(bq, bk, offset, window, nq, nk)
+        return lambda ki, qj: jnp.minimum(q_first(ki) + qj, q_last(ki))
 
     def qblk(ki, qi):
         first = jnp.maximum(ki * np.int32(bk) - np.int32(offset),
@@ -319,7 +398,7 @@ def _query_block(causal, bq, bk, offset, nq):
 
 
 def _call_params(kernel, semantics, q3, kx, vx, bias3, dbias, has_seg, rate,
-                 bq, bk, offset, causal):
+                 bq, bk, offset, causal, window=None):
     """``CompilerParams`` of one lowered call of ``kernel`` ("fwd", "dq",
     "dkv"), which it also records: a ``flash::tile_plan`` trace event and a
     count in ``TILE_PLAN_TALLY``. Mosaic's scoped VMEM default is 16 MiB;
@@ -335,11 +414,31 @@ def _call_params(kernel, semantics, q3, kx, vx, bias3, dbias, has_seg, rate,
     # blocks wholly above the diagonal: the steps causality skips
     skipped = sum(1 for qi in range(nq) for ki in range(nk)
                   if ki * bk > qi * bq + bq - 1 + offset) if causal else 0
-    name = _KERNEL_NAMES[kernel]
-    TILE_PLAN_TALLY[(name, bq, bk)] += 1
-    trace_event("flash::tile_plan", cat="kernel", kernel=name, bq=bq, bk=bk,
-                grid_steps=bhq * nq * nk, skipped_steps=bhq * skipped,
-                vmem_bytes=vmem)
+    if window is None:
+        name = _KERNEL_NAMES[kernel]
+        TILE_PLAN_TALLY[(name, bq, bk)] += 1
+        trace_event("flash::tile_plan", cat="kernel", kernel=name, bq=bq,
+                    bk=bk, grid_steps=bhq * nq * nk,
+                    skipped_steps=bhq * skipped, vmem_bytes=vmem)
+    else:
+        # the grid holds the band's steps only; of those, the ones past a
+        # block's own band (the band is narrower at the sequence's ends)
+        # are skipped in the kernel
+        k_first, k_last, q_first, q_last = _band(bq, bk, offset, window, nq,
+                                                 nk, py=True)
+        if kernel == "dkv":
+            steps = nk * _band_extent(q_first, q_last, nk)
+            run = sum(max(q_last(i) - q_first(i) + 1, 0) for i in range(nk))
+        else:
+            steps = nq * _band_extent(k_first, k_last, nq)
+            run = sum(max(k_last(i) - k_first(i) + 1, 0) for i in range(nq))
+        name = _WIN_KERNEL_NAMES[kernel]
+        TILE_PLAN_TALLY[(name, bq, bk)] += 1
+        trace_event("flash::tile_plan", cat="kernel", kernel=name, bq=bq,
+                    bk=bk, grid_steps=bhq * steps,
+                    skipped_steps=bhq * (steps - run), vmem_bytes=vmem,
+                    window=window,
+                    band_skipped_steps=bhq * (nq * nk - steps))
     limit = None
     if vmem > _VMEM_DEFAULT_LIMIT * 3 // 4:
         limit = min(int(vmem * 1.5), _VMEM_MAX_LIMIT)
@@ -348,19 +447,21 @@ def _call_params(kernel, semantics, q3, kx, vx, bias3, dbias, has_seg, rate,
 
 
 def _fwd(q3, k3, v3, bias3, seed, hq, hk, causal, scale, offset, sk_real,
-         bq, bk, bias_maps, interpret, qseg3=None, kseg3=None):
+         bq, bk, bias_maps, interpret, qseg3=None, kseg3=None, window=None):
     """q3: (B*Hq, Sq, D) padded; k3/v3: (B*Hk, Sk, D) padded; bias3:
     (Bb*Hb, Sqb, Sk_pad) or None; seed: (1,) i32 or None; qseg3/kseg3:
-    (B*Hq, Sq, 1) / (B*Hq, 1, Sk) i32 segment ids or None."""
+    (B*Hq, Sq, 1) / (B*Hq, 1, Sk) i32 segment ids or None. With a
+    ``window`` the grid's last dim holds the band's key blocks only."""
     bhq, sq, d = q3.shape
     sk = k3.shape[1]
-    nq, nk = sq // bq, sk // bk
+    nq, nk_all = sq // bq, sk // bk
+    nk, names, band = _band_grid("k", window, bq, bk, offset, nq, nk_all)
     grid = (bhq, nq, nk)
     kv_map = functools.partial(_kv_index, hq=hq, hk=hk)
     has_bias = bias3 is not None
     has_seg = qseg3 is not None
 
-    kblk = _key_block(causal, bq, bk, offset, nk)
+    kblk = _key_block(causal, bq, bk, offset, nk_all, window, nq)
 
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, _Z)),
@@ -389,7 +490,7 @@ def _fwd(q3, k3, v3, bias3, seed, hq, hk, causal, scale, offset, sk_real,
         _fwd_kernel, scale=scale, causal=causal, offset=offset,
         bq=bq, bk=bk, nk=nk, sk_real=sk_real, pad_keys=sk != sk_real,
         has_bias=has_bias, has_seg=has_seg,
-        seg_causal=bias_maps.get("seg_causal", False), rate=rate)
+        seg_causal=bias_maps.get("seg_causal", False), rate=rate, **band)
     out, lse = pl.pallas_call(
         kernel,
         grid=grid,
@@ -409,9 +510,9 @@ def _fwd(q3, k3, v3, bias3, seed, hq, hk, causal, scale, offset, sk_real,
         ],
         compiler_params=_call_params(
             "fwd", ("parallel", "parallel", "arbitrary"), q3, k3, v3, bias3,
-            False, has_seg, rate, bq, bk, offset, causal),
+            False, has_seg, rate, bq, bk, offset, causal, window),
         interpret=interpret,
-        name=_KERNEL_NAMES["fwd"],
+        name=names["fwd"],
     )(*args)
     return out, lse[..., 0]
 
@@ -493,7 +594,8 @@ def _bias_spec(maps, bq, bk, kblk=None, qblk=None):
 # ---------------------------------------------------------------------------
 
 def _dq_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, pad_keys,
-               has_bias, has_seg, seg_causal, emit_dbias, rate):
+               has_bias, has_seg, seg_causal, emit_dbias, rate, window=None,
+               nq_all=None, nk_all=None):
     scale = np.float32(scale)  # strong f64 scalars poison Mosaic under x64
     it = iter(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = (
@@ -508,10 +610,14 @@ def _dq_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, pad_keys,
 
     bh = pl.program_id(0)
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    kj = pl.program_id(2)
+    ki, inside = kj, None
+    if window is not None:
+        ki = _band(bq, bk, offset, window, nq_all, nk_all)[0](qi) + kj
+        inside = ki < np.int32(nk_all)
     q_start, k_start = qi * bq, ki * bk
 
-    @pl.when(ki == 0)
+    @pl.when(kj == 0)
     def _init():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
@@ -535,7 +641,7 @@ def _dq_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, pad_keys,
             s = s + bias_ref[0].astype(jnp.float32)
         s = _hide(s, q_start, k_start, pad_keys=pad_keys, causal=causal,
                   offset=offset, sk_real=sk_real, qseg_ref=qseg_ref,
-                  kseg_ref=kseg_ref, seg_causal=seg_causal)
+                  kseg_ref=kseg_ref, seg_causal=seg_causal, window=window)
         p = jnp.exp(s - lse_safe)                               # (bq, bk)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
@@ -549,15 +655,17 @@ def _dq_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, pad_keys,
         dq_acc[...] += jax.lax.dot(ds.astype(k.dtype), k,
                                    preferred_element_type=jnp.float32) * scale
 
-    _when_visible(_body, q_start, k_start, bq, causal=causal, offset=offset)
+    _when_visible(_body, q_start, k_start, bq, causal=causal, offset=offset,
+                  bk=bk, window=window, inside=inside)
 
-    @pl.when(ki == nk - 1)
+    @pl.when(kj == nk - 1)
     def _fin():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(*refs, scale, causal, offset, bq, bk, nq, rep, sk_real,
-                pad_keys, has_bias, has_seg, seg_causal, rate):
+                pad_keys, has_bias, has_seg, seg_causal, rate, window=None,
+                nq_all=None, nk_all=None):
     """Grid (B*Hk, nk, rep, nq): one kv-head block accumulates dk/dv over
     ALL rep q-heads of its group (GQA-native — no rep-expanded K/V in HBM
     and no post-kernel sum over q-head groups). rep == 1 is plain MHA.
@@ -578,13 +686,17 @@ def _dkv_kernel(*refs, scale, causal, offset, bq, bk, nq, rep, sk_real,
 
     ki = pl.program_id(1)
     r = pl.program_id(2)                  # q-head within the kv group
-    qi = pl.program_id(3)                 # q block
+    qj = pl.program_id(3)                 # q block (of the band, if any)
+    qi, inside = qj, None
+    if window is not None:
+        qi = _band(bq, bk, offset, window, nq_all, nk_all)[2](ki) + qj
+        inside = qi < np.int32(nq_all)
     # global q-head row — the dropout mask replay is per q-head (fwd hashes
     # with the q-head program index)
     bh = pl.program_id(0) * np.int32(rep) + r
     q_start, k_start = qi * bq, ki * bk
 
-    @pl.when(jnp.logical_and(r == 0, qi == 0))
+    @pl.when(jnp.logical_and(r == 0, qj == 0))
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
@@ -603,7 +715,7 @@ def _dkv_kernel(*refs, scale, causal, offset, bq, bk, nq, rep, sk_real,
             s = s + bias_ref[0].astype(jnp.float32)
         s = _hide(s, q_start, k_start, pad_keys=pad_keys, causal=causal,
                   offset=offset, sk_real=sk_real, qseg_ref=qseg_ref,
-                  kseg_ref=kseg_ref, seg_causal=seg_causal)
+                  kseg_ref=kseg_ref, seg_causal=seg_causal, window=window)
         p = jnp.exp(s - lse_safe)                               # (bq, bk)
         if rate > 0.0:
             keep = _keep_block(_mix_seed(seed_ref[0], bh), q_start, k_start,
@@ -627,10 +739,11 @@ def _dkv_kernel(*refs, scale, causal, offset, bq, bk, nq, rep, sk_real,
             preferred_element_type=jnp.float32) * scale          # (bk, d)
 
     # block contributes iff some query row sees some key col
-    _when_visible(_body, q_start, k_start, bq, causal=causal, offset=offset)
+    _when_visible(_body, q_start, k_start, bq, causal=causal, offset=offset,
+                  bk=bk, window=window, inside=inside)
 
     @pl.when(jnp.logical_and(r == np.int32(rep - 1),
-                             qi == np.int32(nq - 1)))
+                             qj == np.int32(nq - 1)))
     def _fin():
         dk_ref[0] = dk_acc[...].astype(dk_ref.dtype)
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
@@ -638,7 +751,7 @@ def _dkv_kernel(*refs, scale, causal, offset, bq, bk, nq, rep, sk_real,
 
 def _bwd_dq(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
             offset, sk_real, bq, bk, bias_maps, interpret, qseg3, kseg3,
-            hq, hk):
+            hq, hk, window=None):
     """dq (and, for a full per-(batch, head) bias, its (bq, bk) dbias
     tiles) on the forward's (bh, qi, ki) grid: q3/do3/lse3/delta3 per
     q-head (BHq, ...), kx/vx per KV head (BHk, Sk, D), read through the
@@ -654,8 +767,10 @@ def _bwd_dq(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
     # (B*Hq, Sq, Sk) — they take the bounded recompute path in _fa_bwd.
     emit_dbias = has_bias and bias_maps["full"]
     rate = bias_maps["rate"]
+    nk_all = nk
+    nk, names, band = _band_grid("k", window, bq, bk, offset, nq, nk_all)
 
-    kblk = _key_block(causal, bq, bk, offset, nk)
+    kblk = _key_block(causal, bq, bk, offset, nk_all, window, nq)
 
     in_specs = [
         pl.BlockSpec((1, bq, d), lambda bh, qi, ki: (bh, qi, _Z)),
@@ -697,7 +812,7 @@ def _bwd_dq(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
                           sk_real=sk_real, pad_keys=sk != sk_real,
                           has_bias=has_bias, has_seg=has_seg,
                           seg_causal=bias_maps.get("seg_causal", False),
-                          emit_dbias=emit_dbias, rate=rate),
+                          emit_dbias=emit_dbias, rate=rate, **band),
         grid=(bhq, nq, nk),
         in_specs=in_specs,
         out_specs=dq_out_specs if emit_dbias else dq_out_specs[0],
@@ -705,16 +820,16 @@ def _bwd_dq(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
         compiler_params=_call_params(
             "dq", ("parallel", "parallel", "arbitrary"), q3, kx, vx, bias3,
-            emit_dbias, has_seg, rate, bq, bk, offset, causal),
+            emit_dbias, has_seg, rate, bq, bk, offset, causal, window),
         interpret=interpret,
-        name=_KERNEL_NAMES["dq"],
+        name=names["dq"],
     )(*args)
     return dq_outs if emit_dbias else (dq_outs, None)
 
 
 def _bwd_dkv(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
              offset, sk_real, bq, bk, bias_maps, interpret, qseg3, kseg3,
-             hq, hk):
+             hq, hk, window=None):
     """dk/dv per KV head on the (kv-head, k-block, r, qi) grid: the
     (q-head-of-group, q-block) sweep as two AFFINE dims, all i32 (index
     maps lower through Mosaic), accumulated in-grid over the group's
@@ -727,11 +842,13 @@ def _bwd_dkv(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
     has_seg = qseg3 is not None
     rate = bias_maps["rate"]
     rep_i = np.int32(rep)
+    nq_all = nq
+    nq, names, band = _band_grid("q", window, bq, bk, offset, nq_all, nk)
 
     def qrow(bh, r):
         return bh * rep_i + r
 
-    qblk = _query_block(causal, bq, bk, offset, nq)
+    qblk = _query_block(causal, bq, bk, offset, nq_all, window, nk)
 
     def qside(last):
         return pl.BlockSpec(
@@ -767,7 +884,7 @@ def _bwd_dkv(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
                           sk_real=sk_real, pad_keys=sk != sk_real,
                           has_bias=has_bias, has_seg=has_seg,
                           seg_causal=bias_maps.get("seg_causal", False),
-                          rate=rate),
+                          rate=rate, **band),
         grid=(bhk, nk, rep, nq),
         in_specs=kq_specs,
         out_specs=[
@@ -782,15 +899,16 @@ def _bwd_dkv(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
                         pltpu.VMEM((bk, d), jnp.float32)],
         compiler_params=_call_params(
             "dkv", ("parallel", "parallel", "arbitrary", "arbitrary"), q3,
-            kx, vx, bias3, False, has_seg, rate, bq, bk, offset, causal),
+            kx, vx, bias3, False, has_seg, rate, bq, bk, offset, causal,
+            window),
         interpret=interpret,
-        name=_KERNEL_NAMES["dkv"],
+        name=names["dkv"],
     )(*kq_args)
 
 
 def _bwd_impl(q3, kx, vx, do3, lse, delta, bias3, seed, causal, scale,
               offset, sk_real, plan, bias_maps, interpret, qseg3=None,
-              kseg3=None, hq=None, hk=None):
+              kseg3=None, hq=None, hk=None, window=None):
     """Both backward kernels over operands padded to lengths that the dq
     and the dkv tile of ``plan`` divide. hq == hk is plain MHA. Returns
     (dq, dk (BHk), dv (BHk), dbias_blocks)."""
@@ -799,7 +917,7 @@ def _bwd_impl(q3, kx, vx, do3, lse, delta, bias3, seed, causal, scale,
     hk = hk if hk is not None else bhq
     common = (q3, kx, vx, do3, lse[..., None], delta[..., None], bias3,
               seed, causal, scale, offset, sk_real)
-    rest = (bias_maps, interpret, qseg3, kseg3, hq, hk)
+    rest = (bias_maps, interpret, qseg3, kseg3, hq, hk, window)
     dq, dbias_blocks = _bwd_dq(*common, *plan.dq, *rest)
     dk, dv = _bwd_dkv(*common, *plan.dkv, *rest)
     return dq, dk, dv, dbias_blocks
@@ -923,22 +1041,22 @@ def _vmem_bytes(kernel, bq, bk, d, q_bytes, k_bytes, v_bytes, bias_bytes,
     return 2 * blocks + scratch + temps * bq * bk * 4
 
 
-def _side_blocks(s):
+def _side_blocks(s, max_block=_MAX_BLOCK):
     """Block lengths one side of the score tile may take: the whole length
     where it is short (decode has Sq = 1), else the multiples of 128, up to
-    ``_MAX_BLOCK``, that divide the length padded to a multiple of 128 —
+    ``max_block``, that divide the length padded to a multiple of 128 —
     so no block pads more than 128 does, and any two of them divide one
     padded length (the backward's two kernels share their operands)."""
     if s <= _LANES:
         return (s,)
     n = _round_up(s, _LANES) // _LANES
-    return tuple(_LANES * m for m in range(1, _MAX_BLOCK // _LANES + 1)
+    return tuple(_LANES * m for m in range(1, max_block // _LANES + 1)
                  if n % m == 0)
 
 
 def tile_plan(sq, sk, d, q_bytes=2, k_bytes=2, v_bytes=2, *, bias_bytes=0,
               dbias=False, segments=False, dropout=False,
-              vmem_budget=_VMEM_BUDGET) -> TilePlan:
+              vmem_budget=_VMEM_BUDGET, window=None) -> TilePlan:
     """The tiles of the three kernels, from what a call can see at trace
     time: the lengths, the head dim, the operands' item sizes, whether a
     bias block (and its dbias tile), segment ids or dropout ride along,
@@ -946,8 +1064,16 @@ def tile_plan(sq, sk, d, q_bytes=2, k_bytes=2, v_bytes=2, *, bias_bytes=0,
     the lengths and whose estimate fits the budget (PERF.md, "PR 26": the
     sweep on the chip). Causal or not does not enter: the order was
     measured causal, where a large tile wastes most (the half of a
-    diagonal block above the diagonal), and it was the fastest there."""
-    qs, ks = _side_blocks(sq), _side_blocks(sk)
+    diagonal block above the diagonal), and it was the fastest there.
+
+    A ``window`` does enter: a (bq, bk) tile on the band computes
+    ``bq + window`` keys, rounded up to blocks, for each of its queries
+    and needs ``window`` of them, so the sides stop at the window rounded
+    up to 128 (at 1024 x 1024 a 512-wide band computes 4 times the scores
+    it needs, at 512 x 512 twice; PERF.md, "PR 28": the sweep)."""
+    side = _MAX_BLOCK if window is None else \
+        min(_MAX_BLOCK, _round_up(window, _LANES))
+    qs, ks = _side_blocks(sq, side), _side_blocks(sk, side)
 
     def pick(kernel):
         fits = [(bq, bk) for bq in qs for bk in ks
@@ -962,7 +1088,7 @@ def tile_plan(sq, sk, d, q_bytes=2, k_bytes=2, v_bytes=2, *, bias_bytes=0,
     return TilePlan(pick("fwd"), pick("dq"), pick("dkv"))
 
 
-def _blocks(block_q, block_k, q, k, v, bias, segments, rate):
+def _blocks(block_q, block_k, q, k, v, bias, segments, rate, window=None):
     """TilePlan of a call on q [B,Sq,Hq,D], k/v [B,Sk,Hk,D]: explicit
     ``block_q`` and ``block_k`` (cut to the length) go to all three
     kernels; both None, the plan chooses."""
@@ -979,7 +1105,7 @@ def _blocks(block_q, block_k, q, k, v, bias, segments, rate):
         bias_bytes=jnp.asarray(bias).dtype.itemsize if bias is not None
         else 0,
         dbias=bias is not None and _bias_shape4(bias) == (B, Hq, Sq, Sk),
-        segments=segments, dropout=rate > 0.0)
+        segments=segments, dropout=rate > 0.0, window=window)
 
 
 def _pad_seq(x3, block):
@@ -1034,27 +1160,44 @@ def _seg_mask(qenc, kenc, seg_causal):
     return same
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(7, 8, 9, 10, 11, 12))
+@functools.partial(jax.custom_vjp,
+                   nondiff_argnums=(7, 8, 9, 10, 11, 12, 13))
 def flash_attention_ext(q, k, v, bias, seed, q_seg, k_seg, causal, scale,
-                        dropout_rate, block_q, block_k, interpret):
+                        dropout_rate, block_q, block_k, interpret,
+                        window=None):
     """Full-contract flash attention: q [B,Sq,Hq,D], k/v [B,Sk,Hk,D],
     optional additive ``bias`` broadcastable to [B,Hq,Sq,Sk] (full Sk dim),
     deterministic dropout driven by ``seed`` ((1,) int32; see
     ``dropout_keep_mask``), optional varlen packing via ``q_seg``/``k_seg``
     ((B, Sq)/(B, Sk) int32 segment ids — attention is masked where the ids
     differ, the TPU-native form of the reference's cu_seqlens contract,
-    flash_attn_kernel.cu:199). Returns out [B,Sq,Hq,D]."""
+    flash_attn_kernel.cu:199). ``window`` (None or a length; causal calls
+    without bias or segment ids) hides the keys that lie ``window`` or more
+    behind a query: key j is visible to query i when ``j <= i`` and
+    ``i - j < window``; the kernels then sweep the band's blocks only and
+    carry the names ``flash_win_*``. Returns out [B,Sq,Hq,D]."""
     out, _ = _fa_fwd(q, k, v, bias, seed, q_seg, k_seg, causal, scale,
-                     dropout_rate, block_q, block_k, interpret)
+                     dropout_rate, block_q, block_k, interpret, window)
     return out
 
 
+def _check_window(window, causal, bias, q_seg):
+    if window is None:
+        return
+    if not causal or bias is not None or q_seg is not None:
+        raise ValueError("flash_attention_ext: a window goes with causal "
+                         "attention, without bias and without segment ids")
+    if int(window) < 1:
+        raise ValueError(f"window must be at least 1, got {window!r}")
+
+
 def _fa_fwd(q, k, v, bias, seed, q_seg, k_seg, causal, scale, dropout_rate,
-            block_q, block_k, interpret):
+            block_q, block_k, interpret, window=None):
     B, Sq, Hq, D = q.shape
     Sk, Hk = k.shape[1], k.shape[2]
+    _check_window(window, causal, bias, q_seg)
     bq, bk = _blocks(block_q, block_k, q, k, v, bias, q_seg is not None,
-                     dropout_rate).fwd
+                     dropout_rate, window).fwd
     offset = Sk - Sq
 
     q3 = _pad_seq(q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, D), bq)
@@ -1083,19 +1226,20 @@ def _fa_fwd(q, k, v, bias, seed, q_seg, k_seg, causal, scale, dropout_rate,
         seed_in = None
 
     out3, lse = _fwd(q3, k3, v3, bias3, seed_in, Hq, Hk, causal, scale,
-                     offset, Sk, bq, bk, maps, interpret, qseg3, kseg3)
+                     offset, Sk, bq, bk, maps, interpret, qseg3, kseg3,
+                     window)
     out = out3[:, :Sq].reshape(B, Hq, Sq, D).transpose(0, 2, 1, 3)
     return out, (q, k, v, bias, seed, q_seg, k_seg, out, lse)
 
 
-def _fa_bwd(causal, scale, dropout_rate, block_q, block_k, interpret, res,
-            dout):
+def _fa_bwd(causal, scale, dropout_rate, block_q, block_k, interpret, window,
+            res, dout):
     q, k, v, bias, seed, q_seg, k_seg, out, lse = res
     B, Sq, Hq, D = q.shape
     Sk, Hk = k.shape[1], k.shape[2]
     rep = Hq // Hk
     plan = _blocks(block_q, block_k, q, k, v, bias, q_seg is not None,
-                   dropout_rate)
+                   dropout_rate, window)
     # the two kernels share their operands: pad each side to a length both
     # of its blocks divide
     bq, bk = math.lcm(plan.dq[0], plan.dkv[0]), \
@@ -1155,7 +1299,7 @@ def _fa_bwd(causal, scale, dropout_rate, block_q, block_k, interpret, res,
     dq3, dk3, dv3, dbias_blocks = _bwd_impl(
         q3, kx, vx, do3, lse_p, delta, bias3, seed_in, causal, scale,
         offset, Sk, plan, maps, interpret, qseg3, kseg3,
-        hq=hq_eff, hk=hk_eff)
+        hq=hq_eff, hk=hk_eff, window=window)
     dq = dq3[:, :Sq].reshape(B, Hq, Sq, D).transpose(0, 2, 1, 3)
     if expand_kv:  # per-q-head dk/dv: sum q-head groups onto their kv head
         dk4 = dk3[:, :Sk].reshape(B, Hk, rep, Sk, D).sum(axis=2)
@@ -1263,13 +1407,16 @@ def flash_chunk_bwd(q, k, v, do, lse, delta, causal, scale, block_q=None,
 # ---------------------------------------------------------------------------
 
 @register_op_impl("flash_attention", "pallas")
-def _attention_pallas(q, k, v, bias, causal, scale, dropout_p, dropout_key):
+def _attention_pallas(q, k, v, bias, causal, scale, dropout_p, dropout_key,
+                      window=None):
     """Pallas path for the training hot path, now including attention
     dropout and additive bias in-kernel (reference contract
     paddle/phi/api/yaml/ops.yaml:978-989); routes to the XLA reference
     impl only for head_dim > 256, short sequences, unsupported bias
     layouts, bias or dropout under a device mesh (see ``_per_shard``),
-    or CPU interpret mode."""
+    or CPU interpret mode. A ``window`` (causal, no bias) takes the
+    plan's tiles without the autotune and never the whole-op XLA route: its
+    score matrix is what the window exists to avoid."""
     from ...nn.functional.flash_attention import _attention_xla
     interpret = pallas_interpret()
     on_tpu = not interpret
@@ -1290,15 +1437,19 @@ def _attention_pallas(q, k, v, bias, causal, scale, dropout_p, dropout_key):
     if (not bias_ok or q.shape[-1] > 256
             or (rate > 0.0 and dropout_key is None)
             or (meshed and (bias is not None or rate > 0.0))
+            or (window is not None and (bias is not None or not causal))
             or (on_tpu and k.shape[1] < min_seq)
             or (interpret and not _flags.get_flag("pallas_force_interpret"))):
         return _attention_xla(q, k, v, bias, causal, scale, dropout_p,
-                              dropout_key)
+                              dropout_key, window)
     seed = seed_from_key(dropout_key) if rate > 0.0 \
         else jnp.zeros((1,), jnp.int32)
-    impl, bq, bk, out = _tuned_blocks(q, k, v, bias, seed, bool(causal),
-                                      float(scale), rate, interpret,
-                                      dropout_key=dropout_key)
+    if window is not None:
+        impl, bq, bk, out = "pallas", None, None, None
+    else:
+        impl, bq, bk, out = _tuned_blocks(q, k, v, bias, seed, bool(causal),
+                                          float(scale), rate, interpret,
+                                          dropout_key=dropout_key)
     if out is not None:   # autotune just measured the winner end-to-end
         return out
     if impl == "xla":
@@ -1309,7 +1460,7 @@ def _attention_pallas(q, k, v, bias, causal, scale, dropout_p, dropout_key):
     def kernel(q_, k_, v_):
         return flash_attention_ext(q_, k_, v_, bias, seed, None, None,
                                    bool(causal), float(scale), rate, bq, bk,
-                                   interpret)
+                                   interpret, window)
     if meshed:
         kernel = _per_shard(kernel, auto, q, k)
     return kernel(q, k, v)

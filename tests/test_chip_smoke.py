@@ -45,7 +45,7 @@ def interpret_kernels():
 @pytest.mark.slow
 def test_leg_kernels_tiny():
     out = chip_smoke.leg_kernels(chip_smoke.TINY["kernels"])
-    assert out["interpret"] and out["cases"] == 11
+    assert out["interpret"] and out["cases"] == 12
 
 
 @pytest.mark.slow
@@ -53,6 +53,20 @@ def test_leg_train_tiny(interpret_kernels):
     out = chip_smoke.leg_train(chip_smoke.TINY["train"])
     assert out["compiles_after_first_step"] == 0
     assert out["losses"][-1] < out["losses"][0]
+
+
+def test_leg_laguna_tiny(interpret_kernels):
+    out = chip_smoke.leg_laguna(chip_smoke.TINY["laguna"])
+    assert out["losses"][-1] < out["losses"][0]
+    assert [r["layer"] for r in out["routing_stats"]] == [1, 2, 3, 4]
+    # interpreted kernels leave no name in the lowered step; their plans
+    # say that the windowed flash kernels and the expert layer were lowered
+    from paddle_tpu.incubate.moe import MOE_PLAN_TALLY
+    from paddle_tpu.ops.pallas.flash_attention import TILE_PLAN_TALLY
+    assert set(out["kernels_in_step"]) >= {"flash_win_fwd", "moe_gmm_fwd"}
+    assert {k[0] for k in TILE_PLAN_TALLY} >= {
+        "flash_win_fwd", "flash_win_bwd_dq", "flash_win_bwd_dkv"}
+    assert any(k[:3] == (4, 16, 4) for k in MOE_PLAN_TALLY)
 
 
 def test_leg_serve_tiny():

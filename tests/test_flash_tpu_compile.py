@@ -1,4 +1,5 @@
-"""The flash kernels at the tile plan's tiles, compiled for a described v5e.
+"""The flash kernels at the tile plan's tiles, and the grouped matmuls at the
+expert layer's, compiled for a described v5e.
 
 Interpret mode cannot say whether Mosaic takes a tile: whether its blocks
 fit the scoped VMEM the call asks for (``_call_params``), whether a
@@ -16,6 +17,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import grouped_matmul as gm
 
 
 @pytest.fixture(scope="module")
@@ -108,3 +110,67 @@ def test_plan_tiles_compile_for_v5e(case, one_chip, production_numerics):
     assert lowered == {("flash_fwd",) + plan.fwd,
                        ("flash_bwd_dq",) + plan.dq,
                        ("flash_bwd_dkv",) + plan.dkv}
+
+
+# laguna-xs2-train-s8192's attention: name: (b, s, hq, hk, d, window)
+_WINDOW_CASES = {
+    "laguna_window_layer": (2, 8192, 64, 8, 128, 512),
+    "laguna_full_layer": (2, 8192, 48, 8, 128, None),
+    # a window that is no multiple of 128, on a length that is none either
+    "window_200_s1100": (1, 1100, 6, 2, 64, 200),
+}
+
+
+@pytest.mark.parametrize("case", list(_WINDOW_CASES))
+def test_windowed_plan_tiles_compile_for_v5e(case, one_chip,
+                                             production_numerics):
+    b, s, hq, hk, d, window = _WINDOW_CASES[case]
+
+    def arg(shape, dtype="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    def loss(q, k, v, seed):
+        out = fa.flash_attention_ext(q, k, v, None, seed, None, None, True,
+                                     float(d) ** -0.5, 0.0, None, None,
+                                     False, window)
+        return out.astype(jnp.float32).sum()
+
+    before = dict(fa.TILE_PLAN_TALLY)
+    compiled = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        arg((b, s, hq, d)), arg((b, s, hk, d)), arg((b, s, hk, d)),
+        arg((1,), "int32")).compile()
+    assert compiled.as_text().count("tpu_custom_call") >= 3
+    lowered = {key for key, n in fa.TILE_PLAN_TALLY.items()
+               if n > before.get(key, 0)}
+    plan = fa.tile_plan(s, s, d, window=window)
+    pre = "flash_" if window is None else "flash_win_"
+    assert lowered == {(pre + "fwd",) + plan.fwd,
+                       (pre + "bwd_dq",) + plan.dq,
+                       (pre + "bwd_dkv",) + plan.dkv}
+    if window is not None:     # the tile stops at the window
+        assert max(plan.fwd + plan.dq + plan.dkv) <= -(-window // 128) * 128
+
+
+@pytest.mark.parametrize("k,n", [(2048, 1024), (512, 2048)],
+                         ids=["gate_up", "down"])
+def test_grouped_matmuls_compile_for_v5e(k, n, one_chip, production_numerics):
+    """The cell's two grouped products, forward and both backward products,
+    over buffers sized for the worst routing of 16,384 tokens x top-8 onto
+    32 held experts."""
+    held, tm = 32, gm.ROW_TILE
+    rows = gm.padded_rows(16384 * 8, held, tm)
+
+    def arg(shape, dtype="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    def loss(x, w, tile_group, n_tiles):
+        return gm.grouped_matmul(x, w, tile_group, n_tiles,
+                                 interpret=False).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.value_and_grad(loss, (0, 1))).lower(
+        arg((rows, k)), arg((held, k, n)), arg((rows // tm,), "int32"),
+        arg((1,), "int32")).compile().as_text()
+    for name in ("moe_gmm_fwd", "moe_gmm_bwd_x", "moe_gmm_bwd_w"):
+        assert name in text, name

@@ -30,13 +30,18 @@ import tempfile
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-# name: (B*Hq, B*Hk, heads q, heads kv, S, D, q/k dtype, v dtype)
+# name: (B*Hq, B*Hk, heads q, heads kv, S, D, q/k dtype, v dtype[, window])
 SHAPES = {
     # gpt2s-train-s1024: b32, 12 heads of 64, bf16
     "gpt2": (384, 384, 12, 12, 1024, 64, "bfloat16", "bfloat16"),
     # mistral7b-l2-train-s4096: b4, 32/8 heads of 128; RoPE's float32 tables
     # leave q and k float32, v stays bf16 (models/llama.py)
     "mistral": (128, 32, 32, 8, 4096, 128, "float32", "bfloat16"),
+    # laguna-xs2-train-s8192: b2, head 128, bf16 throughout; a window layer
+    # (64 heads over 8, window 512: the kernels are then flash_win_*) and a
+    # full layer (48 heads over 8)
+    "laguna_win": (128, 16, 64, 8, 8192, 128, "bfloat16", "bfloat16", 512),
+    "laguna_full": (96, 16, 48, 8, 8192, 128, "bfloat16", "bfloat16"),
 }
 TILES = ((128, 128), (256, 256), (256, 512), (512, 256), (512, 512),
          (256, 1024), (1024, 256), (512, 1024), (1024, 512), (1024, 1024))
@@ -50,7 +55,8 @@ def build(shape, kernel, bq, bk):
 
     from paddle_tpu.ops.pallas import flash_attention as fa
 
-    bhq, bhk, hq, hk, s, d, qk_dt, v_dt = SHAPES[shape]
+    bhq, bhk, hq, hk, s, d, qk_dt, v_dt = SHAPES[shape][:8]
+    window = (SHAPES[shape] + (None,))[8]
     scale = float(d) ** -0.5
     qk_dt, v_dt = jnp.dtype(qk_dt), jnp.dtype(v_dt)
     q = jax.ShapeDtypeStruct((bhq, s, d), qk_dt)
@@ -61,13 +67,13 @@ def build(shape, kernel, bq, bk):
     if kernel == "fwd":
         def fn(q, k, v):
             return fa._fwd(q, k, v, None, None, hq, hk, True, scale, 0, s,
-                           bq, bk, maps, False)
+                           bq, bk, maps, False, window=window)
         return fn, (q, k, v)
     impl = fa._bwd_dq if kernel == "dq" else fa._bwd_dkv
 
     def fn(q, k, v, do, lse, delta):
         return impl(q, k, v, do, lse, delta, None, None, True, scale, 0, s,
-                    bq, bk, maps, False, None, None, hq, hk)
+                    bq, bk, maps, False, None, None, hq, hk, window)
     return fn, (q, k, v, q, col, col)   # do has the output's dtype: q's
 
 
@@ -111,7 +117,7 @@ def measure(shapes, tiles, reps):
                          "(use --compile-only here)")
     rows = []
     for shape in shapes:
-        bhq, bhk, hq, hk, s, d, qk_dt, v_dt = SHAPES[shape]
+        bhq, bhk, hq, hk, s, d, qk_dt, v_dt = SHAPES[shape][:8]
         rng = np.random.RandomState(0)
 
         def rand(n, dt):
@@ -202,7 +208,7 @@ def table(rows):
 
 def main(argv=None):
     ap = argparse.ArgumentParser(description=__doc__.splitlines()[0])
-    ap.add_argument("--shapes", default=",".join(SHAPES))
+    ap.add_argument("--shapes", default="gpt2,mistral")
     ap.add_argument("--tiles", default=",".join(f"{a}x{b}" for a, b in TILES))
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--compile-only", action="store_true")
