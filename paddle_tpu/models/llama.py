@@ -127,14 +127,16 @@ def blockwise_lm_loss(h, w, labels, transpose_w=False):
 
 def apply_rotary_pos_emb(q_arr, k_arr, cos, sin):
     """Rotate-half RoPE on [B, S, H, D] arrays (parity:
-    fused_rotary_position_embedding semantics)."""
+    fused_rotary_position_embedding semantics). Rotated in the tables'
+    dtype (float32), each of q and k returned in the dtype it came in:
+    a bfloat16 model's activations stay bfloat16 past the rotation."""
     def rot(x):
         x1, x2 = x[..., ::2], x[..., 1::2]
         c = cos[None, :, None, :]
         s = sin[None, :, None, :]
         o1 = x1 * c - x2 * s
         o2 = x2 * c + x1 * s
-        return jnp.stack([o1, o2], axis=-1).reshape(x.shape)
+        return jnp.stack([o1, o2], axis=-1).reshape(x.shape).astype(x.dtype)
     return rot(q_arr), rot(k_arr)
 
 

@@ -167,6 +167,52 @@ class TestIncubateFused:
             use_neox_rotary_style=False)
         np.testing.assert_allclose(out.numpy(), np.asarray(ref), rtol=1e-5)
 
+    @pytest.mark.parametrize("q_dtype,k_dtype", [
+        ("bfloat16", "bfloat16"), ("float32", "float32"),
+        ("bfloat16", "float32"), ("float32", "bfloat16")])
+    def test_rope_returns_the_dtypes_it_was_given(self, q_dtype, k_dtype):
+        """Rotated in float32 (the tables are), rounded once to what came
+        in: bit for bit the float32 rotation cast to the input's dtype."""
+        from paddle_tpu.models.llama import _rope_tables, apply_rotary_pos_emb
+        rng = np.random.RandomState(1)
+        q = jnp.asarray(rng.randn(2, 8, 4, 16), q_dtype)
+        k = jnp.asarray(rng.randn(2, 8, 2, 16), k_dtype)
+        cos, sin = _rope_tables(8, 16, 10000.0)
+        qr, kr = apply_rotary_pos_emb(q, k, cos, sin)
+        assert (qr.dtype, kr.dtype) == (q.dtype, k.dtype)
+        q32, k32 = apply_rotary_pos_emb(q.astype(jnp.float32),
+                                        k.astype(jnp.float32), cos, sin)
+        assert (q32.dtype, k32.dtype) == (jnp.float32, jnp.float32)
+        np.testing.assert_array_equal(np.asarray(qr),
+                                      np.asarray(q32.astype(q.dtype)))
+        np.testing.assert_array_equal(np.asarray(kr),
+                                      np.asarray(k32.astype(k.dtype)))
+
+    def test_laguna_rotation_is_bitwise_what_it_was(self):
+        """``laguna.py::_rope_partial`` hands float32 slices in and casts
+        the result itself, so the rule changes nothing there: its q and k
+        equal the rotation written out in float32 and rounded once."""
+        from paddle_tpu.models.laguna import _rope_partial, laguna_rope_tables
+        rng = np.random.RandomState(2)
+        q = jnp.asarray(rng.randn(2, 8, 6, 16), jnp.bfloat16)
+        k = jnp.asarray(rng.randn(2, 8, 2, 16), jnp.bfloat16)
+        cos, sin = laguna_rope_tables(
+            8, 16, {"rope_theta": 10000.0, "partial_rotary_factor": 0.5})
+        qr, kr = _rope_partial(q, k, cos, sin)
+        rot = 2 * cos.shape[-1]
+        assert rot == 8 and (qr.dtype, kr.dtype) == (q.dtype, k.dtype)
+
+        def plain(x):
+            x32 = np.asarray(x[..., :rot].astype(jnp.float32))
+            x1, x2 = x32[..., ::2], x32[..., 1::2]
+            c, s = cos[None, :, None, :], sin[None, :, None, :]
+            out = np.stack([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
+            out = jnp.asarray(out.reshape(x32.shape)).astype(x.dtype)
+            return np.concatenate([np.asarray(out), np.asarray(x[..., rot:])],
+                                  axis=-1)
+        np.testing.assert_array_equal(np.asarray(qr), plain(q))
+        np.testing.assert_array_equal(np.asarray(kr), plain(k))
+
     def test_fused_norms(self):
         x = paddle.to_tensor(
             np.random.RandomState(0).randn(4, 32).astype(np.float32))
