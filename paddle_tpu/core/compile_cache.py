@@ -1,7 +1,7 @@
 """Where XLA's persistent compile cache lives.
 
 One rule for every entry point that compiles for a device
-(``chip_smoke.py``, the bench scripts, ``serving/host.py``): when
+(``chip_smoke.py``, ``benchmarks/run.py``, ``serving/host.py``): when
 ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and this module
 sets nothing; otherwise the cache goes to ONE fixed directory inside the
 checkout. A cache that moves between runs never hits, so the path is a

@@ -385,39 +385,13 @@ def _load_pallas_impls():
 def select_impl(name: str):
     """Pick the Pallas implementation when registered and enabled, else XLA.
     (Thin analog of the reference KernelFactory::SelectKernelOrThrowError,
-    paddle/phi/core/kernel_factory.h:326 — XLA subsumes backend/dtype keys.)
-
-    With FLAGS_use_autotune, the returned callable measures every
-    registered impl on the first eager call per (op, shapes) key and
-    caches the winner (core/autotune.py — the reference's
-    phi/kernels/autotune cache)."""
+    paddle/phi/core/kernel_factory.h:326 — XLA subsumes backend/dtype keys.)"""
     if _flags.get_flag("use_pallas_kernels"):
         _load_pallas_impls()
     d = OPS.get(name)
     impls = d.impls if d is not None else {}
-
-    def _default_impl(imp):
-        if _flags.get_flag("use_pallas_kernels") and "pallas" in imp:
-            return imp["pallas"]
-        if "xla" in imp:
-            return imp["xla"]
-        raise KeyError(f"no implementation registered for op '{name}'")
-
-    # candidates respect the user's kernel toggles: a disabled pallas
-    # impl must never be measured (nor cached as the winner)
-    candidates = {k: v for k, v in impls.items()
-                  if k != "pallas" or _flags.get_flag("use_pallas_kernels")}
-    if _flags.get_flag("use_autotune") and len(candidates) > 1:
-        from . import autotune as _at
-
-        def tuned(*args, _name=name, _impls=candidates):
-            choice, out = _at.pick_impl(
-                _name, _impls, args,
-                lambda impl_name: _impls[impl_name](*args))
-            if out is not None:
-                return out  # reuse the winning measurement's result
-            if choice is not None:
-                return _impls[choice](*args)
-            return _default_impl(_impls)(*args)
-        return tuned
-    return _default_impl(impls)
+    if _flags.get_flag("use_pallas_kernels") and "pallas" in impls:
+        return impls["pallas"]
+    if "xla" in impls:
+        return impls["xla"]
+    raise KeyError(f"no implementation registered for op '{name}'")

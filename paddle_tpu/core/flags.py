@@ -58,7 +58,6 @@ define_flag("check_nan_inf", False, "check every op output for NaN/Inf")
 define_flag("check_index_bounds", False,
             "eager range-check of gather/embedding indices (host sync)")
 define_flag("use_pallas_kernels", True, "prefer Pallas fused kernels over XLA lowering")
-define_flag("use_autotune", False, "measure-and-cache fused-kernel impl selection per op+shape (parity: FLAGS_use_autotune, paddle/phi/kernels/autotune/switch_autotune.h)")
 define_flag("use_spmd_rules", True,
             "apply explicit per-op SPMD rules (sharding constraints + "
             "dist_attr propagation) where registered")
@@ -75,14 +74,6 @@ define_flag("planner_strict", False,
             "counted in planner.planner_stats)")
 define_flag("use_fused_optimizer", True,
             "eager optimizer.step as one jitted multi-tensor XLA program")
-define_flag("pallas_flash_min_seq", 1024,
-            "kv length at which the pallas flash-attention kernel takes "
-            "over from XLA's fused attention. chip_smoke.py pins that the "
-            "kernel compiles and agrees with XLA at s=1024, the GPT-2 "
-            "training shape; the crossover itself is open: one "
-            "bench_kernels.py run on a v5e (CHANGES.md, ISSUE 21) had XLA "
-            "ahead fwd+bwd for MHA from s=1k to s=4k. Unchanged until a "
-            "ledger row decides it (ROADMAP S5)")
 define_flag("pallas_prefer_ce", False,
             "prefer the pallas fused softmax-CE over XLA's on TPU")
 define_flag("pallas_ce_bwd", "auto",
@@ -93,11 +84,6 @@ define_flag("pallas_prefer_norms", False,
             "ship the pallas rms/layer-norm kernels on TPU even under "
             "differentiation (default ships XLA there: its fused fwd+bwd "
             "measured faster on v5e; fwd-dominant inference can opt in)")
-define_flag("flash_gqa_xla_max_bytes", 4_500_000_000,
-            "route grouped-query attention to the XLA path while the "
-            "score matrix (B*Hq*Sq*Sk*4 bytes) fits this budget: XLA's "
-            "saved-probabilities backward beats the flash recompute "
-            "backward for GQA (r3 v5e capture: 0.837 at s4k)")
 define_flag("pallas_force_interpret", False,
             "run Pallas kernels in interpret mode on non-TPU backends "
             "(kernel tests); default falls back to the XLA impl off-TPU")

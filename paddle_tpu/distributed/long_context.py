@@ -232,8 +232,7 @@ def ring_chunked_single(q, k, v, n_chunks, causal, scale, interpret):
     """Single-chip model of the per-device ring compute: q/k/v [B,S,H(k),D]
     split into ``n_chunks`` sequence chunks, flash block kernel per (qi,
     kj) chunk pair, log-sum-exp merge — exactly what each ring device
-    executes, minus the ppermute. This is the chunk-level bench surface
-    (bench_kernels.py ring_chunks_*): its time vs the monolithic kernel
+    executes, minus the ppermute: its time against the monolithic kernel
     is the ring's single-chip compute overhead."""
     out, _ = _ring_chunked_fwd(q, k, v, n_chunks, causal, scale, interpret)
     return out

@@ -1,7 +1,7 @@
 """Convolution functionals (parity: python/paddle/nn/functional/conv.py).
 All lower to lax.conv_general_dilated — XLA maps these onto the MXU; there
 is no cuDNN-style algorithm search because the compiler owns scheduling
-(the reference's conv autotune cache, phi/kernels/autotune, is subsumed)."""
+(the reference's cache of searched conv algorithms is subsumed)."""
 from __future__ import annotations
 
 import jax

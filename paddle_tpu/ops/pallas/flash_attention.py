@@ -19,9 +19,10 @@ Design (online-softmax, Dao et al. 2022, re-derived for the MXU):
   with nothing in it and 128 x 128 blocks spent the kernels' time there
   (PERF.md, "PR 26"). A call whose estimate passes Mosaic's 16 MiB of
   scoped VMEM asks for what it needs (``_call_params``). Explicit block
-  arguments and a warm autotune cache win over the plan. Every lowered call
-  leaves a ``flash::tile_plan`` trace event and a count in
-  ``TILE_PLAN_TALLY``.
+  arguments win over the plan. Every lowered call leaves a
+  ``flash::tile_plan`` trace event and a count in ``TILE_PLAN_TALLY``.
+- route: ``attention_route`` decides from the same kind of facts whether a
+  dense call takes these kernels or XLA's attention.
 - forward: grid (batch*heads, q_blocks, k_blocks) with the k dimension
   innermost/sequential ("arbitrary"); VMEM scratch carries the running
   (acc, m, l) across k blocks; causal blocks above the diagonal are skipped
@@ -1108,6 +1109,64 @@ def _blocks(block_q, block_k, q, k, v, bias, segments, rate, window=None):
         segments=segments, dropout=rate > 0.0, window=window)
 
 
+# ---------------------------------------------------------------------------
+# the route: whether a dense call takes the kernels or XLA's attention
+# ---------------------------------------------------------------------------
+
+class Route(NamedTuple):
+    """``impl`` is "kernel" or "xla"; ``rule`` names the rule that decided."""
+    impl: str
+    rule: str
+
+
+# Two rules no cell of the benchmark stands on either side of (every cell's
+# kv is 1024 or longer, and where its heads are grouped its scores are past
+# the budget): kept as they were, until a cell or a measurement on the chip
+# decides them (ROADMAP S5 b).
+# The kv length under which the chip takes XLA's fused attention.
+_MIN_KV_ON_CHIP = 1024
+# Grouped heads on the chip take XLA's attention while the float32 score
+# matrix, B * Hq * Sq * Sk * 4 bytes whatever the operands' dtype, is no
+# larger than this: XLA's backward keeps the probabilities, the flash
+# backward recomputes them for every q head of a group.
+_GQA_XLA_SCORE_BYTES = 4_500_000_000
+
+
+def attention_route(q, k, bias, *, dropout_rate, has_key, causal, window,
+                    meshed, on_tpu, force_interpret) -> Route:
+    """The implementation a dense attention call gets, from its static
+    facts: q [B,Sq,Hq,D] and k [B,Sk,Hk,D] (anything with a ``shape``), the
+    bias or None, the dropout rate and whether a key came with it,
+    ``causal``, ``window``, whether GSPMD-owned mesh axes larger than 1
+    surround the call (``meshed``), the backend, and the
+    ``pallas_force_interpret`` flag. The first rule that holds decides.
+    The kernel's tiles are ``tile_plan``'s."""
+    B, sq, Hq, D = q.shape
+    sk, Hk = k.shape[1], k.shape[2]
+    if bias is not None and not bias_supported(bias, B, Hq, sq, sk):
+        return Route("xla", "bias_layout")
+    if D > 256:
+        return Route("xla", "head_dim")
+    if dropout_rate > 0.0 and not has_key:
+        return Route("xla", "dropout_without_key")
+    # see ``_per_shard``: bias-free, dropout-free calls only
+    if meshed and (bias is not None or dropout_rate > 0.0):
+        return Route("xla", "mesh_with_bias_or_dropout")
+    if window is not None and (bias is not None or not causal):
+        return Route("xla", "window_with_bias_or_not_causal")
+    if on_tpu and sk < _MIN_KV_ON_CHIP:
+        return Route("xla", "short_kv")
+    if not on_tpu and not force_interpret:
+        return Route("xla", "interpret_not_forced")
+    if window is not None:
+        # never XLA for its size: the score matrix is what a window avoids
+        return Route("kernel", "window")
+    if (Hq // max(Hk, 1) > 1 and on_tpu
+            and B * Hq * sq * sk * 4 <= _GQA_XLA_SCORE_BYTES):
+        return Route("xla", "gqa_scores_fit")
+    return Route("kernel", "default")
+
+
 def _pad_seq(x3, block):
     s = x3.shape[1]
     pad = (-s) % block
@@ -1409,58 +1468,34 @@ def flash_chunk_bwd(q, k, v, do, lse, delta, causal, scale, block_q=None,
 @register_op_impl("flash_attention", "pallas")
 def _attention_pallas(q, k, v, bias, causal, scale, dropout_p, dropout_key,
                       window=None):
-    """Pallas path for the training hot path, now including attention
-    dropout and additive bias in-kernel (reference contract
-    paddle/phi/api/yaml/ops.yaml:978-989); routes to the XLA reference
-    impl only for head_dim > 256, short sequences, unsupported bias
-    layouts, bias or dropout under a device mesh (see ``_per_shard``),
-    or CPU interpret mode. A ``window`` (causal, no bias) takes the
-    plan's tiles without the autotune and never the whole-op XLA route: its
-    score matrix is what the window exists to avoid."""
+    """Pallas path for the training hot path, including attention dropout
+    and additive bias in-kernel (reference contract
+    paddle/phi/api/yaml/ops.yaml:978-989). ``attention_route`` says whether
+    the call takes the kernels, with the plan's tiles, or the XLA reference
+    impl."""
     from ...nn.functional.flash_attention import _attention_xla
     interpret = pallas_interpret()
-    on_tpu = not interpret
     # mesh axes GSPMD still owns at this point of the trace (inside a
     # shard_map the manual axes are per-shard already)
     mesh = jax.sharding.get_abstract_mesh()
     auto = {a: mesh.shape[a] for a in mesh.auto_axes}
     meshed = any(n > 1 for n in auto.values())
-    # pick by shape, like the reference's kernel autotune cache
-    # (paddle/phi/kernels/autotune/): XLA's fused attention for short kv,
-    # the pallas streaming kernel once score materialization bites. Where
-    # the crossover (FLAGS_pallas_flash_min_seq) belongs is open — see the
-    # flag's help text (ROADMAP S5).
-    min_seq = int(_flags.get_flag("pallas_flash_min_seq"))
     rate = float(dropout_p or 0.0)
-    bias_ok = bias is None or bias_supported(
-        bias, q.shape[0], q.shape[2], q.shape[1], k.shape[1])
-    if (not bias_ok or q.shape[-1] > 256
-            or (rate > 0.0 and dropout_key is None)
-            or (meshed and (bias is not None or rate > 0.0))
-            or (window is not None and (bias is not None or not causal))
-            or (on_tpu and k.shape[1] < min_seq)
-            or (interpret and not _flags.get_flag("pallas_force_interpret"))):
+    route = attention_route(
+        q, k, bias, dropout_rate=rate, has_key=dropout_key is not None,
+        causal=bool(causal), window=window, meshed=meshed,
+        on_tpu=not interpret,
+        force_interpret=bool(_flags.get_flag("pallas_force_interpret")))
+    if route.impl == "xla":
         return _attention_xla(q, k, v, bias, causal, scale, dropout_p,
                               dropout_key, window)
     seed = seed_from_key(dropout_key) if rate > 0.0 \
         else jnp.zeros((1,), jnp.int32)
-    if window is not None:
-        impl, bq, bk, out = "pallas", None, None, None
-    else:
-        impl, bq, bk, out = _tuned_blocks(q, k, v, bias, seed, bool(causal),
-                                          float(scale), rate, interpret,
-                                          dropout_key=dropout_key)
-    if out is not None:   # autotune just measured the winner end-to-end
-        return out
-    if impl == "xla":
-        # GQA at moderate seq (or a measured "xla" winner): XLA's saved-P
-        # backward beats the flash recompute backward (r3 capture 0.837)
-        return _attention_xla(q, k, v, bias, causal, scale, dropout_p,
-                              dropout_key)
+
     def kernel(q_, k_, v_):
         return flash_attention_ext(q_, k_, v_, bias, seed, None, None,
-                                   bool(causal), float(scale), rate, bq, bk,
-                                   interpret, window)
+                                   bool(causal), float(scale), rate, None,
+                                   None, interpret, window)
     if meshed:
         kernel = _per_shard(kernel, auto, q, k)
     return kernel(q, k, v)
@@ -1497,104 +1532,3 @@ def _per_shard(kernel, auto, q, k):
     return jax.shard_map(kernel, in_specs=(spec, spec, spec),
                          out_specs=spec, axis_names=frozenset(auto),
                          check_vma=False)
-
-
-# the (block_q, block_k) tilings autotune measures end to end (fwd + bwd, one
-# tile for all three kernels) when it is on (core/autotune.py — the analog
-# of the reference's exhaustive-search cache,
-# paddle/phi/kernels/autotune/cache.h). With autotune off, or a cold
-# cache, ``tile_plan`` chooses, per kernel
-_BLOCK_CANDIDATES = ((128, 128), (256, 256), (512, 256), (256, 512),
-                     (512, 512), (512, 1024), (1024, 1024))
-
-
-def _tuned_blocks(q, k, v, bias, seed, causal, scale, rate, interpret,
-                  dropout_key=None):
-    """(impl, bq, bk, out) for this call — ``impl`` in {"pallas", "xla"};
-    ``bq``/``bk`` None where nothing was measured: ``tile_plan`` then
-    chooses each kernel's tile from the shape (``flash_attention_ext``
-    with block arguments None).
-
-    Consult the autotune cache (traced calls), or measure candidates
-    fwd+bwd on concrete eager calls. The measured timing includes the
-    backward pass — block sizes that win fwd can lose the dq/dkv kernels —
-    and the candidate set includes the whole-op XLA attention (VERDICT r3
-    #2, per-direction winners): XLA's autodiff saves the probability
-    matrix from the forward, so where P fits in HBM it beats any
-    flash-style recompute backward; a cached "xla" winner routes the
-    entire op there."""
-    from ...core import autotune as _autotune
-
-    B, sq, Hq = q.shape[0], q.shape[1], q.shape[2]
-    sk, Hk = k.shape[1], k.shape[2]
-    rep = Hq // max(Hk, 1)
-    # default heuristic with a cold cache, from the r3 on-chip capture
-    # (fa_s4k_gqa32_8 fwd_bwd 0.837 vs MHA shapes all >= 1.23): grouped
-    # heads double the recompute cost of the flash backward while XLA's
-    # saved-P backward stays flat — route GQA to XLA whenever the score
-    # materialization fits the HBM budget
-    score_bytes = B * Hq * sq * sk * 4
-    xla_fits = score_bytes <= int(_flags.get_flag("flash_gqa_xla_max_bytes"))
-    default_impl = "xla" if (rep > 1 and not interpret and xla_fits) \
-        else "pallas"
-
-    cands = {f"b{a}x{b}": (a, b) for a, b in _BLOCK_CANDIDATES
-             if a <= max(sq, 128) and b <= max(sk, 128)}
-    if not interpret and xla_fits and (rate == 0.0
-                                       or dropout_key is not None):
-        cands["xla"] = None
-    bias_sig = "x".join(map(str, bias.shape)) if bias is not None else "0"
-    # v2: the candidate set gained the whole-op "xla" entry and the GQA
-    # routing default (r4) — r3-persisted winners (incl. the GQA 128x128
-    # tile measured before the per-direction work) must MISS, not pin the
-    # old behavior
-    tag = (f"flash_attention_blocks_v2_c{int(causal)}_r{int(rate > 0)}"
-           f"_b{bias_sig}")
-
-    from .select import vjp_probe
-
-    def call(name):
-        if name == "xla":
-            from ...nn.functional.flash_attention import _attention_xla
-            fn = lambda q_, k_, v_: _attention_xla(  # noqa: E731
-                q_, k_, v_, bias, causal, scale, rate, dropout_key)
-        else:
-            a, b = cands[name]
-            fn = lambda q_, k_, v_: flash_attention_ext(  # noqa: E731
-                q_, k_, v_, bias, seed, None, None, causal, scale, rate,
-                a, b, interpret)
-        return vjp_probe(fn, (q, k, v), (0, 1, 2))
-
-    # tile optimum is (seq, heads, head-dim)-determined, not batch: key on
-    # batch-1 surrogates so a b8-tuned entry serves the b16/b32 sweep
-    key_arrays = (jax.ShapeDtypeStruct((1,) + tuple(q.shape[1:]), q.dtype),
-                  jax.ShapeDtypeStruct((1,) + tuple(k.shape[1:]), k.dtype))
-    # shape-CLASS key for the measured-defaults table (VERDICT r4 #6):
-    # power-of-two seq buckets; an unseen exact shape inside a captured
-    # class still gets the measured winner under jit. A class-default
-    # "xla" can never route a call whose own score matrix exceeds the HBM
-    # budget: "xla" is only in this call's candidate set when it fits.
-    class_key = _autotune.flash_class_key(tag, sq, sk, rep > 1,
-                                          q.shape[-1], q.dtype)
-    choice, out = _autotune.pick_impl(tag, cands, (q, k), call,
-                                      key_arrays=key_arrays,
-                                      class_key=class_key)
-    if out is not None:
-        # fresh measurement: note the batch it ran at — the key is batch-
-        # stripped (tile optima are seq/head-determined), and the note
-        # lets a future sweep re-measure entries whose serving batch
-        # drifted far from the measured one (advisor r3)
-        _autotune.record_meta(tag, key_arrays, f"measured_batch={B}")
-    if choice == "xla" and "xla" in cands:
-        # the cache key is batch-stripped (tile optima are batch-invariant)
-        # but the xla-vs-pallas choice is NOT: "xla" only returns when THIS
-        # call's score matrix fits the HBM budget ("xla" in cands implies
-        # xla_fits above) — a b2-cached "xla" must not OOM a b16 call
-        return "xla", None, None, out
-    if choice is None or choice not in cands:
-        # choice unknown: autotune off / stale persisted entry from an
-        # older candidate list / cached "xla" that this call excluded —
-        # the default route, with the tile plan's blocks
-        return default_impl, None, None, None
-    bq, bk = cands[choice]
-    return "pallas", bq, bk, out

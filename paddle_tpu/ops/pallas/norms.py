@@ -18,7 +18,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -135,25 +134,10 @@ def _rms_norm_pallas_impl(a, w, eps):
     # fwd+bwd (rms 0.883/0.891, ln 0.944 pallas-vs-xla) while the Pallas
     # forward wins alone (1.04-1.13). Training always differentiates, so
     # XLA ships by default on TPU; FLAGS_pallas_prefer_norms opts
-    # fwd-dominant workloads (inference Predictor) back in, and a measured
-    # autotune entry (fwd+vjp timing) overrides both.
-    from .select import pick_grad_impl
-    variants = {
-        "pallas": lambda x, ww: rms_norm_pallas(x, ww, float(eps),
-                                                interpret),
-        "xla": lambda x, ww: _rms_norm_xla(x, ww, eps),
-    }
-    default = ("pallas" if interpret
-               or _flags.get_flag("pallas_prefer_norms") else "xla")
-    from ...core import autotune as _at
-    rows = int(np.prod(a.shape[:-1])) if a.ndim > 1 else 1
-    class_key = _at.norm_class_key("rms_norm_dir", rows, a.shape[-1],
-                                   a.dtype)
-    choice, out = pick_grad_impl("rms_norm_dir", variants, (a, w), default,
-                                 diff_argnums=(0, 1), class_key=class_key)
-    if out is not None:
-        return out
-    return variants[choice](a, w)
+    # fwd-dominant workloads (inference Predictor) back in.
+    if interpret or _flags.get_flag("pallas_prefer_norms"):
+        return rms_norm_pallas(a, w, float(eps), interpret)
+    return _rms_norm_xla(a, w, eps)
 
 
 # ---------------------------------------------------------------------------
@@ -239,24 +223,7 @@ def _layer_norm_pallas_impl(a, w, b, eps, begin_axis):
             or not _use_pallas(a) or a.shape[-1] % 128 != 0):
         return _layer_norm_xla(a, w, b, eps, begin_axis)
     interpret = pallas_interpret()
-    # same shipping rule as rms_norm above: XLA by default under training
-    # (it wins the measured fwd+bwd), Pallas via flag or a measured win
-    from .select import pick_grad_impl
-    variants = {
-        "pallas": lambda x, ww, bb: layer_norm_pallas(x, ww, bb, float(eps),
-                                                      interpret),
-        "xla": lambda x, ww, bb: _layer_norm_xla(x, ww, bb, eps,
-                                                 x.ndim - 1),
-    }
-    default = ("pallas" if interpret
-               or _flags.get_flag("pallas_prefer_norms") else "xla")
-    from ...core import autotune as _at
-    rows = int(np.prod(a.shape[:-1])) if a.ndim > 1 else 1
-    class_key = _at.norm_class_key("layer_norm_dir", rows, a.shape[-1],
-                                   a.dtype)
-    choice, out = pick_grad_impl("layer_norm_dir", variants, (a, w, b),
-                                 default, diff_argnums=(0, 1, 2),
-                                 class_key=class_key)
-    if out is not None:
-        return out
-    return variants[choice](a, w, b)
+    # same shipping rule as rms_norm above
+    if interpret or _flags.get_flag("pallas_prefer_norms"):
+        return layer_norm_pallas(a, w, b, float(eps), interpret)
+    return _layer_norm_xla(a, w, b, eps, a.ndim - 1)

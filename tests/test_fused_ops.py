@@ -517,139 +517,6 @@ class TestMixedPrecisionAttention:
                                        np.asarray(b), atol=5e-2)
 
 
-class TestAutotuneCache:
-    def test_measures_once_then_hits(self):
-        import importlib
-
-        import paddle_tpu as paddle
-        from paddle_tpu.core import autotune
-
-        autotune.clear_autotune_cache()
-        autotune.enable_autotune()
-        try:
-            import paddle_tpu.nn.functional as F
-            x = paddle.to_tensor(
-                np.random.RandomState(0).randn(2, 64, 4, 32).astype(
-                    np.float32))
-            F.flash_attention(x, x, x, causal=True)
-            st1 = autotune.autotune_status()
-            assert st1["misses"] == 1
-            assert st1["cache_size"] == 1
-            F.flash_attention(x, x, x, causal=True)
-            st2 = autotune.autotune_status()
-            assert st2["hits"] >= 1
-            assert st2["misses"] == 1  # no re-measure
-            # a different shape is a new key
-            y = paddle.to_tensor(
-                np.random.RandomState(0).randn(1, 32, 2, 16).astype(
-                    np.float32))
-            F.flash_attention(y, y, y, causal=True)
-            assert autotune.autotune_status()["cache_size"] == 2
-        finally:
-            autotune.disable_autotune()
-            autotune.clear_autotune_cache()
-
-    def test_raising_candidate_is_recorded_and_warned(self):
-        """A candidate that raises cannot win, but it is never dropped
-        silently: the reason lands in autotune_status()["failed"] and a
-        RuntimeWarning names it."""
-        import jax.numpy as jnp
-
-        from paddle_tpu.core import autotune
-
-        def call(name):
-            if name == "broken":
-                raise AttributeError("no such compiler params class")
-            return jnp.ones((4,))
-
-        autotune.clear_autotune_cache()
-        autotune.enable_autotune()
-        try:
-            with pytest.warns(RuntimeWarning, match="'broken' of demo_op"):
-                choice, out = autotune.pick_impl(
-                    "demo_op", {"broken": None, "good": None},
-                    (jnp.ones((4,)),), call)
-            assert choice == "good" and out is not None
-            failed = autotune.autotune_status()["failed"]
-            assert list(failed.values()) == [
-                {"broken": "AttributeError: no such compiler params class"}]
-        finally:
-            autotune.disable_autotune()
-            autotune.clear_autotune_cache()
-
-    def test_tile_key_is_batch_agnostic(self):
-        """flash-attn TILE keys ignore batch (the tile optimum is
-        (seq, heads, head-dim)-determined), so a b1-tuned entry serves
-        larger batches; drives _tuned_blocks for real in interpret mode
-        at a shape with >=2 candidate tilings."""
-        import jax.numpy as jnp
-
-        from paddle_tpu.core import autotune, flags
-        from paddle_tpu.ops.pallas.flash_attention import _tuned_blocks
-
-        autotune.clear_autotune_cache()
-        autotune.enable_autotune()
-        flags.set_flags({"pallas_force_interpret": True})
-        try:
-            rng = np.random.RandomState(0)
-
-            def qkv(b):
-                mk = lambda: jnp.asarray(  # noqa: E731
-                    rng.randn(b, 256, 2, 32), jnp.float32) * 0.1
-                return mk(), mk(), mk()
-
-            seed = jnp.zeros((1,), jnp.int32)
-            q1, k1, v1 = qkv(1)
-            _tuned_blocks(q1, k1, v1, None, seed, True, 0.18, 0.0, True)
-            def tile_keys():
-                return sorted(k for k in autotune._CACHE
-                              if k.startswith("flash_attention_blocks")
-                              and not k.endswith("__meta"))
-            tiles = tile_keys()
-            assert len(tiles) == 1, tiles      # a real measurement ran
-            assert "(1, 256, 2, 32)" in tiles[0]  # batch-1 surrogate key
-            # the measured batch rides in a side note so a future sweep
-            # can spot serving-batch drift (advisor r3)
-            assert autotune._CACHE.get(tiles[0] + "__meta") == \
-                "measured_batch=1"
-            misses = autotune.autotune_status()["misses"]
-            q4, k4, v4 = qkv(4)
-            _tuned_blocks(q4, k4, v4, None, seed, True, 0.18, 0.0, True)
-            assert autotune.autotune_status()["misses"] == misses, \
-                "batch-4 call re-measured: tile key not batch-agnostic"
-            assert tile_keys() == tiles
-        finally:
-            flags.set_flags({"pallas_force_interpret": False})
-            autotune.disable_autotune()
-            autotune.clear_autotune_cache()
-
-    def test_cache_file_roundtrip(self, tmp_path):
-        from paddle_tpu.core import autotune
-        autotune.clear_autotune_cache()
-        path = str(tmp_path / "at.json")
-        autotune.set_autotune_cache_file(path)
-        autotune.enable_autotune()
-        try:
-            import paddle_tpu as paddle
-            import paddle_tpu.nn.functional as F
-            x = paddle.to_tensor(
-                np.random.RandomState(0).randn(2, 64, 4, 32).astype(
-                    np.float32))
-            F.flash_attention(x, x, x, causal=True)
-            assert os.path.exists(path)
-            import json
-            data = json.load(open(path))
-            assert len(data) == 1
-            # preload path
-            autotune.clear_autotune_cache()
-            autotune.set_autotune_cache_file(path)
-            assert autotune.autotune_status()["cache_size"] == 1
-        finally:
-            autotune.disable_autotune()
-            autotune.clear_autotune_cache()
-            autotune.set_autotune_cache_file(None)
-
-
 class TestPerDirectionSelection:
     """VERDICT r3 #2: per-direction impl winners — the CE kernel's "xla"
     backward (softmax-minus-onehot from the saved lse) must match the
@@ -683,28 +550,25 @@ class TestPerDirectionSelection:
         assert not np.allclose(np.asarray(g)[0], 0.0)
 
     def test_flash_routing_gqa_defaults_to_xla(self):
-        """Cold cache, no autotune: GQA with a fitting score matrix routes
-        to XLA; MHA and over-budget GQA stay on the Pallas kernel."""
-        from paddle_tpu.ops.pallas.flash_attention import _tuned_blocks
-        seed = jnp.zeros((1,), jnp.int32)
+        """On the chip GQA with a fitting score matrix routes to XLA; MHA
+        and over-budget GQA stay on the Pallas kernel."""
+        from paddle_tpu.ops.pallas.flash_attention import attention_route
 
         def probe(b, s, hq, hk, d=64):
             q = jax.ShapeDtypeStruct((b, s, hq, d), jnp.bfloat16)
             k = jax.ShapeDtypeStruct((b, s, hk, d), jnp.bfloat16)
-            # ShapeDtypeStructs carry shape/dtype; _tuned_blocks only
-            # inspects shapes when autotune is off
-            imp, _, _, out = _tuned_blocks(
-                q, k, k, None, seed, True, d ** -0.5, 0.0, False)
-            assert out is None
-            return imp
+            return attention_route(
+                q, k, None, dropout_rate=0.0, has_key=False, causal=True,
+                window=None, meshed=False, on_tpu=True,
+                force_interpret=False).impl
 
         assert probe(2, 4096, 32, 8) == "xla"       # r3's losing shape
-        assert probe(2, 4096, 16, 16) == "pallas"   # MHA: kernel wins
+        assert probe(2, 4096, 16, 16) == "kernel"   # MHA: kernel wins
         # GQA but score matrix over budget -> flash recompute bwd
-        assert probe(8, 8192, 32, 8) == "pallas"
+        assert probe(8, 8192, 32, 8) == "kernel"
 
     def test_norms_ship_xla_on_tpu_by_default(self):
-        """The norm dispatch defaults (no autotune cache): pallas under
+        """The norm dispatch defaults: pallas under
         interpret/flag, xla otherwise — encoded in the impl wrappers."""
         from paddle_tpu.core import flags as _flags
         from paddle_tpu.ops.pallas.norms import _rms_norm_pallas_impl

@@ -335,12 +335,20 @@ class TestIncubateAutogradASP:
         y = pickle.loads(pickle.dumps(x))
         np.testing.assert_allclose(y.numpy(), x.numpy())
 
-    def test_autotune_set_config(self):
-        from paddle_tpu.core import autotune as core_at
-        paddle.incubate.autotune.set_config({"kernel": {"enable": True}})
-        assert core_at.autotune_status()["use_autotune"]
-        paddle.incubate.autotune.set_config({"kernel": {"enable": False}})
-        assert not core_at.autotune_status()["use_autotune"]
+    def test_autotune_set_config(self, tmp_path):
+        """A parity shim: the config comes back, as a dict or from a JSON
+        path, and no flag moves (tiles and route come from the shape)."""
+        import json
+        from paddle_tpu.core import flags
+        before = dict(flags._REGISTRY)
+        cfg = {"kernel": {"enable": True, "tuning_range": [1, 3]},
+               "layout": {"enable": False}}
+        assert paddle.incubate.autotune.set_config(cfg) == cfg
+        path = tmp_path / "autotune.json"
+        path.write_text(json.dumps(cfg))
+        assert paddle.incubate.autotune.set_config(str(path)) == cfg
+        assert paddle.incubate.autotune.set_config() == {}
+        assert flags._REGISTRY == before
 
 
 class TestIncubateLayers:

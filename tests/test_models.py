@@ -210,8 +210,8 @@ def test_recompute_engages_jax_checkpoint_under_jit():
 
 def test_multistep_scan_matches_single_step_loop():
     """create_multistep_train_step(K) == K create_train_step calls on the
-    same fold sequence — the scan-of-K execute bench.py scores on TPU must
-    be the same math as the single-step loop, not a different trainer."""
+    same fold sequence — the scan-of-K execute must be the same math as
+    the single-step loop, not a different trainer."""
     from paddle_tpu.models import create_multistep_train_step
 
     K = 4
@@ -330,7 +330,7 @@ def test_multistep_scan_with_loss_fn_momentum_batchnorm():
 def test_sharded_multistep_scan_matches_plain_multistep():
     """create_sharded_train_step(steps=K) over dp=2 x tp=4 must produce
     the same per-step losses as the unsharded scan-of-K trainer (the
-    zero3/TP path bench_configs.py times)."""
+    zero3/TP path)."""
     from jax.sharding import Mesh
 
     from paddle_tpu.models import create_multistep_train_step

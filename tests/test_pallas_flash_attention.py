@@ -274,37 +274,6 @@ def test_dispatch_dropout_keeps_pallas_path():
     assert called.get("ext"), "dropout call fell back off the Pallas path"
 
 
-def test_autotune_block_cache_populates_and_consults(tmp_path):
-    """Block-size autotune (VERDICT r2 #2): an eager call measures the
-    candidate (bq, bk) tilings fwd+bwd and caches the winner; the next
-    call (and any traced call) consults the cache instead of re-measuring."""
-    from paddle_tpu.core import autotune as at
-    from paddle_tpu.ops.pallas.flash_attention import _tuned_blocks
-
-    rng = np.random.RandomState(7)
-    q = jnp.asarray(rng.randn(1, 256, 2, 128), jnp.float32) * 0.1
-    k = jnp.asarray(rng.randn(1, 256, 2, 128), jnp.float32) * 0.1
-    v = jnp.asarray(rng.randn(1, 256, 2, 128), jnp.float32) * 0.1
-    seed0 = jnp.zeros((1,), jnp.int32)
-    at.enable_autotune()
-    at.set_autotune_cache_file(str(tmp_path / "cache.json"))
-    try:
-        imp, bq, bk, out = _tuned_blocks(q, k, v, None, seed0, True,
-                                         128.0 ** -0.5, 0.0, True)
-        assert imp == "pallas"
-        assert (bq, bk) in {(128, 128), (256, 256)}
-        assert out is not None            # miss: winner's output returned
-        assert at.autotune_status()["cache_size"] >= 1
-        imp2, bq2, bk2, out2 = _tuned_blocks(q, k, v, None, seed0, True,
-                                             128.0 ** -0.5, 0.0, True)
-        assert (imp2, bq2, bk2) == (imp, bq, bk)
-        assert out2 is None               # hit: no re-measurement
-    finally:
-        at.disable_autotune()
-        at.set_autotune_cache_file(None)
-        at.clear_autotune_cache()
-
-
 class TestVarlenSegments:
     """In-kernel segment-id masking (the TPU form of the reference's
     cu_seqlens varlen contract, flash_attn_kernel.cu:199): packed ragged
@@ -709,13 +678,14 @@ def test_explicit_blocks_win_over_the_plan():
 
 
 def test_tuned_blocks_cold_returns_the_plan():
-    """Autotune off (the default): no measured tile, so the dispatch hands
-    None on and the kernels lower with the plan's."""
+    """The route function says ``kernel``, and the call the dispatch lowers
+    carries the plan's tiles."""
     q, k, v = _mk(1, 512, 512, 2, 2, 64, seed=26)
-    impl, bq, bk, out = fa._tuned_blocks(q, k, v, None, _SEED0, True, 0.125,
-                                         0.0, True)
-    assert (impl, bq, bk, out) == ("pallas", None, None, None)
-    plan = fa._blocks(bq, bk, q, k, v, None, False, 0.0)
+    assert fa.attention_route(
+        q, k, None, dropout_rate=0.0, has_key=False, causal=True,
+        window=None, meshed=False, on_tpu=False,
+        force_interpret=True) == ("kernel", "default")
+    plan = fa._blocks(None, None, q, k, v, None, False, 0.0)
     assert plan == fa.tile_plan(512, 512, 64, 4, 4, 4)
     _flags.set_flags({"pallas_force_interpret": True})
     before = dict(fa.TILE_PLAN_TALLY)

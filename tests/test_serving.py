@@ -14,7 +14,8 @@ from paddle_tpu import profiler, serving
 from paddle_tpu.jit import InputSpec, StaticFunction
 from paddle_tpu.serving import (DeadlineExceeded, Server, ServerClosed,
                                 ServerOverloaded)
-from paddle_tpu.serving.bucketing import next_bucket, pow2_buckets
+from paddle_tpu.serving.bucketing import (next_bucket, pow2_buckets,
+                                          stack_and_pad)
 
 
 @pytest.fixture(autouse=True)
@@ -51,6 +52,22 @@ def _submit_all(srv, examples, deadline_ms=None):
     return futs
 
 
+def _alone_in_bucket(x, bucket):
+    """``x`` as row 0 of a batch of ``bucket`` rows, padded as the server
+    pads a request that is alone in its batch."""
+    return stack_and_pad([x], x.shape, bucket)[0]
+
+
+# What the server promises is that padding rows and neighbours do not change
+# a request's row. It does not promise that XLA's batch-1 program equals its
+# batch-8 program to the last bit: the CPU backend picks another matmul for
+# batch 1 (largest gap seen 2.4e-7). So a reference is computed at the
+# served batch size, and compared bitwise, wherever the test fixes the
+# bucket; where coalescing lets the served batch size vary from run to run,
+# the comparison allows a few float32 ulps of the sums' terms.
+ACROSS_BATCH_SIZES = dict(rtol=1e-5, atol=1e-6)
+
+
 class TestBucketing:
     def test_pow2_buckets_include_max(self):
         assert pow2_buckets(8) == [1, 2, 4, 8]
@@ -76,8 +93,9 @@ class TestCoalescingAndCorrectness:
             futs = _submit_all(srv, examples)
             outs = [f.result(timeout=30) for f in futs]
             st = srv.stats()
+        # batch buckets 1, 2, 4, 8: which one served a request varies
         for got, ref in zip(outs, refs):
-            np.testing.assert_allclose(got, ref, rtol=1e-6)
+            np.testing.assert_allclose(got, ref, **ACROSS_BATCH_SIZES)
         assert st["completed"] == 32
         # coalescing actually happened: fewer dispatches than requests,
         # and at least one batch had more than one request in it
@@ -89,9 +107,10 @@ class TestCoalescingAndCorrectness:
         sf = StaticFunction(net)
         rng = np.random.RandomState(1)
         x = rng.randn(8).astype(np.float32)
-        # unpadded reference at batch 1, straight through the jit path
+        # the reference at the bucket's batch size, padded the same way,
+        # straight through the jit path
         ref = np.asarray(sf._build()(
-            sf._state(), jax.random.key(0), x[None]))[0]
+            sf._state(), jax.random.key(0), _alone_in_bucket(x, 8)))[0]
         with Server(sf, max_batch_size=8, batch_buckets=[8],
                     batch_timeout_ms=1) as srv:
             got = srv.run(x, timeout=30)   # padded 1 -> 8 inside
@@ -133,9 +152,10 @@ class TestExecutableCache:
         jitted = sf._build()
         for x, got in zip(examples, outs):
             assert got.shape == (len(x), 256)
-            ref = np.asarray(jitted(state, key0, x[None]))[0]
+            ref = np.asarray(jitted(state, key0, _alone_in_bucket(x, 8)))[0]
             if len(x) in (16, 32):
-                # bucket-aligned: batch padding alone is bitwise
+                # bucket-aligned: at the bucket's batch size neither the
+                # padding rows nor the neighbours change a row
                 np.testing.assert_array_equal(got, ref)
             else:
                 # sequence padding reassociates the attention softmax
@@ -167,14 +187,13 @@ class TestPredictorServing:
         model = LlamaForCausalLM(llama_tiny())
         model.eval()
         served = str(tmp_path / "served")    # batch-4 artifact to serve
-        single = str(tmp_path / "single")    # batch-1 reference artifact
         jit.save(model, served, input_spec=[InputSpec([4, 16], "int64")])
-        jit.save(model, single, input_spec=[InputSpec([1, 16], "int64")])
 
         cfg = Config(served)
         cfg.enable_serving(batch_timeout_ms=20, max_queue_size=64)
         pred = create_predictor(cfg)
-        ref_pred = create_predictor(Config(single))
+        # the reference: a plain run() of the same exported program
+        ref_pred = create_predictor(Config(served))
 
         rng = np.random.RandomState(4)
         examples = [rng.randint(0, 250, (16,)).astype(np.int64)
@@ -189,7 +208,7 @@ class TestPredictorServing:
         assert st["compile_count"] == 1
         assert st["completed"] == 12
         for x, got in zip(examples, outs):
-            ref = ref_pred.run([x[None]])[0][0]
+            ref = ref_pred.run([_alone_in_bucket(x, 4)])[0][0]
             np.testing.assert_array_equal(got, ref)
 
     def test_submit_without_enable_serving_raises(self, tmp_path):
@@ -302,7 +321,9 @@ class TestShutdown:
         assert all(f.done() for f in futs)
         for x, f in zip(examples, futs):
             ref = net(paddle.to_tensor(x[None])).numpy()[0]
-            np.testing.assert_allclose(f.result(0), ref, rtol=1e-6)
+            # batch buckets 1, 2, 4: which one served a request varies
+            np.testing.assert_allclose(f.result(0), ref,
+                                       **ACROSS_BATCH_SIZES)
         with pytest.raises(ServerClosed):
             srv.submit(examples[0])
 

@@ -36,8 +36,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TIER2_PATTERNS = ("tests/test_zz_*.py", "tests/test_serving_router*.py",
                   "tests/test_graft_lint_wave4.py",
                   "tests/test_graft_lint_wave5.py",
-                  "tests/test_kernel_hygiene_fixes.py",
-                  "tests/test_check_bench_ratios.py")
+                  "tests/test_kernel_hygiene_fixes.py")
 
 
 def tier2_files() -> list:
