@@ -18,9 +18,22 @@ Design (online-softmax, Dao et al. 2022, re-derived for the MXU):
   fits, up to 1024 x 1024, since a grid step costs about half a microsecond
   with nothing in it and 128 x 128 blocks spent the kernels' time there
   (PERF.md, "PR 26"). A call whose estimate passes Mosaic's 16 MiB of
-  scoped VMEM asks for what it needs (``_call_params``). Explicit block
-  arguments win over the plan. Every lowered call leaves a
-  ``flash::tile_plan`` trace event and a count in ``TILE_PLAN_TALLY``.
+  scoped VMEM asks for what it needs (``_compiler_params``). Explicit
+  block arguments win over the plan. Every call leaves a
+  ``flash::tile_plan`` trace event and a count in ``TILE_PLAN_TALLY``; the
+  three Mosaic calls are jitted on their own, so a model's equal layers
+  share one trace and one lowering of each.
+- sub-tiles: a grid step does not compute a (block_q, block_k) tile in one
+  piece where an edge of what attention may see crosses it. It walks it in
+  the plan's (sub_q, sub_k) sub-tiles, static slices of the refs it already
+  holds: what lies above the diagonal, below a window's band or in the
+  padded keys is never entered, and only a sub-tile that an edge crosses is
+  masked. Where the edges lie in a tile is one of a few static facts, so a
+  kernel holds one straight-line body for each kind of tile its grid meets
+  (PERF.md, "PR 31"). A tile that nothing crosses, and every tile of a call
+  that is neither causal nor padded, is computed in one piece, unmasked.
+  ``_tile`` decides all of this once a call; its ``Tile`` is what the
+  kernel lowers and what the event reports.
 - route: ``attention_route`` decides from the same kind of facts whether a
   dense call takes these kernels or XLA's attention.
 - forward: grid (batch*heads, q_blocks, k_blocks) with the k dimension
@@ -85,8 +98,8 @@ _KERNEL_NAMES = {"fwd": "flash_fwd", "dq": "flash_bwd_dq",
 # (benchmarks/metrics/window_attn_roofline.py)
 _WIN_KERNEL_NAMES = {"fwd": "flash_win_fwd", "dq": "flash_win_bwd_dq",
                      "dkv": "flash_win_bwd_dkv"}
-# one count per lowered pallas_call, by (kernel name, bq, bk): trace time
-# only, nothing per step
+# one count per lowered pallas_call, by (kernel name, bq, bk, sub_q, sub_k):
+# trace time only, nothing per step
 TILE_PLAN_TALLY: collections.Counter = collections.Counter()
 
 
@@ -185,12 +198,12 @@ def dropout_keep_mask(seed, bh_total, sq, sk, rate):
 # ---------------------------------------------------------------------------
 
 def _hide(s, q_start, k_start, *, pad_keys, causal, offset, sk_real,
-          qseg_ref, kseg_ref, seg_causal, window=None):
-    """The (bq, bk) score block at (q_start, k_start) with what attention
-    may not see set to -inf: padded key columns, what lies above the
-    (Sk - Sq)-offset causal diagonal, what lies ``window`` or more keys
-    below it, other segments. A term is built only where it can hide
-    something."""
+          qseg=None, kseg=None, seg_causal=False, window=None):
+    """The score block whose first score is (q_start, k_start) with what
+    attention may not see set to -inf: padded key columns, what lies above
+    the (Sk - Sq)-offset causal diagonal, what lies ``window`` or more keys
+    below it, other segments (``qseg`` (rows, 1) and ``kseg`` (1, cols)
+    encoded words). A term is built only where it can hide something."""
     mask = None
     if pad_keys or causal:
         kidx = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
@@ -202,19 +215,22 @@ def _hide(s, q_start, k_start, *, pad_keys, causal, offset, sk_real,
         mask = diag if mask is None else mask & diag
         if window is not None:
             mask = mask & (kidx > qidx + np.int32(offset - window))
-    if qseg_ref is not None:  # varlen packing: never across sequences
-        seg = _seg_mask(qseg_ref[0], kseg_ref[0], seg_causal)
+    if qseg is not None:  # varlen packing: never across sequences
+        seg = _seg_mask(qseg, kseg, seg_causal)
         mask = seg if mask is None else mask & seg
     return s if mask is None else jnp.where(mask, s, _NEG_INF)
 
 
 def _when_visible(body, q_start, k_start, bq, *, causal, offset, bk=None,
                   window=None, inside=None):
-    """Run ``body`` unless the score block at (q_start, k_start) lies wholly
+    """Run ``body`` unless the (bq, bk) tile at (q_start, k_start) lies wholly
     above the causal diagonal (its first key column beyond the horizon of
     its last query row), wholly below the window's band (its last key
     column ``window`` or more behind its first query row), or, on a band
-    grid, past the operand's end (``inside`` false)."""
+    grid, past the operand's end (``inside`` false). This is the skip at
+    the grain of a grid step: a skipped step moves no data (the index maps
+    clamp). Inside a tile that runs, ``body`` skips at the grain of the
+    sub-tile (``_key_subs``, ``_query_subs``)."""
     run = True
     if causal:
         run = k_start <= q_start + bq - 1 + offset
@@ -223,6 +239,240 @@ def _when_visible(body, q_start, k_start, bq, *, causal, offset, bk=None,
                               > q_start + np.int32(offset - window))
         run = jnp.logical_and(run, inside)
     pl.when(run)(body)
+
+
+# ---------------------------------------------------------------------------
+# sub-tiles: a grid step does not compute its (bq, bk) tile in one piece
+# where an edge of what attention may see crosses it. It walks it in (sub_q,
+# sub_k) pieces of the refs it already holds: what lies above the diagonal,
+# below the band or in the padding is never entered, and only a piece that
+# an edge crosses is masked. Where the edges lie in a tile is one of a few
+# static facts (``_tile_kinds``), so a walk is straight-line code over
+# static slices, one body a kind: a loop with dynamic bounds was measured
+# and lost more to its serial trips than the skip won (PERF.md, "PR 31").
+# The same Python arithmetic lays out the walks and gives the
+# ``flash::tile_plan`` event its counts.
+# ---------------------------------------------------------------------------
+
+def _pieces(vis_lo, vis_hi, plain_lo, plain_hi, sub, n):
+    """Of ``n`` pieces of length ``sub`` laid from 0: (lo, a, b, hi) where
+    [lo, hi) are the pieces that hold a position of [vis_lo, vis_hi) and
+    [a, b), within them, those that lie wholly inside [plain_lo, plain_hi).
+    [lo, a) and [b, hi) are then the pieces an edge crosses."""
+    span = n * sub
+
+    def whole_below(x):     # pieces that end at or before x
+        return min(max(x, 0), span) // sub
+
+    def begun_below(x):     # pieces that start before x
+        return (min(max(x, 0), span) + sub - 1) // sub
+    lo = whole_below(vis_lo)
+    hi = max(begun_below(vis_hi), lo)
+    a = min(max(begun_below(plain_lo), lo), hi)
+    b = min(max(whole_below(plain_hi), a), hi)
+    return lo, a, b, hi
+
+
+def _key_subs(r0, sub_q, k_start, sub_k, nsk, *, causal, offset, window,
+              sk_real):
+    """The key sub-tiles, of the ``nsk`` from ``k_start``, that the query
+    rows ``r0 .. r0 + sub_q - 1`` enter: ``_pieces`` over key columns. A row
+    r sees the real keys in (r + offset - window, r + offset]."""
+    real = sk_real - k_start
+    vis_lo = plain_lo = 0
+    vis_hi = plain_hi = real
+    if causal:
+        vis_hi = min(r0 + sub_q + offset - k_start, real)
+        plain_hi = min(r0 + offset + 1 - k_start, real)
+    if window is not None:
+        vis_lo = r0 + offset - window + 1 - k_start
+        plain_lo = vis_lo + sub_q - 1
+    return _pieces(vis_lo, vis_hi, plain_lo, plain_hi, sub_k, nsk)
+
+
+def _query_subs(c0, sub_k, q_start, sub_q, nsq, *, causal, offset, window,
+                sk_real):
+    """The query sub-tiles, of the ``nsq`` from ``q_start``, that see the key
+    columns ``c0 .. c0 + sub_k - 1``: ``_pieces`` over query rows. A real key
+    c is seen by the rows in [c - offset, c - offset + window); a piece that
+    holds a padded key is masked whole, one of padded keys alone is left."""
+    vis_lo = plain_lo = 0
+    vis_hi = plain_hi = nsq * sub_q
+    if causal:
+        vis_lo = c0 - offset - q_start
+        plain_lo = vis_lo + sub_k - 1
+    if window is not None:
+        plain_hi = c0 + window - offset - q_start
+        vis_hi = plain_hi + sub_k - 1
+    if c0 >= sk_real:
+        vis_hi = 0
+    if c0 + sub_k > sk_real:
+        plain_hi = 0
+    return _pieces(vis_lo, vis_hi, plain_lo, plain_hi, sub_q, nsq)
+
+
+# a tile's kind is (delta, real). ``delta`` places the diagonal in the tile,
+# q_start + offset - k_start: the column of the tile that its first row sees
+# last; an int where the diagonal or the band's lower edge crosses the tile,
+# None where neither does, _ANY for every tile one of them or the padding
+# touches, where the tiles are not walked. ``real`` counts the tile's real
+# keys where it holds padded ones too, else None.
+_ANY = "any"
+# the walked bodies a kernel may hold. This bounds the kernel's text, which
+# grows by one unrolled body a kind, and is no tuned number: the plan's own
+# tiles meet at most three kinds (square tiles on a diagonal at a multiple of
+# the side, one of them padded). A call that meets more (explicit block sides
+# that differ and share no large divisor with the offset) is not walked.
+_MAX_WALKS = 4
+
+
+def _tile_kinds(bq, bk, nq, nk, *, causal, offset, window, sk_real):
+    """The kinds of the tiles that run, over the whole grid of a call."""
+    kinds = set()
+    for qi in range(nq):
+        lo, _, _, hi = _key_subs(qi * bq, bq, 0, bk, nk, causal=causal,
+                                 offset=offset, window=window,
+                                 sk_real=sk_real)
+        for ki in range(lo, hi):
+            delta = qi * bq + offset - ki * bk
+            inside = not causal or (delta >= bk - 1 and (
+                window is None or delta <= window - bq))
+            real = sk_real - ki * bk
+            kinds.add((None if inside else delta,
+                       real if real < bk else None))
+    return kinds
+
+
+def _tile_is(kind, q_start, k_start, ki, *, bq, bk, causal, offset, window,
+             nk_all, pad_keys):
+    """Whether the tile at (q_start, k_start), key block ``ki`` of
+    ``nk_all``, is of ``kind``: traced, all i32."""
+    delta, real = kind
+    here = q_start + np.int32(offset) - k_start
+    inside = True
+    if causal:
+        inside = here >= np.int32(bk - 1)
+        if window is not None:
+            inside = jnp.logical_and(inside, here <= np.int32(window - bq))
+    last = ki == np.int32(nk_all - 1)
+    whole = jnp.logical_not(last) if pad_keys else True
+    if delta == _ANY:
+        return jnp.logical_not(jnp.logical_and(inside, whole))
+    hit = inside if delta is None else here == np.int32(delta)
+    return jnp.logical_and(hit, whole if real is None else last)
+
+
+def _walks(sweeps, kind, bq, bk, sub_q, sub_k, *, causal, window, segments):
+    """The static walk of a tile of ``kind``, in the tile's own rows and
+    columns: [(outer, inner, runs, local)] with ``outer`` the slice of the
+    side a kernel accumulates over (``sweeps`` "k": fwd and dq, ``sub_q``
+    query rows; "q": dkv, ``sub_k`` keys), ``inner`` the contiguous slice
+    of the other side that those enter, ``runs`` its cut into [(first,
+    last, masked)] from the slice's start, and ``local`` the edges as
+    ``_hide`` takes them in the tile's own coordinates."""
+    delta, real = kind
+    local = dict(causal=causal and delta is not None, offset=delta or 0,
+                 window=window if delta is not None else None,
+                 sk_real=bk if real is None else real)
+    out = []
+    for o in range((bq // sub_q) if sweeps == "k" else (bk // sub_k)):
+        if sweeps == "k":
+            sub_o, sub_i = sub_q, sub_k
+            lo, a, b, hi = _key_subs(o * sub_q, sub_q, 0, sub_k, bk // sub_k,
+                                     **local)
+        else:
+            sub_o, sub_i = sub_k, sub_q
+            lo, a, b, hi = _query_subs(o * sub_k, sub_k, 0, sub_q,
+                                       bq // sub_q, **local)
+        cuts = [(lo, hi, True)] if segments else \
+            [(lo, a, True), (a, b, False), (b, hi, True)]
+        runs = [((x - lo) * sub_i, (y - lo) * sub_i, masked)
+                for x, y, masked in cuts if y > x]
+        if runs:
+            out.append((slice(o * sub_o, (o + 1) * sub_o),
+                        slice(lo * sub_i, hi * sub_i), runs,
+                        dict(local, pad_keys=real is not None)))
+    return out
+
+
+def _is_walked(kind, bq, bk, sub_q, sub_k):
+    """Whether a tile of ``kind`` is walked in its sub-tile: where an edge
+    or the padding touches it and the sub-tile is not the tile itself. A
+    tile nothing touches has nothing to skip: one piece, the largest the
+    MXU is fed with."""
+    return kind != (None, None) and (sub_q, sub_k) != (bq, bk)
+
+
+def _hide_runs(s, axis, runs, hide):
+    """``s`` with ``hide(piece, first)`` in place of each masked run of its
+    ``axis``: the runs start at whole sublanes or lanes, so cutting and
+    joining moves nothing."""
+    if len(runs) == 1:
+        (first, _, masked), = runs
+        return hide(s, first) if masked else s
+    parts = []
+    for first, last, masked in runs:
+        piece = jax.lax.slice_in_dim(s, first, last, axis=axis)
+        parts.append(hide(piece, first) if masked else piece)
+    return jnp.concatenate(parts, axis=axis)
+
+
+def _attend_tile(attend, sweeps, kinds, q_start, k_start, ki, qseg_ref,
+                 kseg_ref, *, bq, bk, sub_q, sub_k, nk_all, pad_keys, causal,
+                 offset, sk_real, window, inside, seg_causal):
+    """What a kernel does with the tile at (q_start, k_start), key block
+    ``ki`` of ``nk_all``, unless no query row of it sees a key of it
+    (``_when_visible``): ``attend(rows, cols, hide)`` on static slices of
+    the tile, the scores of each passed through ``hide(s)``. One body for
+    each of the ``kinds`` of tile the grid meets, run where the tile is of
+    that kind: walked in (sub_q, sub_k) as ``sweeps`` says (``_walks``),
+    with the edges in the tile's own rows and columns, or in one piece,
+    with the call's edges where one touches the tile (``_ANY``) and the
+    segment ids alone, if any, where none does."""
+    edges = dict(pad_keys=pad_keys, causal=causal, offset=offset,
+                 sk_real=sk_real, window=window)
+    whole = slice(None)
+
+    def segs(rows, cols):
+        if qseg_ref is None:
+            return {}
+        return dict(qseg=qseg_ref[0, rows, :], kseg=kseg_ref[0, :, cols],
+                    seg_causal=seg_causal)
+
+    def one_piece(kind):
+        on = edges if kind[0] == _ANY else dict(
+            edges, causal=False, pad_keys=False, window=None)
+        attend(whole, whole, lambda s: _hide(s, q_start, k_start, **on,
+                                             **segs(whole, whole)))
+
+    def walked(kind):
+        axis = 1 if sweeps == "k" else 0      # the side the runs cut
+        for outer, inner, runs, local in _walks(
+                sweeps, kind, bq, bk, sub_q, sub_k, causal=causal,
+                window=window, segments=qseg_ref is not None):
+            rows, cols = (outer, inner) if sweeps == "k" else (inner, outer)
+
+            def hide(piece, first, rows=rows, cols=cols, local=local):
+                at = [rows.start, cols.start]
+                at[axis] += first
+                r, c = (slice(o, o + n) for o, n in zip(at, piece.shape))
+                return _hide(piece, *at, **local, **segs(r, c))
+            attend(rows, cols, functools.partial(
+                _hide_runs, axis=axis, runs=runs, hide=hide))
+
+    def bodies():
+        for kind in kinds:
+            body = functools.partial(
+                walked if _is_walked(kind, bq, bk, sub_q, sub_k)
+                else one_piece, kind)
+            if len(kinds) == 1:
+                body()
+            else:
+                pl.when(_tile_is(kind, q_start, k_start, ki, bq=bq, bk=bk,
+                                 causal=causal, offset=offset, window=window,
+                                 nk_all=nk_all, pad_keys=pad_keys))(body)
+    _when_visible(bodies, q_start, k_start, bq, causal=causal, offset=offset,
+                  bk=bk, window=window, inside=inside)
 
 
 def _band(bq, bk, offset, window, nq, nk, py=False):
@@ -272,12 +522,15 @@ def _band_grid(sweeps, window, bq, bk, offset, nq, nk):
                                            nk_all=nk)
 
 
-def _fwd_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, pad_keys,
-                has_bias, has_seg, seg_causal, rate, window=None, nq_all=None,
-                nk_all=None):
+def _fwd_kernel(*refs, scale, causal, offset, bq, bk, sub_q, sub_k, kinds,
+                nk, sk_real, pad_keys, has_bias, has_seg, seg_causal, rate,
+                window=None, nq_all=None, nk_all=None):
     """``nk`` is the grid's extent over key blocks: all of them, or with a
     ``window`` the band's (the step ``kj`` then works on key block
-    ``k_first(qi) + kj`` of ``nk_all``)."""
+    ``k_first(qi) + kj`` of ``nk_all``). ``kinds`` are the kinds of tile the
+    grid meets (``_tile``): a body each, walked in (sub_q, sub_k) for
+    each ``sub_q`` query rows over the keys those rows see, or, where the
+    sub-tile is the tile, computed in one piece."""
     scale = np.float32(scale)  # strong f64 scalars poison Mosaic under x64
     it = iter(refs)
     q_ref, k_ref, v_ref = next(it), next(it), next(it)
@@ -304,47 +557,50 @@ def _fwd_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, pad_keys,
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    def _body():
+    def attend(rows, cols, hide):
+        """The online softmax of the query rows ``rows`` over the keys
+        ``cols`` of the tile, their scores passed through ``hide``."""
         # inputs stay in storage dtype (bf16 on the training path): the MXU
         # multiplies bf16 natively at 2x f32 rate, accumulating f32 via
         # preferred_element_type; scale is applied to the f32 product
-        q = q_ref[0]                                             # (bq, d)
-        k = k_ref[0]                                             # (bk, d)
+        q = q_ref[0, rows, :]                                    # (sq, d)
+        k = k_ref[0, cols, :]                                    # (sk, d)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if has_bias:
-            s = s + bias_ref[0].astype(jnp.float32)
-        s = _hide(s, q_start, k_start, pad_keys=pad_keys, causal=causal,
-                  offset=offset, sk_real=sk_real, qseg_ref=qseg_ref,
-                  kseg_ref=kseg_ref, seg_causal=seg_causal, window=window)
-
-        m_prev = m_ref[...]                                      # (bq, LANES)
-        s_max = jnp.max(s, axis=1, keepdims=True)                # (bq, 1)
+            brows = slice(None) if bias_ref.shape[1] == 1 else rows
+            s = s + bias_ref[0, brows, cols].astype(jnp.float32)
+        s = hide(s)
+        m_prev = m_ref[rows, :]                                  # (sq, LANES)
+        s_max = jnp.max(s, axis=1, keepdims=True)                # (sq, 1)
         m_new = jnp.maximum(m_prev, jnp.broadcast_to(s_max, m_prev.shape))
         # fully-masked-so-far rows keep m = -inf; use a safe exponent base so
         # exp() never sees (-inf) - (-inf)
         m_safe = jnp.where(m_new == _NEG_INF, _F0, m_new)
-        alpha = jnp.exp(m_prev - m_safe)                         # (bq, LANES)
-        p = jnp.exp(s - m_safe[:, :1])                           # (bq, bk)
+        alpha = jnp.exp(m_prev - m_safe)                         # (sq, LANES)
+        p = jnp.exp(s - m_safe[:, :1])                           # (sq, sk)
         # l and lse come from the UNDROPPED probabilities (dropout applies
         # after softmax); only the value accumulation sees the mask
-        l_ref[...] = alpha * l_ref[...] + jnp.broadcast_to(
+        l_ref[rows, :] = alpha * l_ref[rows, :] + jnp.broadcast_to(
             jnp.sum(p, axis=1, keepdims=True), m_prev.shape)
         if rate > 0.0:
-            keep = _keep_block(_mix_seed(seed_ref[0], bh), q_start, k_start,
-                               bq, bk, sk_real, _dropout_thresh(rate))
-            p_v = jnp.where(keep, p * np.float32(1.0 / (1.0 - rate)), _F0)
-        else:
-            p_v = p
-        v = v_ref[0]                                             # (bk, d)
+            keep = _keep_block(
+                _mix_seed(seed_ref[0], bh), q_start + (rows.start or 0),
+                k_start + (cols.start or 0), *s.shape, sk_real,
+                _dropout_thresh(rate))
+            p = jnp.where(keep, p * np.float32(1.0 / (1.0 - rate)), _F0)
+        v = v_ref[0, cols, :]                                    # (sk, d)
         # probabilities ride the MXU in v's storage dtype (bf16-safe: p in
         # [0,1], the f32 accumulator keeps the sum exact enough)
-        acc_ref[...] = acc_ref[...] * alpha[:, :1] + jax.lax.dot(
-            p_v.astype(v.dtype), v, preferred_element_type=jnp.float32)
-        m_ref[...] = m_new
+        acc_ref[rows, :] = acc_ref[rows, :] * alpha[:, :1] + jax.lax.dot(
+            p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+        m_ref[rows, :] = m_new
 
-    _when_visible(_body, q_start, k_start, bq, causal=causal, offset=offset,
-                  bk=bk, window=window, inside=inside)
+    _attend_tile(
+        attend, "k", kinds, q_start, k_start, ki, qseg_ref, kseg_ref, bq=bq,
+        bk=bk, sub_q=sub_q, sub_k=sub_k, nk_all=nk_all or nk,
+        pad_keys=pad_keys, causal=causal, offset=offset, sk_real=sk_real,
+        window=window, inside=inside, seg_causal=seg_causal)
 
     @pl.when(kj == nk - 1)
     def _fin():
@@ -398,29 +654,82 @@ def _query_block(causal, bq, bk, offset, nq, window=None, nk=None):
     return qblk
 
 
-def _call_params(kernel, semantics, q3, kx, vx, bias3, dbias, has_seg, rate,
-                 bq, bk, offset, causal, window=None):
-    """``CompilerParams`` of one lowered call of ``kernel`` ("fwd", "dq",
-    "dkv"), which it also records: a ``flash::tile_plan`` trace event and a
-    count in ``TILE_PLAN_TALLY``. Mosaic's scoped VMEM default is 16 MiB;
-    a tile whose estimate does not fit it asks for what it needs, with the
-    room the estimate leaves out (spills, the compiler's own scratch)."""
-    from ...profiler.tracing import trace_event
-    bhq, sq, d = q3.shape
-    nq, nk = sq // bq, kx.shape[1] // bk
-    vmem = _vmem_bytes(kernel, bq, bk, d, q3.dtype.itemsize,
-                       kx.dtype.itemsize, vx.dtype.itemsize,
+def _sub_tile_counts(kernel, bq, bk, sub_q, sub_k, nq, nk, *, causal, offset,
+                     window, sk_real, segments):
+    """(entered, masked) sub-tiles of one (batch, q head) of a call, by the
+    arithmetic that lays out the kernels' walks. A tile computed in one
+    piece (the sub-tile is the tile) is entered if it runs and masked if an
+    edge or the padding touches it; under segment ids whatever is entered
+    keeps its mask."""
+    edges = dict(causal=causal, offset=offset, window=window,
+                 sk_real=sk_real)
+    entered = plain = 0
+    for qi in range(nq):
+        # the tiles that run are the pieces of a walk at the tile's grain
+        t_lo, t_a, t_b, t_hi = _key_subs(qi * bq, bq, 0, bk, nk, **edges)
+        if (sub_q, sub_k) == (bq, bk):
+            entered, plain = entered + t_hi - t_lo, plain + t_b - t_a
+            continue
+        for ki in range(t_lo, t_hi):
+            if kernel == "dkv":
+                walks = (_query_subs(ki * bk + j * sub_k, sub_k, qi * bq,
+                                     sub_q, bq // sub_q, **edges)
+                         for j in range(bk // sub_k))
+            else:
+                walks = (_key_subs(qi * bq + i * sub_q, sub_q, ki * bk,
+                                   sub_k, bk // sub_k, **edges)
+                         for i in range(bq // sub_q))
+            for lo, a, b, hi in walks:
+                entered, plain = entered + hi - lo, plain + b - a
+    return entered, entered if segments else entered - plain
+
+
+def _call_vmem(kernel, q3, kx, vx, bias3, dbias, has_seg, rate, tile):
+    return _vmem_bytes(kernel, tile.bq, tile.bk, q3.shape[2],
+                       q3.dtype.itemsize, kx.dtype.itemsize,
+                       vx.dtype.itemsize,
                        bias3.dtype.itemsize if bias3 is not None else 0,
                        dbias, has_seg, rate > 0.0)
+
+
+def _compiler_params(semantics, vmem):
+    """``CompilerParams`` of a call whose VMEM estimate is ``vmem``. Mosaic's
+    scoped VMEM default is 16 MiB; a tile whose estimate does not fit it
+    asks for what it needs, with the room the estimate leaves out (spills,
+    the compiler's own scratch)."""
+    limit = None
+    if vmem > _VMEM_DEFAULT_LIMIT * 3 // 4:
+        limit = min(int(vmem * 1.5), _VMEM_MAX_LIMIT)
+    return pltpu.CompilerParams(dimension_semantics=semantics,
+                                vmem_limit_bytes=limit)
+
+
+def _record_call(kernel, q3, kx, vx, bias3, dbias, has_seg, rate, tile,
+                 offset, causal, sk_real, window=None):
+    """One call of ``kernel`` ("fwd", "dq", "dkv") on its way to be lowered:
+    a ``flash::tile_plan`` trace event and a count in ``TILE_PLAN_TALLY``.
+    Python at trace time, outside the jitted call, so every call counts
+    though equal calls share one lowering."""
+    from ...profiler.tracing import trace_event
+    bq, bk, sub_q, sub_k, _ = tile
+    bhq, sq, _ = q3.shape
+    nq, nk = sq // bq, kx.shape[1] // bk
     # blocks wholly above the diagonal: the steps causality skips
     skipped = sum(1 for qi in range(nq) for ki in range(nk)
                   if ki * bk > qi * bq + bq - 1 + offset) if causal else 0
+    entered, masked = _sub_tile_counts(
+        kernel, bq, bk, sub_q, sub_k, nq, nk, causal=causal, offset=offset,
+        window=window, sk_real=sk_real, segments=has_seg)
+    every = (sq // sub_q) * (kx.shape[1] // sub_k)
+    attrs = dict(bq=bq, bk=bk, sub_q=sub_q, sub_k=sub_k,
+                 sub_tiles=bhq * entered,
+                 sub_tiles_skipped=bhq * (every - entered),
+                 sub_tiles_masked=bhq * masked,
+                 vmem_bytes=_call_vmem(kernel, q3, kx, vx, bias3, dbias,
+                                       has_seg, rate, tile))
     if window is None:
         name = _KERNEL_NAMES[kernel]
-        TILE_PLAN_TALLY[(name, bq, bk)] += 1
-        trace_event("flash::tile_plan", cat="kernel", kernel=name, bq=bq,
-                    bk=bk, grid_steps=bhq * nq * nk,
-                    skipped_steps=bhq * skipped, vmem_bytes=vmem)
+        attrs.update(grid_steps=bhq * nq * nk, skipped_steps=bhq * skipped)
     else:
         # the grid holds the band's steps only; of those, the ones past a
         # block's own band (the band is narrower at the sequence's ends)
@@ -434,27 +743,43 @@ def _call_params(kernel, semantics, q3, kx, vx, bias3, dbias, has_seg, rate,
             steps = nq * _band_extent(k_first, k_last, nq)
             run = sum(max(k_last(i) - k_first(i) + 1, 0) for i in range(nq))
         name = _WIN_KERNEL_NAMES[kernel]
-        TILE_PLAN_TALLY[(name, bq, bk)] += 1
-        trace_event("flash::tile_plan", cat="kernel", kernel=name, bq=bq,
-                    bk=bk, grid_steps=bhq * steps,
-                    skipped_steps=bhq * (steps - run), vmem_bytes=vmem,
-                    window=window,
-                    band_skipped_steps=bhq * (nq * nk - steps))
-    limit = None
-    if vmem > _VMEM_DEFAULT_LIMIT * 3 // 4:
-        limit = min(int(vmem * 1.5), _VMEM_MAX_LIMIT)
-    return pltpu.CompilerParams(dimension_semantics=semantics,
-                                vmem_limit_bytes=limit)
+        attrs.update(grid_steps=bhq * steps,
+                     skipped_steps=bhq * (steps - run), window=window,
+                     band_skipped_steps=bhq * (nq * nk - steps))
+    TILE_PLAN_TALLY[(name, bq, bk, sub_q, sub_k)] += 1
+    trace_event("flash::tile_plan", cat="kernel", kernel=name, **attrs)
 
 
+class _Static(dict):
+    """A dict of plain values that a jitted call takes as a static
+    argument."""
+
+    def __hash__(self):
+        return hash(tuple(sorted(self.items())))
+
+
+# the three calls are jitted on their own: a model's layers make equal calls,
+# and a walked body is long (one straight-line piece a row block), so tracing
+# and lowering it to Mosaic once a layer cost the GPT-2 cell's twelve layers
+# 6 to 9 s of set-up. Equal calls share one trace, and one lowering a module
+# (that cell's step: 3.5 s to trace and lower, 6.1 s before tiles were
+# walked; PERF.md, "PR 31"); XLA inlines the calls, so the compiled step and
+# its instructions' names are what they were.
+_CALL_STATICS = ("hq", "hk", "causal", "scale", "offset", "sk_real", "tile",
+                 "bias_maps", "interpret", "window")
+
+
+@functools.partial(jax.jit, static_argnames=_CALL_STATICS)
 def _fwd(q3, k3, v3, bias3, seed, hq, hk, causal, scale, offset, sk_real,
-         bq, bk, bias_maps, interpret, qseg3=None, kseg3=None, window=None):
+         tile, bias_maps, interpret, qseg3=None, kseg3=None, window=None):
     """q3: (B*Hq, Sq, D) padded; k3/v3: (B*Hk, Sk, D) padded; bias3:
     (Bb*Hb, Sqb, Sk_pad) or None; seed: (1,) i32 or None; qseg3/kseg3:
-    (B*Hq, Sq, 1) / (B*Hq, 1, Sk) i32 segment ids or None. With a
+    (B*Hq, Sq, 1) / (B*Hq, 1, Sk) i32 segment ids or None; ``tile`` the
+    plan's ``Tile`` of the forward; ``bias_maps`` a ``_Static``. With a
     ``window`` the grid's last dim holds the band's key blocks only."""
     bhq, sq, d = q3.shape
     sk = k3.shape[1]
+    bq, bk, sub_q, sub_k, kinds = tile
     nq, nk_all = sq // bq, sk // bk
     nk, names, band = _band_grid("k", window, bq, bk, offset, nq, nk_all)
     grid = (bhq, nq, nk)
@@ -488,8 +813,9 @@ def _fwd(q3, k3, v3, bias3, seed, hq, hk, causal, scale, offset, sk_real,
 
     rate = bias_maps["rate"]
     kernel = functools.partial(
-        _fwd_kernel, scale=scale, causal=causal, offset=offset,
-        bq=bq, bk=bk, nk=nk, sk_real=sk_real, pad_keys=sk != sk_real,
+        _fwd_kernel, scale=scale, causal=causal, offset=offset, bq=bq, bk=bk,
+        sub_q=sub_q, sub_k=sub_k, kinds=kinds, nk=nk, sk_real=sk_real,
+        pad_keys=sk != sk_real,
         has_bias=has_bias, has_seg=has_seg,
         seg_causal=bias_maps.get("seg_causal", False), rate=rate, **band)
     out, lse = pl.pallas_call(
@@ -509,13 +835,25 @@ def _fwd(q3, k3, v3, bias3, seed, hq, hk, causal, scale, offset, sk_real,
             pltpu.VMEM((bq, _LANES), jnp.float32),
             pltpu.VMEM((bq, _LANES), jnp.float32),
         ],
-        compiler_params=_call_params(
-            "fwd", ("parallel", "parallel", "arbitrary"), q3, k3, v3, bias3,
-            False, has_seg, rate, bq, bk, offset, causal, window),
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary"),
+            _call_vmem("fwd", q3, k3, v3, bias3, False, has_seg, rate, tile)),
         interpret=interpret,
         name=names["fwd"],
     )(*args)
     return out, lse[..., 0]
+
+
+def _fwd_impl(q3, k3, v3, bias3, seed, hq, hk, causal, scale, offset,
+              sk_real, tile, bias_maps, interpret, qseg3=None, kseg3=None,
+              window=None):
+    """The forward kernel over padded operands, as ``_bwd_impl`` is the
+    backward's: the call recorded, then made."""
+    _record_call("fwd", q3, k3, v3, bias3, False, qseg3 is not None,
+                 bias_maps["rate"], tile, offset, causal, sk_real, window)
+    return _fwd(q3, k3, v3, bias3, seed, hq, hk, causal, scale, offset,
+                sk_real, tile, _Static(bias_maps), interpret, qseg3, kseg3,
+                window)
 
 
 # ---------------------------------------------------------------------------
@@ -594,9 +932,11 @@ def _bias_spec(maps, bq, bk, kblk=None, qblk=None):
 # backward
 # ---------------------------------------------------------------------------
 
-def _dq_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, pad_keys,
-               has_bias, has_seg, seg_causal, emit_dbias, rate, window=None,
-               nq_all=None, nk_all=None):
+def _dq_kernel(*refs, scale, causal, offset, bq, bk, sub_q, sub_k, kinds,
+               nk, sk_real, pad_keys, has_bias, has_seg, seg_causal,
+               emit_dbias, rate, window=None, nq_all=None, nk_all=None):
+    """The forward's grid, kinds of tile and walk: for each ``sub_q`` query
+    rows the keys they see, dq summed over them."""
     scale = np.float32(scale)  # strong f64 scalars poison Mosaic under x64
     it = iter(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = (
@@ -623,57 +963,65 @@ def _dq_kernel(*refs, scale, causal, offset, bq, bk, nk, sk_real, pad_keys,
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
     if emit_dbias:
-        # every (qi, ki) block owns exactly one dbias tile; causally-skipped
-        # tiles must still be written (zeros), so zero first and let _body
-        # overwrite
+        # every (qi, ki) block owns exactly one dbias tile; what is skipped,
+        # a whole tile or a part of one, must still be written (zeros), so
+        # zero first and let the pieces that run overwrite
         dbias_ref[0] = jnp.zeros_like(dbias_ref[0])
 
-    def _body():
+    def attend(rows, cols, hide):
+        """dq of the query rows ``rows`` from the keys ``cols`` of the tile,
+        their scores passed through ``hide``."""
         # storage-dtype MXU inputs, f32 accumulation (see _fwd_kernel note)
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]                                        # (bq, 1)
+        q = q_ref[0, rows, :]
+        k = k_ref[0, cols, :]
+        v = v_ref[0, cols, :]
+        do = do_ref[0, rows, :]
+        lse = lse_ref[0, rows, :]                               # (sq, 1)
         lse_safe = jnp.where(lse == _NEG_INF, _F0, lse)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if has_bias:
-            s = s + bias_ref[0].astype(jnp.float32)
-        s = _hide(s, q_start, k_start, pad_keys=pad_keys, causal=causal,
-                  offset=offset, sk_real=sk_real, qseg_ref=qseg_ref,
-                  kseg_ref=kseg_ref, seg_causal=seg_causal, window=window)
-        p = jnp.exp(s - lse_safe)                               # (bq, bk)
+            brows = slice(None) if bias_ref.shape[1] == 1 else rows
+            s = s + bias_ref[0, brows, cols].astype(jnp.float32)
+        s = hide(s)
+        p = jnp.exp(s - lse_safe)                               # (sq, sk)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if rate > 0.0:
-            keep = _keep_block(_mix_seed(seed_ref[0], bh), q_start, k_start,
-                               bq, bk, sk_real, _dropout_thresh(rate))
+            keep = _keep_block(
+                _mix_seed(seed_ref[0], bh), q_start + (rows.start or 0),
+                k_start + (cols.start or 0), *s.shape, sk_real,
+                _dropout_thresh(rate))
             dp = jnp.where(keep, dp * np.float32(1.0 / (1.0 - rate)), _F0)
-        ds = p * (dp - delta_ref[0])                            # (bq, bk)
+        ds = p * (dp - delta_ref[0, rows, :])                   # (sq, sk)
         if emit_dbias:
-            dbias_ref[0] = ds.astype(dbias_ref.dtype)
-        dq_acc[...] += jax.lax.dot(ds.astype(k.dtype), k,
-                                   preferred_element_type=jnp.float32) * scale
+            dbias_ref[0, rows, cols] = ds.astype(dbias_ref.dtype)
+        dq_acc[rows, :] += jax.lax.dot(
+            ds.astype(k.dtype), k, preferred_element_type=jnp.float32) * scale
 
-    _when_visible(_body, q_start, k_start, bq, causal=causal, offset=offset,
-                  bk=bk, window=window, inside=inside)
+    _attend_tile(
+        attend, "k", kinds, q_start, k_start, ki, qseg_ref, kseg_ref, bq=bq,
+        bk=bk, sub_q=sub_q, sub_k=sub_k, nk_all=nk_all or nk,
+        pad_keys=pad_keys, causal=causal, offset=offset, sk_real=sk_real,
+        window=window, inside=inside, seg_causal=seg_causal)
 
     @pl.when(kj == nk - 1)
     def _fin():
         dq_ref[0] = dq_acc[...].astype(dq_ref.dtype)
 
 
-def _dkv_kernel(*refs, scale, causal, offset, bq, bk, nq, rep, sk_real,
-                pad_keys, has_bias, has_seg, seg_causal, rate, window=None,
-                nq_all=None, nk_all=None):
+def _dkv_kernel(*refs, scale, causal, offset, bq, bk, sub_q, sub_k, kinds, nq,
+                nk_grid, rep, sk_real, pad_keys, has_bias, has_seg,
+                seg_causal, rate, window=None, nq_all=None, nk_all=None):
     """Grid (B*Hk, nk, rep, nq): one kv-head block accumulates dk/dv over
     ALL rep q-heads of its group (GQA-native — no rep-expanded K/V in HBM
     and no post-kernel sum over q-head groups). rep == 1 is plain MHA.
     The (r, qi) sweep rides two AFFINE grid dims — the earlier folded
     j = r*nq + qi form put div/mod into every q-side index map, which
     blocks Mosaic's cross-iteration DMA pipelining (suspected cause of
-    the r3 GQA fwd_bwd 0.837; on-chip recapture verifies)."""
+    the r3 GQA fwd_bwd 0.837; on-chip recapture verifies). A tile is
+    walked the other way round from fwd and dq: for each ``sub_k`` keys,
+    the query rows that see them."""
     scale = np.float32(scale)  # strong f64 scalars poison Mosaic under x64
     it = iter(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = (
@@ -702,46 +1050,51 @@ def _dkv_kernel(*refs, scale, causal, offset, bq, bk, nq, rep, sk_real,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def _body():
+    def attend(rows, cols, hide):
+        """dk and dv of the keys ``cols`` from the query rows ``rows`` of
+        the tile, their scores passed through ``hide``."""
         # storage-dtype MXU inputs, f32 accumulation (see _fwd_kernel note)
-        q = q_ref[0]
-        k = k_ref[0]
-        v = v_ref[0]
-        do = do_ref[0]
-        lse = lse_ref[0]                                        # (bq, 1)
+        q = q_ref[0, rows, :]
+        k = k_ref[0, cols, :]
+        v = v_ref[0, cols, :]
+        do = do_ref[0, rows, :]
+        lse = lse_ref[0, rows, :]                               # (sq, 1)
         lse_safe = jnp.where(lse == _NEG_INF, _F0, lse)
         s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
                                 preferred_element_type=jnp.float32) * scale
         if has_bias:
-            s = s + bias_ref[0].astype(jnp.float32)
-        s = _hide(s, q_start, k_start, pad_keys=pad_keys, causal=causal,
-                  offset=offset, sk_real=sk_real, qseg_ref=qseg_ref,
-                  kseg_ref=kseg_ref, seg_causal=seg_causal, window=window)
-        p = jnp.exp(s - lse_safe)                               # (bq, bk)
+            brows = slice(None) if bias_ref.shape[1] == 1 else rows
+            s = s + bias_ref[0, brows, cols].astype(jnp.float32)
+        s = hide(s)
+        p = jnp.exp(s - lse_safe)                               # (sq, sk)
         if rate > 0.0:
-            keep = _keep_block(_mix_seed(seed_ref[0], bh), q_start, k_start,
-                               bq, bk, sk_real, _dropout_thresh(rate))
+            keep = _keep_block(
+                _mix_seed(seed_ref[0], bh), q_start + (rows.start or 0),
+                k_start + (cols.start or 0), *s.shape, sk_real,
+                _dropout_thresh(rate))
             inv = np.float32(1.0 / (1.0 - rate))
             p_v = jnp.where(keep, p * inv, _F0)
         else:
             p_v = p
-        dv_acc[...] += jax.lax.dot_general(
+        dv_acc[cols, :] += jax.lax.dot_general(
             p_v.astype(do.dtype), do, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)                  # (bk, d)
+            preferred_element_type=jnp.float32)                  # (sk, d)
         dp = jax.lax.dot_general(do, v, (((1,), (1,)), ((), ())),
                                  preferred_element_type=jnp.float32)
         if rate > 0.0:
-            dp = jnp.where(keep, dp * np.float32(1.0 / (1.0 - rate)), _F0)
-        ds = p * (dp - delta_ref[0])
+            dp = jnp.where(keep, dp * inv, _F0)
+        ds = p * (dp - delta_ref[0, rows, :])
         # s = scale * (q . k) with q unscaled on load, so dk = scale *
         # ds^T @ q carries the factor explicitly
-        dk_acc[...] += jax.lax.dot_general(
+        dk_acc[cols, :] += jax.lax.dot_general(
             ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32) * scale          # (bk, d)
+            preferred_element_type=jnp.float32) * scale          # (sk, d)
 
-    # block contributes iff some query row sees some key col
-    _when_visible(_body, q_start, k_start, bq, causal=causal, offset=offset,
-                  bk=bk, window=window, inside=inside)
+    _attend_tile(
+        attend, "q", kinds, q_start, k_start, ki, qseg_ref, kseg_ref, bq=bq,
+        bk=bk, sub_q=sub_q, sub_k=sub_k, nk_all=nk_grid, pad_keys=pad_keys,
+        causal=causal, offset=offset, sk_real=sk_real, window=window,
+        inside=inside, seg_causal=seg_causal)
 
     @pl.when(jnp.logical_and(r == np.int32(rep - 1),
                              qj == np.int32(nq - 1)))
@@ -750,8 +1103,9 @@ def _dkv_kernel(*refs, scale, causal, offset, bq, bk, nq, rep, sk_real,
         dv_ref[0] = dv_acc[...].astype(dv_ref.dtype)
 
 
+@functools.partial(jax.jit, static_argnames=_CALL_STATICS)
 def _bwd_dq(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
-            offset, sk_real, bq, bk, bias_maps, interpret, qseg3, kseg3,
+            offset, sk_real, tile, bias_maps, interpret, qseg3, kseg3,
             hq, hk, window=None):
     """dq (and, for a full per-(batch, head) bias, its (bq, bk) dbias
     tiles) on the forward's (bh, qi, ki) grid: q3/do3/lse3/delta3 per
@@ -759,6 +1113,7 @@ def _bwd_dq(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
     forward's index map so GQA never expands K/V in HBM."""
     bhq, sq, d = q3.shape
     sk = kx.shape[1]
+    bq, bk, sub_q, sub_k, kinds = tile
     nq, nk = sq // bq, sk // bk
     kv_map = functools.partial(_kv_index, hq=hq, hk=hk)
     has_bias = bias3 is not None
@@ -809,7 +1164,8 @@ def _bwd_dq(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
 
     dq_outs = pl.pallas_call(
         functools.partial(_dq_kernel, scale=scale, causal=causal,
-                          offset=offset, bq=bq, bk=bk, nk=nk,
+                          offset=offset, bq=bq, bk=bk, sub_q=sub_q,
+                          sub_k=sub_k, kinds=kinds, nk=nk,
                           sk_real=sk_real, pad_keys=sk != sk_real,
                           has_bias=has_bias, has_seg=has_seg,
                           seg_causal=bias_maps.get("seg_causal", False),
@@ -819,17 +1175,19 @@ def _bwd_dq(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
         out_specs=dq_out_specs if emit_dbias else dq_out_specs[0],
         out_shape=dq_out_shape if emit_dbias else dq_out_shape[0],
         scratch_shapes=[pltpu.VMEM((bq, d), jnp.float32)],
-        compiler_params=_call_params(
-            "dq", ("parallel", "parallel", "arbitrary"), q3, kx, vx, bias3,
-            emit_dbias, has_seg, rate, bq, bk, offset, causal, window),
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary"),
+            _call_vmem("dq", q3, kx, vx, bias3, emit_dbias, has_seg, rate,
+                       tile)),
         interpret=interpret,
         name=names["dq"],
     )(*args)
     return dq_outs if emit_dbias else (dq_outs, None)
 
 
+@functools.partial(jax.jit, static_argnames=_CALL_STATICS)
 def _bwd_dkv(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
-             offset, sk_real, bq, bk, bias_maps, interpret, qseg3, kseg3,
+             offset, sk_real, tile, bias_maps, interpret, qseg3, kseg3,
              hq, hk, window=None):
     """dk/dv per KV head on the (kv-head, k-block, r, qi) grid: the
     (q-head-of-group, q-block) sweep as two AFFINE dims, all i32 (index
@@ -838,6 +1196,7 @@ def _bwd_dkv(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
     _, sq, d = q3.shape
     bhk, sk = kx.shape[0], kx.shape[1]
     rep = hq // hk
+    bq, bk, sub_q, sub_k, kinds = tile
     nq, nk = sq // bq, sk // bk
     has_bias = bias3 is not None
     has_seg = qseg3 is not None
@@ -881,7 +1240,9 @@ def _bwd_dkv(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
 
     return pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
-                          offset=offset, bq=bq, bk=bk, nq=nq, rep=rep,
+                          offset=offset, bq=bq, bk=bk, sub_q=sub_q,
+                          sub_k=sub_k, kinds=kinds, nq=nq, nk_grid=nk,
+                          rep=rep,
                           sk_real=sk_real, pad_keys=sk != sk_real,
                           has_bias=has_bias, has_seg=has_seg,
                           seg_causal=bias_maps.get("seg_causal", False),
@@ -898,10 +1259,10 @@ def _bwd_dkv(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
         ],
         scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
                         pltpu.VMEM((bk, d), jnp.float32)],
-        compiler_params=_call_params(
-            "dkv", ("parallel", "parallel", "arbitrary", "arbitrary"), q3,
-            kx, vx, bias3, False, has_seg, rate, bq, bk, offset, causal,
-            window),
+        compiler_params=_compiler_params(
+            ("parallel", "parallel", "arbitrary", "arbitrary"),
+            _call_vmem("dkv", q3, kx, vx, bias3, False, has_seg, rate,
+                       tile)),
         interpret=interpret,
         name=names["dkv"],
     )(*kq_args)
@@ -918,9 +1279,15 @@ def _bwd_impl(q3, kx, vx, do3, lse, delta, bias3, seed, causal, scale,
     hk = hk if hk is not None else bhq
     common = (q3, kx, vx, do3, lse[..., None], delta[..., None], bias3,
               seed, causal, scale, offset, sk_real)
-    rest = (bias_maps, interpret, qseg3, kseg3, hq, hk, window)
-    dq, dbias_blocks = _bwd_dq(*common, *plan.dq, *rest)
-    dk, dv = _bwd_dkv(*common, *plan.dkv, *rest)
+    rest = (_Static(bias_maps), interpret, qseg3, kseg3, hq, hk, window)
+    has_seg, rate = qseg3 is not None, bias_maps["rate"]
+    for kernel, tile, dbias in (
+            ("dq", plan.dq, bias3 is not None and bias_maps["full"]),
+            ("dkv", plan.dkv, False)):
+        _record_call(kernel, q3, kx, vx, bias3, dbias, has_seg, rate, tile,
+                     offset, causal, sk_real, window)
+    dq, dbias_blocks = _bwd_dq(*common, plan.dq, *rest)
+    dk, dv = _bwd_dkv(*common, plan.dkv, *rest)
     return dq, dk, dv, dbias_blocks
 
 
@@ -986,13 +1353,26 @@ def _dbias_broadcast(q3, kx, vx, do3, lse_p, delta, bias3, seed, maps,
 # the tile plan: how the three kernels cut attention into grid steps
 # ---------------------------------------------------------------------------
 
+class Tile(NamedTuple):
+    """What one kernel of a call lowers: the (block_q, block_k) a grid step
+    is handed, the (sub_q, sub_k) it walks that in (each divides its side;
+    the tile's own sides where a tile is computed in one piece), and the
+    ``kinds`` of tile the call's grid meets, a straight-line body each
+    (``_tile_kinds``, ``_attend_tile``)."""
+    bq: int
+    bk: int
+    sub_q: int
+    sub_k: int
+    kinds: tuple
+
+
 class TilePlan(NamedTuple):
-    """(block_q, block_k) of each kernel. They need not agree: fwd and dq
-    hold one (bq, d) accumulator and sweep k, dkv holds two (bk, d)
-    accumulators and sweeps q."""
-    fwd: Tuple[int, int]
-    dq: Tuple[int, int]
-    dkv: Tuple[int, int]
+    """The ``Tile`` of each kernel. They need not agree: fwd and dq hold one
+    (bq, d) accumulator and sweep k, dkv holds two (bk, d) accumulators and
+    sweeps q."""
+    fwd: Tile
+    dq: Tile
+    dkv: Tile
 
 
 # Mosaic's scoped VMEM: 16 MiB unless the call asks for more (a v5e core
@@ -1015,7 +1395,10 @@ def _vmem_bytes(kernel, bq, bk, d, q_bytes, k_bytes, v_bytes, bias_bytes,
                 dbias, segments, dropout):
     """Estimated VMEM of one call of ``kernel`` ("fwd", "dq", "dkv") at a
     (bq, bk) tile: in/out blocks double-buffered by the pipeline, scratch,
-    and the score-sized temporaries of the body. A (bq, 1) column block
+    and the score-sized temporaries of the body counted at the whole tile,
+    which a tile computed in one piece needs (a walked tile's pieces are
+    smaller; the estimate does not follow them, so a call asks for the
+    limit it asked for before tiles were walked). A (bq, 1) column block
     occupies whole 128-lane tiles."""
     dl = _round_up(d, _LANES)
     col = _round_up(bq, 8) * _LANES * 4
@@ -1055,25 +1438,87 @@ def _side_blocks(s, max_block=_MAX_BLOCK):
                  if n % m == 0)
 
 
+def _sub_tile(kernel, bq, bk, d, window=None):
+    """(sub_q, sub_k) that ``kernel`` walks a (bq, bk) tile in, square where
+    the sides allow: on each side the largest multiple of 128 that divides
+    it, up to what the sweep on the chip found fastest (PERF.md, "PR 31").
+    dq takes 128 and dkv 256 whatever the head; the forward 128 under a
+    head of 64, where the vector unit bounds it and the finest skip wins,
+    and 512 over a wider head, where the MXU wants long pieces. Measured at
+    heads of 64 and 128 in bf16, at 1024 x 1024 tiles (512 x 512 too under
+    the window): a head of 256, or of 32, follows its neighbour's rule
+    unmeasured. No piece is longer than the window rounded up to 128, or
+    the band skips nothing (measured at the window 512 alone). A side that
+    no multiple of 128 divides (a short one, taken whole) is one piece; a
+    query side under 8 rows (decode) leaves nothing to skip."""
+    if bq < 8:
+        return bq, bk
+    most = {"fwd": 128 if d <= 64 else 512, "dq": 128, "dkv": 256}[kernel]
+    if window is not None:
+        most = min(most, _round_up(window, _LANES))
+
+    def side(block):
+        return max((m for m in range(_LANES, most + 1, _LANES)
+                    if block % m == 0), default=block)
+    return side(bq), side(bk)
+
+
+def _tile(kernel, bq, bk, sq, sk, d, *, causal, window=None, sub=None):
+    """The ``Tile`` that ``kernel`` lowers for a call of ``sq`` queries on
+    ``sk`` keys cut into (bq, bk) blocks: the one place that decides how a
+    call's tiles are walked, for the kernel's body, the ``flash::tile_plan``
+    event and the tests alike. The operands are padded to whole blocks and
+    the diagonal lies ``sk - sq`` to the right, as every caller has it.
+
+    The sub-tile is ``_sub_tile``'s (``sub`` overrides it: the sweep), and
+    the kinds are those of the tiles an edge or the padding touches, where
+    a walk has something to skip or a mask to save and the kinds are few.
+    Else the tile is computed in one piece: a call that is neither causal
+    nor padded, a decode step, a call with more than ``_MAX_WALKS`` kinds.
+    The sub-tile is then the tile, masked where an edge or the padding
+    touches it (``_ANY``) and not elsewhere."""
+    nq, nk = -(-sq // bq), -(-sk // bk)
+    sub_q, sub_k = sub or _sub_tile(kernel, bq, bk, d, window)
+    kinds = _tile_kinds(bq, bk, nq, nk, causal=causal, offset=sk - sq,
+                        window=window, sk_real=sk)
+    touched = kinds - {(None, None)}
+    if not touched or len(touched) > _MAX_WALKS:
+        sub_q, sub_k = bq, bk
+    if (sub_q, sub_k) == (bq, bk):
+        kinds = {(None, None) if kind == (None, None) else (_ANY, None)
+                 for kind in kinds}
+    return Tile(bq, bk, sub_q, sub_k, tuple(sorted(kinds, key=str)))
+
+
 def tile_plan(sq, sk, d, q_bytes=2, k_bytes=2, v_bytes=2, *, bias_bytes=0,
               dbias=False, segments=False, dropout=False,
-              vmem_budget=_VMEM_BUDGET, window=None) -> TilePlan:
-    """The tiles of the three kernels, from what a call can see at trace
+              vmem_budget=_VMEM_BUDGET, window=None, causal=True) -> TilePlan:
+    """The ``Tile`` of the three kernels, from what a call can see at trace
     time: the lengths, the head dim, the operands' item sizes, whether a
-    bias block (and its dbias tile), segment ids or dropout ride along,
-    and a VMEM budget. Each kernel takes the largest tile whose sides suit
-    the lengths and whose estimate fits the budget (PERF.md, "PR 26": the
-    sweep on the chip). Causal or not does not enter: the order was
-    measured causal, where a large tile wastes most (the half of a
-    diagonal block above the diagonal), and it was the fastest there.
+    bias block (and its dbias tile), segment ids or dropout ride along, a
+    VMEM budget, and whether the kernels hide what lies above the diagonal
+    (``causal``; under segment ids they do not, the diagonals ride in the
+    segment words) or outside a ``window``. Each kernel takes the largest
+    tile whose sides suit the lengths and whose estimate fits the budget
+    (PERF.md, "PR 26": the sweep on the chip; a grid step costs about half
+    a microsecond whatever is in it).
 
-    A ``window`` does enter: a (bq, bk) tile on the band computes
-    ``bq + window`` keys, rounded up to blocks, for each of its queries
-    and needs ``window`` of them, so the sides stop at the window rounded
-    up to 128 (at 1024 x 1024 a 512-wide band computes 4 times the scores
-    it needs, at 512 x 512 twice; PERF.md, "PR 28": the sweep)."""
+    What a large tile would waste it does not compute: a step walks its
+    tile in ``_sub_tile``'s pieces, enters only those that hold a score
+    attention may see, and masks only those an edge crosses (the diagonal,
+    the band's lower edge, the padded keys' column). ``_tile`` says which
+    tiles of the call are walked so; what it returns is what is lowered.
+    Causal or not does not move the tile's sides.
+
+    A ``window`` does: a step is handed whole tiles, so a (bq, bk) tile on
+    the band moves ``bq + window`` keys, rounded up to blocks, for ``bq``
+    queries that need ``window`` of them. The sides stop at twice the
+    window rounded up to 128: walked, 1024 x 1024 beat 512 x 512 under a
+    window of 512, which was the other way round while a tile was computed
+    whole (PERF.md, "PR 28" and "PR 31": the sweeps; no other window was
+    measured, so a window of 8 or 200 follows 512's rule)."""
     side = _MAX_BLOCK if window is None else \
-        min(_MAX_BLOCK, _round_up(window, _LANES))
+        min(_MAX_BLOCK, 2 * _round_up(window, _LANES))
     qs, ks = _side_blocks(sq, side), _side_blocks(sk, side)
 
     def pick(kernel):
@@ -1084,29 +1529,35 @@ def tile_plan(sq, sk, d, q_bytes=2, k_bytes=2, v_bytes=2, *, bias_bytes=0,
         # the largest tile, and of two as large the one wider in keys: a
         # grid step's own cost and the (bq, 128) softmax statistics are paid
         # once a step, so they shrink with the tile, the latter with bk
-        return max(fits, key=lambda t: (t[0] * t[1], t[1]),
-                   default=(min(qs), min(ks)))
+        bq, bk = max(fits, key=lambda t: (t[0] * t[1], t[1]),
+                     default=(min(qs), min(ks)))
+        return _tile(kernel, bq, bk, sq, sk, d, causal=causal, window=window)
     return TilePlan(pick("fwd"), pick("dq"), pick("dkv"))
 
 
-def _blocks(block_q, block_k, q, k, v, bias, segments, rate, window=None):
+def _blocks(block_q, block_k, q, k, v, bias, segments, rate, causal,
+            window=None):
     """TilePlan of a call on q [B,Sq,Hq,D], k/v [B,Sk,Hk,D]: explicit
     ``block_q`` and ``block_k`` (cut to the length) go to all three
-    kernels; both None, the plan chooses."""
+    kernels, each with its own sub-tile; both None, the plan chooses.
+    ``causal`` is the call's; under ``segments`` the kernels see none."""
     B, Sq, Hq, D = q.shape
     Sk = k.shape[1]
+    causal = causal and not segments
     if (block_q is None) != (block_k is None):
         raise ValueError("block_q and block_k are given together or not at "
                          f"all, got {block_q!r} and {block_k!r}")
     if block_q is not None:
-        t = (min(block_q, Sq), min(block_k, Sk))
-        return TilePlan(t, t, t)
+        bq, bk = min(block_q, Sq), min(block_k, Sk)
+        return TilePlan(*(_tile(kernel, bq, bk, Sq, Sk, D, causal=causal,
+                                window=window)
+                          for kernel in ("fwd", "dq", "dkv")))
     return tile_plan(
         Sq, Sk, D, q.dtype.itemsize, k.dtype.itemsize, v.dtype.itemsize,
         bias_bytes=jnp.asarray(bias).dtype.itemsize if bias is not None
         else 0,
         dbias=bias is not None and _bias_shape4(bias) == (B, Hq, Sq, Sk),
-        segments=segments, dropout=rate > 0.0, window=window)
+        segments=segments, dropout=rate > 0.0, window=window, causal=causal)
 
 
 # ---------------------------------------------------------------------------
@@ -1255,8 +1706,9 @@ def _fa_fwd(q, k, v, bias, seed, q_seg, k_seg, causal, scale, dropout_rate,
     B, Sq, Hq, D = q.shape
     Sk, Hk = k.shape[1], k.shape[2]
     _check_window(window, causal, bias, q_seg)
-    bq, bk = _blocks(block_q, block_k, q, k, v, bias, q_seg is not None,
-                     dropout_rate, window).fwd
+    tile = _blocks(block_q, block_k, q, k, v, bias, q_seg is not None,
+                   dropout_rate, causal, window).fwd
+    bq, bk = tile.bq, tile.bk
     offset = Sk - Sq
 
     q3 = _pad_seq(q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, D), bq)
@@ -1284,9 +1736,9 @@ def _fa_fwd(q, k, v, bias, seed, q_seg, k_seg, causal, scale, dropout_rate,
     else:
         seed_in = None
 
-    out3, lse = _fwd(q3, k3, v3, bias3, seed_in, Hq, Hk, causal, scale,
-                     offset, Sk, bq, bk, maps, interpret, qseg3, kseg3,
-                     window)
+    out3, lse = _fwd_impl(q3, k3, v3, bias3, seed_in, Hq, Hk, causal, scale,
+                          offset, Sk, tile, maps, interpret, qseg3, kseg3,
+                          window)
     out = out3[:, :Sq].reshape(B, Hq, Sq, D).transpose(0, 2, 1, 3)
     return out, (q, k, v, bias, seed, q_seg, k_seg, out, lse)
 
@@ -1298,11 +1750,11 @@ def _fa_bwd(causal, scale, dropout_rate, block_q, block_k, interpret, window,
     Sk, Hk = k.shape[1], k.shape[2]
     rep = Hq // Hk
     plan = _blocks(block_q, block_k, q, k, v, bias, q_seg is not None,
-                   dropout_rate, window)
+                   dropout_rate, causal, window)
     # the two kernels share their operands: pad each side to a length both
     # of its blocks divide
-    bq, bk = math.lcm(plan.dq[0], plan.dkv[0]), \
-        math.lcm(plan.dq[1], plan.dkv[1])
+    bq, bk = math.lcm(plan.dq.bq, plan.dkv.bq), \
+        math.lcm(plan.dq.bk, plan.dkv.bk)
     offset = Sk - Sq
 
     q3 = _pad_seq(q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, D), bq)
@@ -1418,12 +1870,13 @@ def flash_chunk_fwd(q, k, v, causal, scale, block_q=None, block_k=None,
     chunk); fully-visible chunks pass causal=False."""
     B, Sq, Hq, D = q.shape
     Sk, Hk = k.shape[1], k.shape[2]
-    bq, bk = _blocks(block_q, block_k, q, k, v, None, False, 0.0).fwd
+    tile = _blocks(block_q, block_k, q, k, v, None, False, 0.0, causal).fwd
+    bq, bk = tile.bq, tile.bk
     q3 = _pad_seq(q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, D), bq)
     k3 = _pad_seq(k.transpose(0, 2, 1, 3).reshape(B * Hk, Sk, D), bk)
     v3 = _pad_seq(v.transpose(0, 2, 1, 3).reshape(B * Hk, Sk, D), bk)
-    out3, lse = _fwd(q3, k3, v3, None, None, Hq, Hk, causal, scale,
-                     Sk - Sq, Sk, bq, bk, {"rate": 0.0}, interpret)
+    out3, lse = _fwd_impl(q3, k3, v3, None, None, Hq, Hk, causal, scale,
+                          Sk - Sq, Sk, tile, {"rate": 0.0}, interpret)
     out = out3[:, :Sq].reshape(B, Hq, Sq, D).transpose(0, 2, 1, 3)
     return out, lse[:, :Sq].reshape(B, Hq, Sq)
 
@@ -1437,9 +1890,9 @@ def flash_chunk_bwd(q, k, v, do, lse, delta, causal, scale, block_q=None,
     the flash-attention backward identity at ring granularity."""
     B, Sq, Hq, D = q.shape
     Sk, Hk = k.shape[1], k.shape[2]
-    plan = _blocks(block_q, block_k, q, k, v, None, False, 0.0)
-    bq, bk = math.lcm(plan.dq[0], plan.dkv[0]), \
-        math.lcm(plan.dq[1], plan.dkv[1])
+    plan = _blocks(block_q, block_k, q, k, v, None, False, 0.0, causal)
+    bq, bk = math.lcm(plan.dq.bq, plan.dkv.bq), \
+        math.lcm(plan.dq.bk, plan.dkv.bk)
     q3 = _pad_seq(q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, D), bq)
     kx = _pad_seq(k.transpose(0, 2, 1, 3).reshape(B * Hk, Sk, D), bk)
     vx = _pad_seq(v.transpose(0, 2, 1, 3).reshape(B * Hk, Sk, D), bk)

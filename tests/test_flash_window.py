@@ -2,10 +2,11 @@
 
 A windowed call is checked against plain masked attention, forward and the
 three gradients, in interpret mode; its grid holds the band's blocks only;
-its kernels carry other names. A call WITHOUT a window must lower exactly as
-it did before the window existed: the tile, grid, names, operand shapes and
+its kernels carry other names. A call WITHOUT a window keeps what the
+benchmark's readers find it by: the tile, grid, names, operand shapes and
 VMEM limit at the two accepted benchmark cells' shapes are pinned here as
-constants read off the parent commit (PR 26), and so is the whole jaxpr.
+constants read off PR 26's commit. The whole jaxpr is pinned too, as of
+PR 31, which changed the kernels' bodies (a tile is walked in sub-tiles).
 """
 import hashlib
 import math
@@ -73,23 +74,56 @@ def test_windowed_flash_matches_masked_plain_attention(s, hq, hk, window,
         np.testing.assert_allclose(a, b, atol=2e-4)
 
 
-def test_the_plans_tile_stops_at_the_window():
-    """512-wide bands take 512 x 512 tiles (the sweep on the chip, PERF.md
-    "PR 28"), not the 1024 x 1024 a call without a window takes."""
-    t = (512, 512)
-    assert fa.tile_plan(8192, 8192, 128, window=512) == fa.TilePlan(t, t, t)
-    big = (1024, 1024)
-    assert fa.tile_plan(8192, 8192, 128) == fa.TilePlan(big, big, big)
-    small = fa.tile_plan(8192, 8192, 128, window=8)
-    assert small == fa.TilePlan((128, 128), (128, 128), (128, 128))
-    assert fa.tile_plan(8192, 8192, 128, window=4096) == \
-        fa.TilePlan(big, big, big)
+# the sub-tile a 512 x 512 tile is walked in: itself (one piece), and pieces
+# that the band's two edges cross in one, both or neither of their sides
+@pytest.mark.parametrize("sub", [(512, 512), (128, 128), (64, 256),
+                                 (256, 128)], ids=lambda t: f"{t[0]}x{t[1]}")
+@pytest.mark.parametrize("s,hq,hk,window", [
+    (1200, 4, 1, 512),      # the cell's window, on a length it does not divide
+    (1000, 2, 2, 200),      # narrower than a sub-tile's side or wider
+    (700, 4, 2, 40)])
+def test_windowed_sub_tiles_match_masked_plain_attention(s, hq, hk, window,
+                                                         sub, monkeypatch):
+    monkeypatch.setattr(
+        fa, "_sub_tile",
+        lambda kernel, bq, bk, *_: (min(sub[0], bq), min(sub[1], bk)))
+    q, k, v, do = _mk(1, s, hq, hk, 32, seed=s + window)
+
+    def both(fn):
+        def run(q, k, v):
+            out, vjp = jax.vjp(fn, q, k, v)
+            return (out,) + vjp(do)
+        return jax.jit(run)(q, k, v)
+    got = both(lambda *a: _flash(*a, window, 512, 512))
+    want = both(lambda *a: _plain(*a, window))
+    np.testing.assert_allclose(got[0], want[0], atol=2e-5)
+    for a, b in zip(got[1:], want[1:]):
+        np.testing.assert_allclose(a, b, atol=2e-4)
+
+
+def test_the_plans_tile_stops_at_twice_the_window():
+    """512-wide bands take 1024 x 1024 tiles walked in pieces no longer than
+    the window (the sweep on the chip, PERF.md "PR 31"; 512 x 512 while a
+    tile was computed whole, "PR 28"); a narrow band takes small tiles."""
+    def tile(fwd, dq, dkv, side=1024):
+        return [(side, side, sub, sub) for sub in (fwd, dq, dkv)]
+
+    def plan(window=None):
+        return [t[:4] for t in fa.tile_plan(8192, 8192, 128, window=window)]
+    assert plan(512) == plan() == plan(4096) == tile(512, 128, 256)
+    assert plan(8) == tile(128, 128, 128, side=256)
+    assert plan(200) == tile(256, 128, 256, side=512)
+    # a 1024 tile under the window 512 meets the diagonal at its own
+    # corner, and the band's lower edge in the tile to its left
+    assert {t.kinds for t in fa.tile_plan(8192, 8192, 128, window=512)} == \
+        {((0, None), (1024, None))}
 
 
 def test_a_windowed_call_sweeps_the_band_only_and_says_so():
     """Names, the grid's extent and the ``flash::tile_plan`` attributes of a
-    windowed call: S 1024, window 128, 128 x 128 tiles: a query block sees
-    two key blocks of eight, a key block is seen by two query blocks."""
+    windowed call: S 1024, window 128, so 256 x 256 tiles walked in 128 x
+    128: a query block sees two key blocks of four, a key block is seen by
+    two query blocks; 128 query rows see two sub-tiles of eight."""
     from paddle_tpu.profiler import tracing
 
     q, k, v, _ = _mk(1, 1024, 2, 1, 32, seed=3)
@@ -109,19 +143,24 @@ def test_a_windowed_call_sweeps_the_band_only_and_says_so():
     assert set(events) == {"flash_win_fwd", "flash_win_bwd_dq",
                            "flash_win_bwd_dkv"}
     for name, a in events.items():
-        assert (a["bq"], a["bk"], a["window"]) == (128, 128, 128)
-        assert fa.TILE_PLAN_TALLY[(name, 128, 128)] == \
-            before.get((name, 128, 128), 0) + 1
-        # 2 q heads x 8 outer blocks x 2 band blocks; the first query block
+        assert (a["bq"], a["bk"], a["window"]) == (256, 256, 128)
+        assert (a["sub_q"], a["sub_k"]) == (128, 128)
+        key = (name, 256, 256, 128, 128)
+        assert fa.TILE_PLAN_TALLY[key] == before.get(key, 0) + 1
+        # 2 q heads x 4 outer blocks x 2 band blocks; the first query block
         # (the last key block) has one block in its band
-        assert a["grid_steps"] == 2 * 8 * 2
+        assert a["grid_steps"] == 2 * 4 * 2
         assert a["skipped_steps"] == 2 * 1
-        assert a["band_skipped_steps"] == 2 * (8 * 8 - 8 * 2)
+        assert a["band_skipped_steps"] == 2 * (4 * 4 - 4 * 2)
+        # of the 8 x 8 sub-tiles 15 hold a visible score, each crossed by
+        # the diagonal or the band's lower edge
+        assert a["sub_tiles"] == a["sub_tiles_masked"] == 2 * 15
+        assert a["sub_tiles_skipped"] == 2 * (8 * 8 - 15)
     calls = _pallas_calls(jax.grad(
         lambda q, k, v: _flash(q, k, v, 128).sum(), (0, 1, 2)), q, k, v)
     assert [(c["name"], c["grid"]) for c in calls] == [
-        ("flash_win_fwd", (2, 8, 2)), ("flash_win_bwd_dq", (2, 8, 2)),
-        ("flash_win_bwd_dkv", (1, 8, 2, 2))]
+        ("flash_win_fwd", (2, 4, 2)), ("flash_win_bwd_dq", (2, 4, 2)),
+        ("flash_win_bwd_dkv", (1, 4, 2, 2))]
 
 
 def test_the_xla_fallback_masks_the_same_window():
@@ -211,7 +250,9 @@ def _row(name, grid, d, q_rows, kv_rows, s, n_in, vmem):
             "operands": operands, "vmem_limit_bytes": vmem}
 
 
-# read off the parent commit (97959e4, PR 26) with this file's _pallas_calls
+# names, grids, blocks, operands and VMEM limits read off PR 26's commit
+# (97959e4) with this file's _pallas_calls; the digests are PR 31's, whose
+# kernels walk a tile in sub-tiles and are jitted on their own
 _PINNED = {
     "gpt2s-train-s1024": (
         (32, 1024, 12, 12, 64, jnp.bfloat16, jnp.bfloat16),
@@ -219,14 +260,14 @@ _PINNED = {
          _row("flash_bwd_dq", (384, 1, 1), 64, 384, 384, 1024, 6, 33030144),
          _row("flash_bwd_dkv", (384, 1, 1, 1), 64, 384, 384, 1024, 6,
               34603008)],
-        "ae04cdc57070476a"),
+        "8005a5d08f1ae177"),
     "mistral7b-l2-train-s4096": (
         (4, 4096, 32, 8, 128, jnp.float32, jnp.bfloat16),
         [_row("flash_fwd", (128, 4, 4), 128, 128, 32, 4096, 3, 28311552),
          _row("flash_bwd_dq", (128, 4, 4), 128, 128, 32, 4096, 6, 36175872),
          _row("flash_bwd_dkv", (32, 4, 4, 4), 128, 128, 32, 4096, 6,
               38535168)],
-        "7690020683b019de"),
+        "930ee281d5c13e81"),
 }
 
 

@@ -534,8 +534,8 @@ def test_dropout_same_mask_under_two_tilings():
             q, k, v, None, seed, None, None, True, scale, rate, block,
             block, True).sum(), (0, 1, 2))(q, k, v)
 
-    assert fa._blocks(None, None, q, k, v, None, False, rate).fwd \
-        != (128, 128)
+    assert fa._blocks(None, None, q, k, v, None, False, rate,
+                      True).fwd[:2] != (128, 128)
     (lp, gp), (l128, g128) = run(None), run(128)
     np.testing.assert_allclose(float(lp), float(l128), rtol=1e-5)
     for a, b_ in zip(gp, g128):
@@ -575,7 +575,18 @@ def test_tile_plan_is_legal(row):
                         dbias=dbias, segments=segs, dropout=drop)
     assert plan == fa.tile_plan(sq, sk, d, qb, qb, vb, bias_bytes=bias_b,
                                 dbias=dbias, segments=segs, dropout=drop)
-    for kernel, (bq, bk) in zip(("fwd", "dq", "dkv"), plan):
+    for kernel, (bq, bk, sub_q, sub_k, kinds) in zip(("fwd", "dq", "dkv"),
+                                                     plan):
+        # a sub-tile divides its tile; its key side is whole lanes, its
+        # query side whole sublanes, unless the side is taken whole
+        assert bq % sub_q == 0 and bk % sub_k == 0
+        assert sub_k == bk or sub_k % 128 == 0
+        assert sub_q == bq or sub_q % 8 == 0
+        if sq < 8:
+            assert (sub_q, sub_k) == (bq, bk)    # decode: one piece
+        # a body for each kind of tile the grid meets: few, and hashable
+        # (the Tile is a static argument of the jitted call)
+        assert 1 <= len(kinds) <= fa._MAX_WALKS + 1 and hash(kinds)
         for s, blk in ((sq, bq), (sk, bk)):
             if s <= 128:
                 assert blk == s          # min(block, S): the whole length
@@ -591,30 +602,52 @@ def test_tile_plan_is_legal(row):
             <= fa._VMEM_BUDGET
     # the backward's two kernels share operands padded once: both tiles
     # divide the padded lengths
-    for s, a, b_ in ((sq, plan.dq[0], plan.dkv[0]),
-                     (sk, plan.dq[1], plan.dkv[1])):
+    for s, a, b_ in ((sq, plan.dq.bq, plan.dkv.bq),
+                     (sk, plan.dq.bk, plan.dkv.bk)):
         if s > 128:
             assert (-(-s // 128) * 128) % math.lcm(a, b_) == 0
 
 
 def test_tile_plan_of_the_benchmark_cells():
-    """The tiles PERF.md ("PR 26", the sweep on the chip) records as the
-    fastest measured at the two cells' attention shapes."""
-    big = (1024, 1024)
-    # gpt2s-train-s1024: s1024, head 64, bf16
-    assert fa.tile_plan(1024, 1024, 64, 2, 2, 2) == fa.TilePlan(big, big, big)
-    # mistral7b-l2-train-s4096: s4096, head 128, q and k float32, v bf16
-    assert fa.tile_plan(4096, 4096, 128, 4, 4, 2) == \
-        fa.TilePlan(big, big, big)
+    """The tiles and sub-tiles PERF.md ("PR 26" and "PR 31", the sweeps on
+    the chip) records as the fastest measured at the cells' attention
+    shapes."""
+    def sides(plan):
+        return [t[:4] for t in plan]
+
+    def want(fwd, dq, dkv):
+        return [(1024, 1024, sub, sub) for sub in (fwd, dq, dkv)]
+    # gpt2s-train-s1024: s1024, head 64, bf16: one tile a head, on the
+    # diagonal
+    gpt2 = fa.tile_plan(1024, 1024, 64, 2, 2, 2)
+    assert sides(gpt2) == want(128, 128, 256)
+    assert {t.kinds for t in gpt2} == {((0, None),)}
+    # mistral7b-l2-train-s4096: s4096, head 128, bf16 (q and k float32
+    # until PR 29); laguna-xs2-train-s8192's full layers: tiles on the
+    # diagonal, walked, and tiles under it, one piece each
+    for plan in (fa.tile_plan(4096, 4096, 128, 2, 2, 2),
+                 fa.tile_plan(4096, 4096, 128, 4, 4, 2),
+                 fa.tile_plan(8192, 8192, 128, 2, 2, 2)):
+        assert sides(plan) == want(512, 128, 256)
+        assert {t.kinds for t in plan} == {((0, None), (None, None))}
+    # what the plan returns is what is lowered: a call that is neither
+    # causal nor padded has nothing to walk, a padded one has
+    plain = fa.tile_plan(4096, 4096, 128, 2, 2, 2, causal=False)
+    assert [t for t in plain] == [(1024, 1024, 1024, 1024, ((None, None),))
+                                  ] * 3
+    padded = fa.tile_plan(1000, 1000, 64, 2, 2, 2, causal=False)
+    assert sides(padded) == want(128, 128, 256)
+    assert {t.kinds for t in padded} == {((None, 1000),)}
 
 
 def test_tile_plan_shrinks_under_a_tight_budget():
     roomy = fa.tile_plan(4096, 4096, 128, 4, 4, 2)
     tight = fa.tile_plan(4096, 4096, 128, 4, 4, 2, vmem_budget=2 << 20)
-    for (bq, bk), (tq, tk) in zip(roomy, tight):
+    for (bq, bk, *_), (tq, tk, *_) in zip(roomy, tight):
         assert tq * tk < bq * bk
-    assert fa.tile_plan(4096, 4096, 128, 4, 4, 2, vmem_budget=1) == \
-        fa.TilePlan((128, 128), (128, 128), (128, 128))
+    least = (128, 128, 128, 128)
+    assert [t[:4] for t in fa.tile_plan(
+        4096, 4096, 128, 4, 4, 2, vmem_budget=1)] == [least] * 3
 
 
 def test_tile_plan_event_and_tally_once_per_lowering():
@@ -624,7 +657,7 @@ def test_tile_plan_event_and_tally_once_per_lowering():
 
     q, k, v = _mk(1, 512, 512, 2, 2, 64, seed=24)
     scale = 1.0 / math.sqrt(64)
-    plan = fa._blocks(None, None, q, k, v, None, False, 0.0)
+    plan = fa._blocks(None, None, q, k, v, None, False, 0.0, True)
     step = jax.jit(jax.grad(lambda q, k, v: flash_attention_pallas(
         q, k, v, True, scale, True).sum(), (0, 1, 2)))
     tracing.reset_tracing()
@@ -645,15 +678,19 @@ def test_tile_plan_event_and_tally_once_per_lowering():
     assert len(again) == len(events) == 3
     for ev in events:
         a = ev["args"]
-        bq, bk = want.pop(a["kernel"])
-        assert (a["bq"], a["bk"]) == (bq, bk)
+        bq, bk, sub_q, sub_k, _ = want.pop(a["kernel"])
+        assert (a["bq"], a["bk"], a["sub_q"], a["sub_k"]) == \
+            (bq, bk, sub_q, sub_k)
+        every = 2 * (512 // sub_q) * (512 // sub_k)
+        assert 0 < a["sub_tiles_masked"] <= a["sub_tiles"] <= every
+        assert a["sub_tiles"] + a["sub_tiles_skipped"] == every
         nq, nk = 512 // bq, 512 // bk
         assert a["grid_steps"] == 2 * nq * nk
         assert a["skipped_steps"] == 2 * sum(
             1 for i in range(nq) for j in range(nk)
             if j * bk > i * bq + bq - 1)
         assert 0 < a["vmem_bytes"] <= fa._VMEM_BUDGET
-        key = (a["kernel"], bq, bk)
+        key = (a["kernel"], bq, bk, sub_q, sub_k)
         assert fa.TILE_PLAN_TALLY[key] == before.get(key, 0) + 1
     assert not want
 
@@ -669,10 +706,11 @@ def test_explicit_blocks_win_over_the_plan():
         return {key for key, n in fa.TILE_PLAN_TALLY.items()
                 if n > before.get(key, 0)}
 
-    assert lowered(256, 128) == {(name, 256, 128) for name in (
-        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}
-    assert lowered(4096, 4096) == {(name, 512, 512) for name in (
-        "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv")}   # min(block, S)
+    def keys(bq, bk):
+        return {(name, bq, bk) + fa._sub_tile(kernel, bq, bk, 64)
+                for kernel, name in fa._KERNEL_NAMES.items()}
+    assert lowered(256, 128) == keys(256, 128)
+    assert lowered(4096, 4096) == keys(512, 512)        # min(block, S)
     with pytest.raises(ValueError, match="together"):
         flash_attention_pallas(q, k, v, True, scale, True, 256, None)
 
@@ -685,7 +723,7 @@ def test_tuned_blocks_cold_returns_the_plan():
         q, k, None, dropout_rate=0.0, has_key=False, causal=True,
         window=None, meshed=False, on_tpu=False,
         force_interpret=True) == ("kernel", "default")
-    plan = fa._blocks(None, None, q, k, v, None, False, 0.0)
+    plan = fa._blocks(None, None, q, k, v, None, False, 0.0, True)
     assert plan == fa.tile_plan(512, 512, 64, 4, 4, 4)
     _flags.set_flags({"pallas_force_interpret": True})
     before = dict(fa.TILE_PLAN_TALLY)
@@ -694,5 +732,241 @@ def test_tuned_blocks_cold_returns_the_plan():
             q, k, v, None, True, 0.125, 0.0, None)).lower(q)
     finally:
         _flags.set_flags({"pallas_force_interpret": False})
-    assert fa.TILE_PLAN_TALLY[("flash_fwd",) + plan.fwd] == \
-        before.get(("flash_fwd",) + plan.fwd, 0) + 1
+    key = ("flash_fwd",) + plan.fwd[:4]
+    assert fa.TILE_PLAN_TALLY[key] == before.get(key, 0) + 1
+
+
+# ---------------------------------------------------------------------------
+# sub-tiles (ISSUE 31): a grid step walks its (bq, bk) tile in (sub_q, sub_k)
+# pieces, enters only those that hold a visible score and masks only those an
+# edge crosses. Parity with plain masked attention, forward and every
+# gradient, whatever the sub-tile (S = 1000 meets a tile with the diagonal, one
+# with the padding, one with both and one with neither); the counts the
+# ``flash::tile_plan`` event carries against a count made by brute force.
+# ---------------------------------------------------------------------------
+
+def _force_sub_tile(monkeypatch, sub):
+    """Every kernel walks in ``sub`` (cut to its tile): the plan's own rule
+    is what the chip's sweep found fastest, the kernels take any sub-tile."""
+    monkeypatch.setattr(
+        fa, "_sub_tile",
+        lambda kernel, bq, bk, *_: (min(sub[0], bq), min(sub[1], bk)))
+
+
+_SUBS = [pytest.param((512, 512), id="one_piece"),
+         pytest.param((128, 128), id="128x128"),
+         pytest.param((64, 256), id="64x256"),
+         pytest.param((256, 128), id="256x128")]
+
+# name: (b, sq, sk, hq, hk, d, causal, extra)
+_WALK_CASES = {
+    "causal": (1, 1024, 1024, 2, 2, 32, True, None),
+    # a ring chunk against an earlier, longer stretch of keys: the diagonal
+    # lies Sk - Sq to the right
+    "causal_offset": (1, 512, 1024, 2, 2, 32, True, None),
+    "gqa_4_1": (1, 1024, 1024, 4, 1, 32, True, None),
+    "s1000_padded_keys": (1, 1000, 1000, 2, 2, 32, True, None),
+    "dropout": (1, 1024, 1024, 2, 2, 32, True, "dropout"),
+    "bias_dbias": (1, 1024, 1024, 2, 2, 32, True, "bias"),
+    "segments": (1, 1000, 1000, 2, 2, 32, True, "segments"),
+    "non_causal": (1, 1024, 1024, 2, 2, 32, False, None),
+    "non_causal_padded": (1, 1000, 1000, 2, 2, 32, False, None),
+}
+
+
+@pytest.mark.parametrize("sub", _SUBS)
+@pytest.mark.parametrize("case", list(_WALK_CASES))
+def test_sub_tiles_match_plain_attention(case, sub, monkeypatch):
+    b, sq, sk, hq, hk, d, causal, extra = _WALK_CASES[case]
+    _force_sub_tile(monkeypatch, sub)
+    q, k, v = _mk(b, sq, sk, hq, hk, d, seed=31)
+    scale = 1.0 / math.sqrt(d)
+    rng = np.random.RandomState(32)
+    ct = jnp.asarray(rng.standard_normal((b, sq, hq, d)), jnp.float32)
+    bias = seg = keep = None
+    rate, seed = 0.0, _SEED0
+    if extra == "bias":
+        bias = jnp.asarray(rng.standard_normal((b, hq, sq, sk)),
+                           jnp.float32) * 0.5
+    elif extra == "segments":
+        seg = jnp.asarray(np.repeat(np.arange(4, dtype=np.int32),
+                                    [300, 212, 420, 68])[None, :])
+    elif extra == "dropout":
+        rate, seed = 0.2, jnp.asarray([77], jnp.int32)
+        keep = dropout_keep_mask(seed, b * hq, sq, sk, rate).reshape(
+            b, hq, sq, sk)
+    diff = (q, k, v) if bias is None else (q, k, v, bias)
+
+    def loss_pl(q, k, v, bias=None):
+        out = flash_attention_ext(q, k, v, bias, seed, seg, seg, causal,
+                                  scale, rate, 512, 512, True)
+        return jnp.sum(out * ct), out
+
+    def loss_ref(q, k, v, bias=None):
+        if seg is not None:
+            bias = _segment_bias(seg, seg)
+        out = _dense_oracle(q, k, v, scale, bias=bias, keep=keep, rate=rate,
+                            causal=causal)
+        return jnp.sum(out * ct), out
+
+    argnums = tuple(range(len(diff)))
+    (_, out), gp = jax.jit(jax.value_and_grad(
+        loss_pl, argnums, has_aux=True))(*diff)
+    (_, ref), gr = jax.jit(jax.value_and_grad(
+        loss_ref, argnums, has_aux=True))(*diff)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=3e-5, atol=3e-5)
+    for a, b_, name in zip(gp, gr, ("q", "k", "v", "bias")):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
+                                   rtol=3e-4, atol=3e-4,
+                                   err_msg=f"{case}: grad of {name}")
+
+
+@pytest.mark.parametrize("bq,bk,sq,sk,walked", [
+    # block sides that differ, on a diagonal 72 to the right: six kinds of
+    # tile an edge or the padding touches, more than a kernel holds walked
+    # bodies for, so every tile is computed in one piece
+    (256, 384, 1024, 1096, False),
+    # four such kinds: the most that is walked
+    (128, 384, 512, 512, True)], ids=["six_kinds", "four_kinds"])
+def test_a_call_of_many_kinds_of_tile_is_not_walked(bq, bk, sq, sk, walked):
+    d = 32
+    plan = fa._blocks(bq, bk, *_mk(1, sq, sk, 2, 2, d, seed=35), None, False,
+                      0.0, True)
+    nq, nk = -(-sq // bq), -(-sk // bk)
+    edges = dict(causal=True, offset=sk - sq, window=None, sk_real=sk)
+    touched = fa._tile_kinds(bq, bk, nq, nk, **edges) - {(None, None)}
+    assert (len(touched) <= fa._MAX_WALKS) == walked
+    for kernel, tile in zip(("fwd", "dq", "dkv"), plan):
+        assert (tile[2:4] != (bq, bk)) == walked
+        if not walked:
+            assert tile.kinds == ((fa._ANY, None), (None, None))
+        # the event's counts follow what is lowered: at the tile's grain
+        # where the tile is one piece
+        assert fa._sub_tile_counts(kernel, *tile[:4], nq, nk, segments=False,
+                                   **edges) == \
+            _brute_force(nq * bq, nk * bk, *tile[2:4], **edges)
+    q, k, v = _mk(1, sq, sk, 2, 2, d, seed=35)
+    scale = 1.0 / math.sqrt(d)
+    ct = jnp.asarray(np.random.RandomState(36).standard_normal(
+        (1, sq, 2, d)), jnp.float32)
+
+    def loss_pl(q, k, v):
+        out = flash_attention_ext(q, k, v, None, _SEED0, None, None, True,
+                                  scale, 0.0, bq, bk, True)
+        return jnp.sum(out * ct), out
+
+    def loss_ref(q, k, v):
+        out = _dense_oracle(q, k, v, scale, causal=True)
+        return jnp.sum(out * ct), out
+    (_, out), gp = jax.jit(jax.value_and_grad(
+        loss_pl, (0, 1, 2), has_aux=True))(q, k, v)
+    (_, ref), gr = jax.jit(jax.value_and_grad(
+        loss_ref, (0, 1, 2), has_aux=True))(q, k, v)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               rtol=3e-5, atol=3e-5)
+    for a, b_, name in zip(gp, gr, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b_), rtol=3e-4,
+                                   atol=3e-4, err_msg=f"grad of {name}")
+
+
+def _brute_force(sq, sk, sub_q, sub_k, *, causal, offset, window, sk_real):
+    """(sub-tiles that hold a visible score, those of them that also hold a
+    hidden one) of the (sq, sk) score matrix cut into (sub_q, sub_k)."""
+    r, c = np.arange(sq)[:, None], np.arange(sk)[None, :]
+    vis = np.broadcast_to(c < sk_real, (sq, sk)).copy()
+    if causal:
+        vis &= c <= r + offset
+    if window is not None:
+        vis &= c > r + offset - window
+    t = vis.reshape(sq // sub_q, sub_q, sk // sub_k, sub_k)
+    some, every = t.any(axis=(1, 3)), t.all(axis=(1, 3))
+    return int(some.sum()), int((some & ~every).sum())
+
+
+# name: (sq, sk padded, real sk, d, window, causal, the tiles or None for the
+# plan's): the three cells' attention, Laguna's window layer, and shapes with
+# an offset, padding and a window no sub-tile divides
+_COUNT_CASES = {
+    "gpt2s_cell": (1024, 1024, 1024, 64, None, True, None),
+    "mistral_cell": (4096, 4096, 4096, 128, None, True, None),
+    "laguna_full_layer": (8192, 8192, 8192, 128, None, True, None),
+    "laguna_window_layer": (8192, 8192, 8192, 128, 512, True, None),
+    "offset_chunk": (512, 1024, 1024, 64, None, True, (512, 512, 128, 256)),
+    "padded_s1000": (1024, 1024, 1000, 64, None, True, (512, 512, 64, 128)),
+    "window_200_padded": (1536, 1536, 1400, 64, 200, True,
+                          (512, 512, 128, 128)),
+    "non_causal_padded": (1024, 1024, 1000, 64, None, False,
+                          (512, 512, 256, 128)),
+}
+
+
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("case", list(_COUNT_CASES))
+def test_sub_tile_counts_equal_brute_force(case, kernel):
+    """The arithmetic that lays out the walks enters exactly the sub-tiles
+    that hold a visible score and masks exactly those an edge crosses."""
+    sq, sk, sk_real, d, window, causal, tile = _COUNT_CASES[case]
+    if tile is None:
+        tile = getattr(fa.tile_plan(sq, sk, d, window=window), kernel)
+        assert tile[2:4] != tile[:2]       # the cells' tiles are walked
+    bq, bk, sub_q, sub_k = tile[:4]
+    edges = dict(causal=causal, offset=sk - sq, window=window,
+                 sk_real=sk_real)
+    got = fa._sub_tile_counts(kernel, bq, bk, sub_q, sub_k, sq // bq,
+                              sk // bk, segments=False, **edges)
+    assert got == _brute_force(sq, sk, sub_q, sub_k, **edges)
+    entered, masked = got
+    every = (sq // sub_q) * (sk // sub_k)
+    assert 0 < masked <= entered <= every
+    if causal:
+        assert entered < every           # something is never entered
+    # under segment ids every entered sub-tile keeps its mask
+    assert fa._sub_tile_counts(kernel, bq, bk, sub_q, sub_k, sq // bq,
+                               sk // bk, segments=True, **edges) \
+        == (entered, entered)
+
+
+def test_tile_plan_event_carries_the_sub_tile_counts(monkeypatch):
+    """``sub_tiles``, ``sub_tiles_skipped`` and ``sub_tiles_masked`` of a
+    lowered call, summed over its (batch, q head) rows, against the brute
+    force; a body that computes its tile in one piece enters the tiles that
+    run and masks those an edge or the padding touches."""
+    from paddle_tpu.profiler import tracing
+
+    b, s, hq, hk, d = 2, 1000, 4, 2, 32
+    q, k, v = _mk(b, s, s, hq, hk, d, seed=33)
+
+    def events(sub, causal=True):
+        _force_sub_tile(monkeypatch, sub)
+        tracing.reset_tracing()
+        tracing.enable_tracing()
+        try:
+            jax.make_jaxpr(jax.grad(lambda q, k, v: flash_attention_ext(
+                q, k, v, None, _SEED0, None, None, causal, 0.2, 0.0, 512,
+                512, True).sum(), (0, 1, 2)))(q, k, v)
+            return {e["args"]["kernel"]: e["args"]
+                    for e in tracing.snapshot_events()
+                    if e["name"] == "flash::tile_plan"}
+        finally:
+            tracing.disable_tracing()
+            tracing.reset_tracing()
+
+    edges = dict(causal=True, offset=0, window=None, sk_real=s)
+    for sub in ((128, 256), (512, 512)):
+        entered, masked = _brute_force(1024, 1024, *sub, **edges)
+        got = events(sub)
+        assert set(got) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+        for a in got.values():
+            assert (a["sub_q"], a["sub_k"]) == sub
+            assert a["sub_tiles"] == b * hq * entered
+            assert a["sub_tiles_masked"] == b * hq * masked
+            assert a["sub_tiles"] + a["sub_tiles_skipped"] == \
+                b * hq * (1024 // sub[0]) * (1024 // sub[1])
+    # neither causal nor padded: nothing to skip, no mask to save, so the
+    # tile is computed in one piece whatever the plan's sub-tile
+    q, k, v = _mk(b, 1024, 1024, hq, hk, d, seed=34)
+    for a in events((128, 128), causal=False).values():
+        assert (a["sub_q"], a["sub_k"]) == (a["bq"], a["bk"]) == (512, 512)
+        assert (a["sub_tiles"], a["sub_tiles_masked"],
+                a["sub_tiles_skipped"]) == (b * hq * 4, 0, 0)
