@@ -15,6 +15,12 @@ means, any failure making the exit code non-zero:
              grouped matmuls are in the lowered step, three steps on one
              batch give a finite falling loss, and routing_stats of that
              batch is printed
+  glm        GLM-4.7-Flash as glm47-flash-train-s8192 runs it (5 layers and
+             the MTP module, latent attention at a head of 256, 8 of 64
+             bias-corrected experts held, batch 2 x 8192): the flash
+             kernels and the grouped matmuls are in the lowered step, the
+             route and tile plan of its attention are printed, three
+             steps give a finite falling loss
   train      GPT-2 small, seq 1024, batch 8, bf16 params, through
              create_train_step(donate=True) driven by run_steps: loss
              finite and falling, no compile after the first step, the
@@ -115,6 +121,7 @@ CHIP = {
     },
     "laguna": {"tiny": False, "batch": 2, "seq": 8192, "steps": 3,
                "lr": 3e-4},
+    "glm": {"tiny": False, "batch": 2, "seq": 8192, "steps": 3, "lr": 3e-4},
     "train": {"model": "gpt2_small", "batch": 8, "seq": 1024, "steps": 8,
               "lr": 3e-4},
     "serve": {"model": "gpt2_small", "max_slots": 4, "page_len": 128,
@@ -141,6 +148,7 @@ TINY = {
         "ce": [(16, 512), (16, 384)],
     },
     "laguna": {"tiny": True, "batch": 2, "seq": 32, "steps": 3, "lr": 1e-2},
+    "glm": {"tiny": True, "batch": 2, "seq": 32, "steps": 3, "lr": 1e-2},
     "train": {"model": "gpt2_tiny", "batch": 2, "seq": 128, "steps": 4,
               "lr": 1e-2},
     "serve": {"model": "gpt2_tiny", "max_slots": 4, "page_len": 16,
@@ -355,16 +363,63 @@ def leg_kernels(p) -> dict:
 
 # -- leg: laguna ------------------------------------------------------------
 
-def leg_laguna(p) -> dict:
-    """The decoder of laguna-xs2-train-s8192 (BENCHMARK.json) through the
-    trainer: published widths, 5 layers of the pattern, experts 0-31 of 256
-    and an eighth of the vocabulary here, the layer body recomputed."""
+def _moe_leg(name, model, cfg, p, kernels) -> dict:
+    """Three steps of a decoder with DroplessMoE layers through the trainer
+    on one repeated batch: ``routing_stats`` of the batch, the ``kernels``
+    named in the lowered step (on the chip), no compile after the first
+    step, a finite falling loss."""
     import jax
     import jax.numpy as jnp
 
     import paddle_tpu as paddle
+    from paddle_tpu.models import create_train_step, run_steps
+
+    model.train()
+    opt = paddle.optimizer.AdamW(learning_rate=p["lr"], weight_decay=0.01,
+                                 parameters=model.parameters())
+    rng = np.random.RandomState(SEED)
+    ids = jnp.asarray(rng.randint(0, cfg.vocab_size,
+                                  (p["batch"], p["seq"] + 1)), jnp.int32)
+    x, y = ids[:, :-1], ids[:, 1:]
+    # off the step's path, and before the step consumes the weights
+    routing = model.routing_stats(x)
+    if len(routing) != len(model.sparse_layers()) or any(
+            r["assignments_here"] < 1 for r in routing):
+        raise AssertionError(f"{name}: routing_stats {routing}")
+    step, params, opt_state = create_train_step(model, opt,
+                                                donate="consume")
+    key = jax.random.key(SEED)
+    text = step.lower(params, opt_state, key, x, y, p["lr"]).as_text()
+    names = {n: text.count(n) for n in kernels}
+    if on_chip() and not all(names.values()):
+        raise AssertionError(f"{name}: kernels missing from the lowered "
+                             f"step: {names}")
+    params, opt_state, first = run_steps(
+        step, params, opt_state, [(x, y)], key=key, lr=p["lr"])
+    with compile_watch() as watch:
+        params, opt_state, rest = run_steps(
+            step, params, opt_state, [(x, y)] * (p["steps"] - 1), key=key,
+            lr=p["lr"], start_step=1)
+    losses = [float(v) for v in first + rest]
+    if watch["compiles"]:
+        raise AssertionError(f"{name}: {watch['compiles']} compile(s) "
+                             "after the first step")
+    if not (np.isfinite(losses).all() and losses[-1] < losses[0]
+            and max(losses[1:]) < losses[0]):
+        raise AssertionError(f"{name}: loss not finite and falling on one "
+                             f"repeated batch: {losses}")
+    return {"losses": [round(v, 4) for v in losses],
+            "kernels_in_step": names, "routing_stats": routing,
+            "mosaic_calls_in_step": text.count("tpu_custom_call")}
+
+
+def leg_laguna(p) -> dict:
+    """The decoder of laguna-xs2-train-s8192 (BENCHMARK.json) through the
+    trainer: published widths, 5 layers of the pattern, experts 0-31 of 256
+    and an eighth of the vocabulary here, the layer body recomputed."""
+    import paddle_tpu as paddle
     from paddle_tpu.models import (LagunaConfig, LagunaForCausalLM,
-                                   create_train_step, laguna_tiny, run_steps)
+                                   laguna_tiny)
 
     paddle.seed(SEED)
     if p["tiny"]:
@@ -375,47 +430,54 @@ def leg_laguna(p) -> dict:
             mlp_layer_types=LagunaConfig.mlp_layer_types[:5],
             num_heads_per_layer=LagunaConfig.num_heads_per_layer[:5],
             experts_held=(0, 32), use_recompute=True)
-    model = LagunaForCausalLM(cfg).bfloat16()
-    model.train()
-    opt = paddle.optimizer.AdamW(learning_rate=p["lr"], weight_decay=0.01,
-                                 parameters=model.parameters())
-    rng = np.random.RandomState(SEED)
-    ids = jnp.asarray(rng.randint(0, cfg.vocab_size,
-                                  (p["batch"], p["seq"] + 1)), jnp.int32)
-    x, y = ids[:, :-1], ids[:, 1:]
-    # off the step's path, and before the step consumes the weights
-    routing = model.routing_stats(x)
-    sparse = sum(m == "sparse" for m in cfg.mlp_layer_types)
-    if len(routing) != sparse or any(
-            r["assignments_here"] < 1 for r in routing):
-        raise AssertionError(f"laguna: routing_stats {routing}")
-    step, params, opt_state = create_train_step(model, opt,
-                                                donate="consume")
-    key = jax.random.key(SEED)
-    text = step.lower(params, opt_state, key, x, y, p["lr"]).as_text()
-    names = {n: text.count(n) for n in
-             ("flash_win_fwd", "flash_win_bwd_dq", "flash_win_bwd_dkv",
-              "moe_gmm_fwd", "moe_gmm_bwd_x", "moe_gmm_bwd_w")}
-    if on_chip() and not all(names.values()):
-        raise AssertionError(f"laguna: kernels missing from the lowered "
-                             f"step: {names}")
-    params, opt_state, first = run_steps(
-        step, params, opt_state, [(x, y)], key=key, lr=p["lr"])
-    with compile_watch() as watch:
-        params, opt_state, rest = run_steps(
-            step, params, opt_state, [(x, y)] * (p["steps"] - 1), key=key,
-            lr=p["lr"], start_step=1)
-    losses = [float(v) for v in first + rest]
-    if watch["compiles"]:
-        raise AssertionError(f"laguna: {watch['compiles']} compile(s) "
-                             "after the first step")
-    if not (np.isfinite(losses).all() and losses[-1] < losses[0]
-            and max(losses[1:]) < losses[0]):
-        raise AssertionError(f"laguna: loss not finite and falling on one "
-                             f"repeated batch: {losses}")
-    return {"losses": [round(v, 4) for v in losses],
-            "kernels_in_step": names, "routing_stats": routing,
-            "mosaic_calls_in_step": text.count("tpu_custom_call")}
+    return _moe_leg("laguna", LagunaForCausalLM(cfg).bfloat16(), cfg, p,
+                    ("flash_win_fwd", "flash_win_bwd_dq",
+                     "flash_win_bwd_dkv", "moe_gmm_fwd", "moe_gmm_bwd_x",
+                     "moe_gmm_bwd_w"))
+
+
+def leg_glm(p) -> dict:
+    """The decoder of glm47-flash-train-s8192 (BENCHMARK.json) through the
+    trainer: published widths, the dense layer, 4 sparse ones and the MTP
+    module, experts 0-7 of 64 and an eighth of the vocabulary here, the
+    layer body recomputed; the ``mla::plan`` of its attention is printed
+    (route, rule, tiles)."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models import (GlmMoeLiteConfig, GlmMoeLiteForCausalLM,
+                                   glm_moe_lite_tiny)
+    from paddle_tpu.models.glm_moe_lite import MLA_PLAN_TALLY
+    from paddle_tpu.profiler import tracing
+
+    paddle.seed(SEED)
+    if p["tiny"]:
+        cfg = glm_moe_lite_tiny(experts_held=(4, 4), use_recompute=True)
+    else:
+        cfg = GlmMoeLiteConfig(vocab_size=19360, num_hidden_layers=5,
+                               experts_held=(0, 8), use_recompute=True)
+    model = GlmMoeLiteForCausalLM(cfg).bfloat16()
+    before = sum(MLA_PLAN_TALLY.values())
+    tracing.reset_tracing()
+    # a whole step's events: a smaller ring left by an earlier caller
+    # would keep only the last few
+    tracing.enable_tracing(ring_size=tracing.DEFAULT_RING_SIZE)
+    try:
+        out = _moe_leg("glm", model, cfg, p,
+                       ("flash_fwd", "flash_bwd_dq", "flash_bwd_dkv",
+                        "moe_gmm_fwd", "moe_gmm_bwd_x", "moe_gmm_bwd_w"))
+        plans = [e["args"] for e in tracing.snapshot_events()
+                 if e["name"] == "mla::plan"]
+    finally:
+        tracing.disable_tracing()
+        tracing.reset_tracing()
+    layers = cfg.num_hidden_layers + cfg.num_nextn_predict_layers
+    lowered = sum(MLA_PLAN_TALLY.values()) - before
+    if not plans or lowered % layers or (
+            on_chip() and plans[-1]["route"] != "kernel"):
+        raise AssertionError(f"glm: {lowered} attention layers planned for "
+                             f"steps of {layers}, last plan "
+                             f"{plans[-1] if plans else None}")
+    out["mla_plan"] = {k: plans[-1][k] for k in ("route", "rule", "tiles")}
+    return out
 
 
 # -- leg: train -------------------------------------------------------------
@@ -699,7 +761,8 @@ def leg_four_chip(p) -> dict:
 
 # -- driver -----------------------------------------------------------------
 
-LEGS = {"kernels": leg_kernels, "laguna": leg_laguna, "train": leg_train,
+LEGS = {"kernels": leg_kernels, "laguna": leg_laguna, "glm": leg_glm,
+        "train": leg_train,
         "serve": leg_serve, "four_chip": leg_four_chip}
 
 

@@ -130,6 +130,13 @@ class DroplessMoE(Layer):
         routed_scale: factor on the routed sum.
         row_tile: rows of a grouped product's tile; each held expert's rows
             are padded to a multiple of it.
+        score_bias: the layer holds ``e_score_correction_bias``
+            [num_experts], a buffer and no parameter (zeros until a
+            checkpoint or the caller fills it; no gradient, no optimizer
+            state): the k experts are chosen by ``score + bias`` and
+            weighted by their scores alone ("noaux_tc"). The rule that
+            moves the bias from the routing loads during training is not
+            here.
 
     The router's scores are a sigmoid of ``router_input @ router_weight`` in
     float32; the k largest are chosen and their scores normalised to sum to
@@ -144,7 +151,8 @@ class DroplessMoE(Layer):
     def __init__(self, d_model: int, d_expert: int, num_experts: int,
                  top_k: int, held: Optional[Tuple[int, int]] = None,
                  shared: Optional[Layer] = None, routed_scale: float = 1.0,
-                 init_std: float = 0.02, row_tile: int = ROW_TILE):
+                 init_std: float = 0.02, row_tile: int = ROW_TILE,
+                 score_bias: bool = False):
         super().__init__()
         first, count = held if held is not None else (0, num_experts)
         if not (0 <= first and count >= 1 and first + count <= num_experts):
@@ -167,15 +175,29 @@ class DroplessMoE(Layer):
         self.down_proj = self.create_parameter(
             [count, d_expert, d_model], default_initializer=init)
         self.shared = shared
+        if score_bias:
+            from ...core.tensor import Tensor
+            self.register_buffer("e_score_correction_bias", Tensor(
+                jnp.zeros((num_experts,), jnp.float32)))
+        else:
+            self.e_score_correction_bias = None
         self.expert_load = self.expert_choice = None
 
-    def route(self, x32, router_weight):
+    def route(self, x32, router_weight, bias=None):
         """(chosen experts [T, k] int32, their weights [T, k] float32, the
-        routed scale not yet applied) of tokens x32 [T, d]."""
+        routed scale not yet applied) of tokens x32 [T, d]. With ``bias``
+        [num_experts] the choice is by ``score + bias``; the weights are the
+        chosen experts' scores over their sum, the bias taking no part."""
         logits = jnp.dot(x32.astype(jnp.float32),
                          router_weight.astype(jnp.float32),
                          precision=_HIGHEST)
-        top_s, top_i = jax.lax.top_k(jax.nn.sigmoid(logits), self.top_k)
+        scores = jax.nn.sigmoid(logits)
+        if bias is None:
+            top_s, top_i = jax.lax.top_k(scores, self.top_k)
+        else:
+            _, top_i = jax.lax.top_k(scores + bias.astype(jnp.float32),
+                                     self.top_k)
+            top_s = jnp.take_along_axis(scores, top_i, axis=-1)
         return top_i.astype(jnp.int32), \
             top_s / jnp.sum(top_s, axis=-1, keepdims=True)
 
@@ -189,6 +211,7 @@ class DroplessMoE(Layer):
             first=self.first, top_k=k, tokens=tokens,
             expected_rows=tokens * k * self.held // self.num_experts,
             buffer_rows=padded_rows(tokens * k, self.held, tm), row_tile=tm,
+            score_bias=self.e_score_correction_bias is not None,
             gate_up_tile="%dx%d" % gmm_tiles(d, 2 * f),
             down_tile="%dx%d" % gmm_tiles(f, d))
 
@@ -201,9 +224,9 @@ class DroplessMoE(Layer):
         first, held = self.first, self.held
         self._plan(t)
 
-        def fn(xt, x32, wr, wg, wu, wd):
+        def fn(xt, x32, wr, wg, wu, wd, *bias):
             tok = xt.reshape(t, self.d_model)
-            top_i, w = self.route(x32.reshape(t, self.d_model), wr)
+            top_i, w = self.route(x32.reshape(t, self.d_model), wr, *bias)
             lay = group_layout(top_i.reshape(-1), first, held, tm)
             rows = lay.row_src.shape[0]
             row_tok = jnp.where(lay.row_src < t * k, lay.row_src // k, 0)
@@ -219,11 +242,13 @@ class DroplessMoE(Layer):
             return (routed * self.routed_scale).reshape(shape), lay.sizes, \
                 top_i
 
+        bias = self.e_score_correction_bias
         routed, load, choice = run_op(
             "moe_dropless", fn,
             (x, x if router_input is None else router_input,
              self.router_weight, self.gate_proj, self.up_proj,
-             self.down_proj), num_nondiff_outputs=2)
+             self.down_proj) + (() if bias is None else (bias,)),
+            num_nondiff_outputs=2)
         self.expert_load, self.expert_choice = load, choice
         if self.shared is None:
             return routed.astype(x.dtype)

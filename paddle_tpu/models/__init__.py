@@ -6,6 +6,8 @@ from .llama import (LlamaConfig, LlamaForCausalLM, llama_7b, llama_13b,  # noqa:
                     llama_pipeline_model)
 from .laguna import (LagunaConfig, LagunaForCausalLM,  # noqa: F401
                      laguna_rope_tables, laguna_tiny)
+from .glm_moe_lite import (GlmMoeLiteConfig, GlmMoeLiteForCausalLM,  # noqa: F401
+                           MLAttention, glm_moe_lite_tiny)
 from .decode import (ContiguousKV, decode_attention,  # noqa: F401
                      init_contiguous_cache)
 from .trainer import (create_multistep_train_step,  # noqa: F401

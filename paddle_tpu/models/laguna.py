@@ -330,32 +330,16 @@ class LagunaModel(nn.Layer):
         return self.norm(h)
 
 
-class LagunaForCausalLM(nn.Layer):
-    """Trains through ``create_train_step`` / ``run_steps`` as the other
-    families do (``loss(ids, labels)``: token-mean cross entropy, no balance
-    term). ``routing_stats`` reads the expert layers' loads off the step's
-    path."""
-
-    def __init__(self, cfg: LagunaConfig):
-        super().__init__()
-        self.cfg = cfg
-        self.model = LagunaModel(cfg)
-        self.lm_head = _linear(cfg.hidden_size, cfg.vocab_size)
-        self._routing_jit = None
-
-    def forward(self, input_ids):
-        return self.lm_head(self.model(input_ids))
-
-    def loss(self, input_ids, labels):
-        if self.cfg.lm_ce == "blockwise":
-            return blockwise_lm_loss(self.model(input_ids),
-                                     self.lm_head.weight, labels,
-                                     transpose_w=True)
-        return causal_lm_loss(self(input_ids), labels)
+class RoutingStats:
+    """``routing_stats`` and ``chosen_experts`` of a model with
+    ``DroplessMoE`` layers, from one forward pass in a jitted function of its
+    own, off the training step's path. The model says how to run that pass
+    (``_route_pass(ids)``: a forward, nothing recomputed) and which layers
+    to read (``sparse_layers()``: (index, DroplessMoE) pairs), and holds
+    ``_routing_jit = None``."""
 
     def _routing(self, input_ids, params):
-        """(loads, choices) by sparse layer's index, from one forward pass in
-        a jitted function of its own, off the training step's path."""
+        """(loads, choices) by sparse layer's index."""
         from ..core.autograd import tape_paused
         from ..core.tensor import Tensor
         from ..nn.layer.layers import _swapped_state, functional_state
@@ -363,9 +347,8 @@ class LagunaForCausalLM(nn.Layer):
         if self._routing_jit is None:
             def routing(arrays, ids):
                 with _swapped_state(self, arrays), tape_paused():
-                    self.model(Tensor(ids), recompute_layers=False)
-                    sparse = [(i, layer.mlp) for i, layer in
-                              enumerate(self.model.layers) if layer.sparse]
+                    self._route_pass(Tensor(ids))
+                    sparse = self.sparse_layers()
                     return ({i: m.expert_load._data for i, m in sparse},
                             {i: m.expert_choice._data.reshape(
                                 ids.shape + (-1,)) for i, m in sparse})
@@ -390,3 +373,34 @@ class LagunaForCausalLM(nn.Layer):
     def chosen_experts(self, input_ids, params=None) -> dict:
         """layer index -> the experts each token chose, [B, S, k] int32."""
         return self._routing(input_ids, params)[1]
+
+
+class LagunaForCausalLM(RoutingStats, nn.Layer):
+    """Trains through ``create_train_step`` / ``run_steps`` as the other
+    families do (``loss(ids, labels)``: token-mean cross entropy, no balance
+    term). ``routing_stats`` reads the expert layers' loads off the step's
+    path."""
+
+    def __init__(self, cfg: LagunaConfig):
+        super().__init__()
+        self.cfg = cfg
+        self.model = LagunaModel(cfg)
+        self.lm_head = _linear(cfg.hidden_size, cfg.vocab_size)
+        self._routing_jit = None
+
+    def forward(self, input_ids):
+        return self.lm_head(self.model(input_ids))
+
+    def loss(self, input_ids, labels):
+        if self.cfg.lm_ce == "blockwise":
+            return blockwise_lm_loss(self.model(input_ids),
+                                     self.lm_head.weight, labels,
+                                     transpose_w=True)
+        return causal_lm_loss(self(input_ids), labels)
+
+    def _route_pass(self, ids):
+        self.model(ids, recompute_layers=False)
+
+    def sparse_layers(self):
+        return [(i, layer.mlp) for i, layer in enumerate(self.model.layers)
+                if layer.sparse]

@@ -1618,6 +1618,25 @@ def attention_route(q, k, bias, *, dropout_rate, has_key, causal, window,
     return Route("kernel", "default")
 
 
+def route_here(q, k, bias=None, *, dropout_rate=0.0, has_key=False,
+               causal=True, window=None):
+    """``attention_route`` of a call made here and now: the backend, the
+    ``pallas_force_interpret`` flag and the mesh axes GSPMD still owns at
+    this point of the trace (inside a shard_map the manual axes are
+    per-shard already) are read, the rest is the call's. Returns the
+    ``Route`` and those axes, {name: size}. What the Pallas implementation
+    of a dense call asks, and what a model asks that reports the route its
+    attention will get (``mla::plan``)."""
+    mesh = jax.sharding.get_abstract_mesh()
+    auto = {a: mesh.shape[a] for a in mesh.auto_axes}
+    return attention_route(
+        q, k, bias, dropout_rate=dropout_rate, has_key=has_key,
+        causal=causal, window=window,
+        meshed=any(n > 1 for n in auto.values()),
+        on_tpu=not pallas_interpret(),
+        force_interpret=bool(_flags.get_flag("pallas_force_interpret"))), auto
+
+
 def _pad_seq(x3, block):
     s = x3.shape[1]
     pad = (-s) % block
@@ -1928,17 +1947,11 @@ def _attention_pallas(q, k, v, bias, causal, scale, dropout_p, dropout_key,
     impl."""
     from ...nn.functional.flash_attention import _attention_xla
     interpret = pallas_interpret()
-    # mesh axes GSPMD still owns at this point of the trace (inside a
-    # shard_map the manual axes are per-shard already)
-    mesh = jax.sharding.get_abstract_mesh()
-    auto = {a: mesh.shape[a] for a in mesh.auto_axes}
-    meshed = any(n > 1 for n in auto.values())
     rate = float(dropout_p or 0.0)
-    route = attention_route(
-        q, k, bias, dropout_rate=rate, has_key=dropout_key is not None,
-        causal=bool(causal), window=window, meshed=meshed,
-        on_tpu=not interpret,
-        force_interpret=bool(_flags.get_flag("pallas_force_interpret")))
+    route, auto = route_here(q, k, bias, dropout_rate=rate,
+                             has_key=dropout_key is not None,
+                             causal=bool(causal), window=window)
+    meshed = any(n > 1 for n in auto.values())
     if route.impl == "xla":
         return _attention_xla(q, k, v, bias, causal, scale, dropout_p,
                               dropout_key, window)

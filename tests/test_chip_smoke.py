@@ -69,6 +69,16 @@ def test_leg_laguna_tiny(interpret_kernels):
     assert any(k[:3] == (4, 16, 4) for k in MOE_PLAN_TALLY)
 
 
+def test_leg_glm_tiny(interpret_kernels):
+    out = chip_smoke.leg_glm(chip_smoke.TINY["glm"])
+    assert out["losses"][-1] < out["losses"][0]
+    # two sparse layers and the MTP module's
+    assert [r["layer"] for r in out["routing_stats"]] == [1, 2, 3]
+    assert out["mla_plan"]["route"] == "kernel"
+    assert out["mla_plan"]["rule"] == "default"
+    assert out["mla_plan"]["tiles"].startswith("fwd=32x32/")
+
+
 def test_leg_serve_tiny():
     out = chip_smoke.leg_serve(chip_smoke.TINY["serve"])
     assert out["requests"] == 5 and not out["pools_donated"]

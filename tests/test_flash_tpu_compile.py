@@ -75,6 +75,10 @@ _CASES = {
     # the tile
     "d256_f32": (1, 4096, 4, 2, 256, "float32", "float32", False, False,
                  0.0),
+    # glm47-flash-train-s8192: latent attention expanded to 20 heads over 20
+    # of 192 + 64 = 256, values 256 too
+    "glm_cell_d256": (2, 8192, 20, 20, 256, "bfloat16", "bfloat16", False,
+                      False, 0.0),
 }
 
 

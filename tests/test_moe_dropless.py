@@ -12,6 +12,7 @@ import pytest
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
+from benchmarks.reference import glm4_moe_lite as ref_glm  # noqa: E402
 from benchmarks.reference import laguna as ref  # noqa: E402
 from benchmarks.reference import numerics  # noqa: E402
 from paddle_tpu.core.autograd import tape_paused  # noqa: E402
@@ -22,6 +23,11 @@ from paddle_tpu.ops.pallas import grouped_matmul as gm  # noqa: E402
 
 D, F, OF, K = 48, 24, 16, 4          # token width, expert width, experts, top-k
 V = {"num_experts_per_tok": K, "moe_routed_scaling_factor": 2.5}
+# the same layer as the GLM MoE lite family configures it: the choice by
+# score + bias (reference/glm4_moe_lite.py makes the bias of "layer 1")
+V_GLM = {"num_experts_per_tok": K, "routed_scaling_factor": 2.5,
+         "n_routed_experts": OF, "dtype": "float32",
+         "score_bias": {"scale": 0.1, "seed": 5}}
 
 
 # -- the grouped matmul -------------------------------------------------------
@@ -121,12 +127,16 @@ def _weights(seed, held=OF):
             "shared.down.weight": n(ks[6], F, D)}
 
 
-def _layer(lp, first, held, shared=True):
-    """A DroplessMoE holding experts first .. first + held - 1 of ``lp``."""
+def _layer(lp, first, held, shared=True, bias=None):
+    """A DroplessMoE holding experts first .. first + held - 1 of ``lp``;
+    with ``bias`` [OF] it selects by score + bias."""
     sh = LlamaMLP(LlamaConfig(hidden_size=D, intermediate_size=F)) \
         if shared else None
     layer = DroplessMoE(D, F, OF, K, held=(first, held), shared=sh,
-                        routed_scale=2.5, row_tile=8)
+                        routed_scale=2.5, row_tile=8,
+                        score_bias=bias is not None)
+    if bias is not None:
+        layer.e_score_correction_bias._data = jnp.asarray(bias)
     layer.router_weight._data = lp["router.weight"]
     cut = slice(first, first + held)
     layer.gate_proj._data = lp["experts.gate"][cut]
@@ -170,20 +180,25 @@ def test_layer_matches_the_reference_on_a_share():
     np.testing.assert_allclose(dx, dx_ref, atol=1e-4)
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
-    """What the four shares of four experts give, the shared expert counted
-    once, is what the uncut reference (held = of) gives for the whole layer:
+@pytest.mark.parametrize("family,width", [("laguna", 4), ("glm", 2)])
+def test_the_shares_add_up_to_the_uncut_layer(family, width):
+    """What the shares give (four of four experts; for the GLM family eight
+    of two, each selecting by score + bias), the shared expert counted once,
+    is what the uncut reference (held = of) gives for the whole layer:
     forward and the gradient of the input."""
     lp = _weights(11)
     x = jax.random.normal(jax.random.key(12), (2, 32, D))
     do = jax.random.normal(jax.random.key(13), (2, 32, D))
-    shares = [_layer(lp, first, 4, shared=(first == 0))
-              for first in range(0, OF, 4)]
+    bias = ref_glm.score_bias(V_GLM, 1) if family == "glm" else None
+    shares = [_layer(lp, first, width, shared=(first == 0), bias=bias)
+              for first in range(0, OF, width)]
 
     def cut(x):
         return sum(_apply(layer, x) for layer in shares)
 
     def uncut(x):
+        if family == "glm":
+            return ref_glm._experts(x, lp, V_GLM, numerics.Exact(), 1)
         return ref._experts(x, lp, V | {"num_experts": OF}, numerics.Exact())
 
     def cut_with_loads(x):
@@ -197,6 +212,69 @@ def test_the_shares_add_up_to_the_uncut_layer():
     # every assignment landed on exactly one share
     _, loads = jax.jit(cut_with_loads)(x)
     assert sum(int(v.sum()) for v in loads) == 2 * 32 * K
+
+
+def test_the_bias_chooses_and_the_scores_weigh():
+    """A bias that flips a choice changes WHO is chosen and leaves every
+    chosen expert's weight a function of the scores alone: its score over
+    the sum of the chosen scores, whatever the bias was."""
+    lp = _weights(41)
+    x = jax.random.normal(jax.random.key(42), (64, D))
+    scores = np.asarray(jax.nn.sigmoid(x @ lp["router.weight"]))
+    plain = _layer(lp, 0, OF)
+    bias = np.zeros(OF, np.float32)
+    bias[3] = 1.0           # expert 3 is always chosen, expert 5 never
+    bias[5] = -1.0
+    biased = _layer(lp, 0, OF, bias=bias)
+    top_p, w_p = plain.route(x, lp["router.weight"])
+    top_b, w_b = biased.route(x, lp["router.weight"],
+                              biased.e_score_correction_bias._data)
+    top_p, top_b = np.asarray(top_p), np.asarray(top_b)
+    assert (top_b == 3).any(-1).all() and not (top_b == 5).any()
+    assert not (top_p == 3).any(-1).all() and (top_p == 5).any()
+    # the chosen are the k largest of score + bias ...
+    want = np.sort(np.argsort(-(scores + bias), axis=-1)[:, :K], -1)
+    np.testing.assert_array_equal(np.sort(top_b, -1), want)
+    # ... and their weights know nothing of it
+    chosen = np.take_along_axis(scores, top_b, -1)
+    np.testing.assert_allclose(w_b, chosen / chosen.sum(-1, keepdims=True),
+                               rtol=1e-6)
+    # the layer against the reference, on a share, forward and backward
+    v = dict(V_GLM, expert_share={"first": 4, "held": 8, "of": OF})
+    made = ref_glm.score_bias(v, 1)
+    layer = _layer(lp, 4, 8, bias=made)
+    cut = dict(lp, **{k: lp[k][4:12] for k in ("experts.gate", "experts.up",
+                                               "experts.down")})
+    x3 = x.reshape(2, 32, D)
+    do = jax.random.normal(jax.random.key(43), x3.shape)
+    got, dx = _out_and_dx(lambda x: _apply(layer, x), x3, do)
+    want, dx_ref = _out_and_dx(
+        lambda x: ref_glm._experts(x, cut, v, numerics.Exact(), 1), x3, do)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    np.testing.assert_allclose(dx, dx_ref, atol=1e-4)
+    # the bias moved choices at this scale (else the check above is idle)
+    assert (np.sort(np.asarray(layer.route(
+        x, lp["router.weight"], jnp.asarray(made))[0]), -1)
+        != np.sort(top_p, -1)).any()
+
+
+def test_without_the_bias_the_layer_is_what_it_was():
+    """``score_bias=False`` (Laguna): no buffer, the lowered layer takes
+    the same six operands and holds no trace of a bias; and a zero bias
+    gives bit for bit the same output as none."""
+    lp = _weights(51)
+    x = jax.random.normal(jax.random.key(52), (2, 32, D))
+    plain, zero = _layer(lp, 4, 8), _layer(lp, 4, 8, bias=np.zeros(OF))
+    assert plain.e_score_correction_bias is None
+    assert [n for n, _ in plain.named_buffers()] == []
+    assert [n for n, _ in zero.named_buffers()] == [
+        "e_score_correction_bias"]
+    assert "e_score_correction_bias" not in dict(zero.named_parameters())
+    np.testing.assert_array_equal(jax.jit(lambda x: _apply(plain, x))(x),
+                                  jax.jit(lambda x: _apply(zero, x))(x))
+    text = jax.jit(lambda x: _apply(plain, x)).lower(x).as_text()
+    biased = jax.jit(lambda x: _apply(zero, x)).lower(x).as_text()
+    assert "tensor<16xf32>" not in text and "tensor<16xf32>" in biased
 
 
 def test_no_token_is_dropped_under_uneven_routing():
@@ -244,6 +322,7 @@ def test_moe_plan_event_and_tally():
     assert a["expected_rows"] == 32 * K * 8 // OF
     assert a["buffer_rows"] == gm.padded_rows(32 * K, 8, 8)
     assert a["row_tile"] == 8 and a["gate_up_tile"] == "48x48"
+    assert a["score_bias"] is False
 
 
 def test_held_must_be_a_range_of_the_experts():

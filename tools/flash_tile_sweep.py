@@ -47,6 +47,9 @@ SHAPES = {
     # full layer (48 heads over 8)
     "laguna_win": (128, 16, 64, 8, 8192, 128, "bfloat16", "bfloat16", 512),
     "laguna_full": (96, 16, 48, 8, 8192, 128, "bfloat16", "bfloat16"),
+    # glm47-flash-train-s8192: b2, latent attention expanded to 20 heads
+    # over 20 of 192 + 64 = 256 (values 256 too), bf16
+    "glm": (40, 40, 20, 20, 8192, 256, "bfloat16", "bfloat16"),
 }
 # (block_q, block_k, sub_q, sub_k): the plan's tile (512 x 512 under the
 # window, which 1024-long sides do not divide: give its own list) in one
@@ -102,7 +105,7 @@ def _row(shape, kernel, tile):
             **dict(zip(("bq", "bk", "sub_q", "sub_k"), tile))}
 
 
-def compile_only(shapes, tiles):
+def compile_only(shapes, tiles, kernels=KERNELS):
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     import jax
     from jax.experimental import topologies
@@ -113,7 +116,7 @@ def compile_only(shapes, tiles):
     chip = SingleDeviceSharding(topo.devices[0])
     rows = []
     for shape in shapes:
-        for kernel in KERNELS:
+        for kernel in kernels:
             for tile in tiles:
                 fn, args = build(shape, kernel, tile)
                 args = [jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=chip)
@@ -130,7 +133,7 @@ def compile_only(shapes, tiles):
     return rows
 
 
-def measure(shapes, tiles, reps):
+def measure(shapes, tiles, reps, kernels=KERNELS):
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -159,7 +162,7 @@ def measure(shapes, tiles, reps):
         args = {"fwd": (q, k, v), "dq": (q, k, v, do, lse3, delta),
                 "dkv": (q, k, v, do, lse3, delta)}
         jitted = {}
-        for kernel in KERNELS:
+        for kernel in kernels:
             for tile in tiles:
                 fn, _ = build(shape, kernel, tile)
                 fn.__name__ = f"sweep_{shape}_{kernel}_" + _label(tile, "_")
@@ -239,6 +242,10 @@ def main(argv=None):
     ap.add_argument("--shapes", default="gpt2,mistral")
     ap.add_argument("--tiles", default=",".join(_label(t) for t in TILES),
                     help="BQxBK[/SQxSK],... or pr26: PR 26's tile grid")
+    ap.add_argument("--kernels", default=",".join(KERNELS),
+                    help="of fwd,dq,dkv: a dozen programs a process is what "
+                    "one trace holds, so a long list of tiles wants one "
+                    "kernel a call")
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--compile-only", action="store_true")
     ap.add_argument("--out", default="chiprun_out/flash_tile_sweep.json")
@@ -250,10 +257,11 @@ def main(argv=None):
     for t in a.tiles.split(","):      # BQxBK or BQxBK/SQxSK
         sides = [int(x) for part in t.split("/") for x in part.split("x")]
         tiles.append(tuple(sides + sides[:4 - len(sides)]))
+    kernels = tuple(a.kernels.split(","))
     if a.compile_only:
-        rows = compile_only(shapes, tiles)
+        rows = compile_only(shapes, tiles, kernels)
     else:
-        rows = measure(shapes, tiles, a.reps)
+        rows = measure(shapes, tiles, a.reps, kernels)
         print(table(rows))
     os.makedirs(os.path.dirname(a.out) or ".", exist_ok=True)
     with open(a.out, "w") as f:
