@@ -47,6 +47,7 @@ import dataclasses
 import functools
 import gc
 import json
+import re
 import sys
 import time
 import traceback
@@ -410,7 +411,13 @@ def _moe_leg(name, model, cfg, p, kernels) -> dict:
                              f"repeated batch: {losses}")
     return {"losses": [round(v, 4) for v in losses],
             "kernels_in_step": names, "routing_stats": routing,
-            "mosaic_calls_in_step": text.count("tpu_custom_call")}
+            "mosaic_calls_in_step": text.count("tpu_custom_call"),
+            # what a recomputed block keeps, and the calls of the flash
+            # forward kernel's jitted ``_fwd`` that the lowered step makes:
+            # one a layer under "flash_saveable", two under "full"
+            "recompute_policy": cfg.recompute_policy,
+            "flash_fwd_calls_in_step": len(
+                re.findall(r"call @_fwd(?:_\d+)?\(", text))}
 
 
 def leg_laguna(p) -> dict:

@@ -14,15 +14,22 @@ state to rebuild the vjp — the reference's RNG-replay contract.
 """
 from __future__ import annotations
 
+import collections
 from typing import Sequence
 
 import jax
+from jax.ad_checkpoint import checkpoint_name
 
 from ....core import random as _random
 from ....core.autograd import TapeNode, is_tape_active, no_grad, tape_paused
 from ....core.tensor import Tensor
 
-__all__ = ["recompute", "recompute_sequential", "checkpoint"]
+__all__ = ["recompute", "recompute_sequential", "checkpoint", "keep",
+           "RECOMPUTE_PLAN_TALLY"]
+
+# blocks lowered through ``_remat_functional`` by (policy, bytes kept):
+# trace time only, nothing a step (as flash's TILE_PLAN_TALLY)
+RECOMPUTE_PLAN_TALLY: collections.Counter = collections.Counter()
 
 
 def _any_traced(args) -> bool:
@@ -32,6 +39,9 @@ def _any_traced(args) -> bool:
     return False
 
 
+# a policy is None (replay everything), an attribute of
+# jax.checkpoint_policies, or the tuple of names ``keep`` gave that the
+# backward pass keeps (save_only_these_names)
 _POLICIES = {
     None: None, "full": None, "nothing_saveable": None,
     # selective remat: save matmul/dot outputs, recompute only cheap
@@ -45,6 +55,18 @@ _POLICIES = {
     "selective": "dots_saveable",
     "dots_with_no_batch_dims_saveable": "dots_with_no_batch_dims_saveable",
     "everything_saveable": "everything_saveable",
+    # "full" with one thing kept: a flash call's output and statistics, so
+    # the replay re-runs the projections (the backward kernels need q, k,
+    # v) but not the forward kernel, whose second run would write the same
+    # bits. A call costs B x S x Hq x D x itemsize + 4 x B x Hq x S bytes.
+    # Rule of thumb: a kept byte of ``out`` saves as many FLOPs as a query
+    # sees keys (causal: 4 x S/2 x D FLOPs a row of 2 D bytes; S at full
+    # attention, the window under one), a matmul output's byte as many as
+    # a quarter of the contracted width: past a few thousand keys the
+    # forward kernel is the dearest thing in the block to replay. The names
+    # are given in ops/pallas/flash_attention.py::_fa_fwd: ``out`` [B, S,
+    # Hq, D] and the softmax statistics ``lse`` [B x Hq, S] float32.
+    "flash_saveable": ("flash_out", "flash_lse"),
 }
 
 
@@ -55,8 +77,44 @@ def _resolve_policy(policy):
         raise ValueError(
             f"unknown recompute policy {policy!r}; one of "
             f"{sorted(k for k in _POLICIES if isinstance(k, str))}")
-    name = _POLICIES[policy]
-    return getattr(jax.checkpoint_policies, name) if name else None
+    entry = _POLICIES[policy]
+    if isinstance(entry, tuple):
+        return jax.checkpoint_policies.save_only_these_names(*entry)
+    return getattr(jax.checkpoint_policies, entry) if entry else None
+
+
+# the residuals named inside each block being traced (innermost last):
+# [name, bytes] pairs, read by ``_remat_functional`` for its plan
+_NAMED = []
+
+
+def keep(x, name):
+    """Name ``x`` for the policies that keep by name (``_POLICIES``), and
+    tell the block being lowered, if one is, what it would hold. Outside a
+    ``jax.checkpoint`` with such a policy the name is the identity and
+    lowers to nothing."""
+    if _NAMED:
+        _NAMED[-1].append((name, x.size * x.dtype.itemsize))
+    return checkpoint_name(x, name)
+
+
+def _record_plan(policy, named):
+    """One ``recompute::plan`` event and one count in RECOMPUTE_PLAN_TALLY
+    for a block lowered through ``_remat_functional``: the policy, the
+    names it keeps and the bytes they hold in this block (from the shapes
+    of what ``keep`` named inside; a callable policy keeps what only it
+    knows: None)."""
+    from ....profiler.tracing import trace_event
+    if callable(policy):
+        label, names, kept = getattr(policy, "__name__", "callable"), (), None
+    else:
+        label, entry = policy or "full", _POLICIES[policy]
+        names = entry if isinstance(entry, tuple) else ()
+        kept = sum(b for n, b in named if n in names)
+    trace_event("recompute::plan", cat="model", policy=label,
+                names=",".join(names), kept_bytes=kept,
+                named_values=len(named))
+    RECOMPUTE_PLAN_TALLY[(label, kept)] += 1
 
 
 def _remat_functional(function, args, kwargs, policy=None):
@@ -66,17 +124,25 @@ def _remat_functional(function, args, kwargs, policy=None):
     live for the optimizer anyway); only the explicit activation args bound
     the remat segment. ``policy`` selects WHAT to save (reference
     recompute saves everything-at-boundaries; 'dots_saveable'/'selective'
-    keep matmul outputs so the backward re-runs only elementwise work)."""
+    keep matmul outputs so the backward re-runs only elementwise work;
+    'flash_saveable' replays everything but the flash forward kernel, whose
+    output and statistics it keeps). Leaves a ``recompute::plan`` event."""
     tensor_idx = [i for i, a in enumerate(args) if isinstance(a, Tensor)]
     arrays = [args[i]._data for i in tensor_idx]
     sg = [args[i].stop_gradient for i in tensor_idx]
-    meta = {}
+    meta, named = {}, []
 
     def pure(*arrs):
         call = list(args)
         for j, i in enumerate(tensor_idx):
             call[i] = Tensor(arrs[j], stop_gradient=sg[j])
-        out = function(*call, **kwargs)
+        # only while the block's own body is traced: the custom_vjp rules
+        # of what is inside are traced later, outside this frame
+        _NAMED.append(named)
+        try:
+            out = function(*call, **kwargs)
+        finally:
+            _NAMED.pop()
         single = not isinstance(out, (tuple, list))
         meta["single"] = single
         outs = (out,) if single else tuple(out)
@@ -86,6 +152,7 @@ def _remat_functional(function, args, kwargs, policy=None):
     pol = _resolve_policy(policy)
     res = (jax.checkpoint(pure, policy=pol) if pol is not None
            else jax.checkpoint(pure))(*arrays)
+    _record_plan(policy, named)
     outs = [Tensor(r, stop_gradient=False) if t else r
             for r, t in zip(res, meta["is_tensor"])]
     return outs[0] if meta["single"] else tuple(outs)
@@ -94,8 +161,14 @@ def _remat_functional(function, args, kwargs, policy=None):
 def recompute(function, *args, **kwargs):
     """paddle.distributed.fleet.utils.recompute parity. ``use_reentrant``
     accepted and ignored (single behavior). ``policy`` (jit path only)
-    picks the jax.checkpoint saveable policy; the eager tape path always
-    replays the whole segment (the reference behavior)."""
+    picks the jax.checkpoint saveable policy (``_POLICIES``): "full", the
+    reference's, replays the whole segment; "dots_saveable" / "selective"
+    keep matmul outputs; "flash_saveable" is "full" with each flash call's
+    ``out`` and ``lse`` kept (B x S x Hq x D x itemsize + 4 x B x Hq x S
+    bytes a call), so the forward kernel is not run a second time: worth
+    it from a few thousand keys on, since a byte of ``out`` saves as many
+    FLOPs as a query sees keys. The eager tape path always replays the
+    whole segment (the reference behavior)."""
     kwargs.pop("use_reentrant", None)
     preserve_rng = kwargs.pop("preserve_rng_state", True)
     policy = kwargs.pop("policy", None)

@@ -88,7 +88,10 @@ class GlmMoeLiteConfig:
     rms_norm_eps: float = 1e-5
     max_position_embeddings: int = 202752
     use_recompute: bool = False
-    recompute_policy: str = "full"
+    # the published contexts start at 8k, where the flash forward kernel is
+    # the dearest thing in a block to replay: keep its output and statistics
+    # (fleet.recompute's policies; "full" replays the kernel too)
+    recompute_policy: str = "flash_saveable"
     lm_ce: str = "blockwise"
 
     def __post_init__(self):

@@ -80,7 +80,10 @@ class LagunaConfig:
     rms_norm_eps: float = 1e-6
     gating: bool = True
     use_recompute: bool = False
-    recompute_policy: str = "full"
+    # the published contexts start at 8k, where the flash forward kernel is
+    # the dearest thing in a block to replay: keep its output and statistics
+    # (fleet.recompute's policies; "full" replays the kernel too)
+    recompute_policy: str = "flash_saveable"
     lm_ce: str = "blockwise"
 
     def __post_init__(self):

@@ -47,7 +47,9 @@ class LlamaConfig:
     use_recompute: bool = False
     # jax.checkpoint saveable policy for use_recompute: "full" replays the
     # whole layer; "dots_saveable"/"selective" keep matmul outputs and
-    # recompute only elementwise (near-zero extra FLOPs, more memory)
+    # recompute only elementwise (near-zero extra FLOPs, more memory);
+    # "flash_saveable" is "full" with each flash call's output and
+    # statistics kept, so the forward kernel is not replayed (long contexts)
     recompute_policy: str = "full"
     # "plain": full logits through lm_head + CE; "blockwise": vocab-chunked
     # streaming LM-head+CE (ops/fused_ce.py) — same math, caps the logits

@@ -1759,6 +1759,10 @@ def _fa_fwd(q, k, v, bias, seed, q_seg, k_seg, causal, scale, dropout_rate,
                           offset, Sk, tile, maps, interpret, qseg3, kseg3,
                           window)
     out = out3[:, :Sq].reshape(B, Hq, Sq, D).transpose(0, 2, 1, 3)
+    # a recomputed block may keep these two (recompute's "flash_saveable")
+    # and spare this kernel's second run; elsewhere a name is the identity
+    from ...distributed.fleet.recompute import keep
+    out, lse = keep(out, "flash_out"), keep(lse, "flash_lse")
     return out, (q, k, v, bias, seed, q_seg, k_seg, out, lse)
 
 

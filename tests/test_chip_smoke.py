@@ -67,6 +67,9 @@ def test_leg_laguna_tiny(interpret_kernels):
     assert {k[0] for k in TILE_PLAN_TALLY} >= {
         "flash_win_fwd", "flash_win_bwd_dq", "flash_win_bwd_dkv"}
     assert any(k[:3] == (4, 16, 4) for k in MOE_PLAN_TALLY)
+    # five recomputed layers, each flash forward lowered once
+    assert out["recompute_policy"] == "flash_saveable"
+    assert out["flash_fwd_calls_in_step"] == 5
 
 
 def test_leg_glm_tiny(interpret_kernels):
@@ -77,6 +80,9 @@ def test_leg_glm_tiny(interpret_kernels):
     assert out["mla_plan"]["route"] == "kernel"
     assert out["mla_plan"]["rule"] == "default"
     assert out["mla_plan"]["tiles"].startswith("fwd=32x32/")
+    # three layers and the MTP module, each flash forward lowered once
+    assert out["recompute_policy"] == "flash_saveable"
+    assert out["flash_fwd_calls_in_step"] == 4
 
 
 def test_leg_serve_tiny():

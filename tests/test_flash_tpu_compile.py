@@ -11,6 +11,8 @@ would refuse, it refuses here. Nothing runs; no time is read.
 The topology is described inside a fixture (never while a module is
 imported): only the worker that is handed this file loads the TPU's library.
 """
+import collections
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -196,3 +198,50 @@ def test_grouped_matmuls_compile_for_v5e(k, n, one_chip, production_numerics):
         arg((1,), "int32")).compile().as_text()
     for name in ("moe_gmm_fwd", "moe_gmm_bwd_x", "moe_gmm_bwd_w"):
         assert name in text, name
+
+
+@pytest.mark.parametrize("policy", ["flash_saveable", "full"])
+def test_recomputed_laguna_step_holds_one_forward_kernel_a_layer(
+        policy, one_chip, production_numerics, monkeypatch):
+    """What Mosaic is handed, not only the jaxpr: a two-layer Laguna step
+    (one full, one window layer, each recomputed in the backward pass)
+    compiled for the described chip holds one ``flash_fwd`` and one
+    ``flash_win_fwd`` custom call when the blocks keep their flash calls'
+    output and statistics (the family's default), and two of each when
+    they replay everything."""
+    import re
+
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu import models
+    from paddle_tpu.ops.pallas import common
+
+    # the step asks the backend for its route; the test steers it
+    monkeypatch.setattr(common, "on_tpu", lambda: True)
+    paddle.seed(0)
+    cfg = models.laguna_tiny(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        head_dim=128, layer_types=("full_attention", "sliding_attention"),
+        mlp_layer_types=("dense", "dense"), num_heads_per_layer=(2, 4),
+        sliding_window=256, max_position_embeddings=1024,
+        lm_ce="blockwise", use_recompute=True, recompute_policy=policy)
+    model = models.LagunaForCausalLM(cfg).bfloat16()
+    model.train()
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                 parameters=model.parameters())
+    step, params, opt_state = models.create_train_step(model, opt)
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    ids = described(np.zeros((1, 1024), np.int32))
+    text = step.lower(
+        jax.tree.map(described, params), jax.tree.map(described, opt_state),
+        described(jax.random.key(0)), ids, ids, 1e-3).compile().as_text()
+    calls = collections.Counter(
+        re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*tpu_custom_call", text))
+    twice = 1 if policy == "flash_saveable" else 2
+    assert {k: n for k, n in calls.items() if "flash" in k} == {
+        "flash_fwd": twice, "flash_win_fwd": twice,
+        "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
+        "flash_win_bwd_dq": 1, "flash_win_bwd_dkv": 1}
