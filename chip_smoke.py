@@ -21,6 +21,13 @@ means, any failure making the exit code non-zero:
              kernels and the grouped matmuls are in the lowered step, the
              route and tile plan of its attention are printed, three
              steps give a finite falling loss
+  sala       MiniCPM-SALA as minicpm-sala-train-s12288 runs it (one period:
+             a block-sparse layer and three lightning layers at 9B widths,
+             an eighth of the vocabulary, batch 1 x 12288, the layer body
+             recomputed): the step is lowered and compiled at that size,
+             both mixers' plans, the kernel names in the lowered text and
+             the compiler's memory count are printed, and three steps
+             give a finite falling loss
   train      GPT-2 small, seq 1024, batch 8, bf16 params, through
              create_train_step(donate=True) driven by run_steps: loss
              finite and falling, no compile after the first step, the
@@ -123,6 +130,8 @@ CHIP = {
     "laguna": {"tiny": False, "batch": 2, "seq": 8192, "steps": 3,
                "lr": 3e-4},
     "glm": {"tiny": False, "batch": 2, "seq": 8192, "steps": 3, "lr": 3e-4},
+    "sala": {"tiny": False, "batch": 1, "seq": 12288, "steps": 3,
+             "lr": 3e-4},
     "train": {"model": "gpt2_small", "batch": 8, "seq": 1024, "steps": 8,
               "lr": 3e-4},
     "serve": {"model": "gpt2_small", "max_slots": 4, "page_len": 128,
@@ -150,6 +159,8 @@ TINY = {
     },
     "laguna": {"tiny": True, "batch": 2, "seq": 32, "steps": 3, "lr": 1e-2},
     "glm": {"tiny": True, "batch": 2, "seq": 32, "steps": 3, "lr": 1e-2},
+    # 64 tokens: past the tiny preset's dense_len of 32
+    "sala": {"tiny": True, "batch": 2, "seq": 64, "steps": 3, "lr": 1e-2},
     "train": {"model": "gpt2_tiny", "batch": 2, "seq": 128, "steps": 4,
               "lr": 1e-2},
     "serve": {"model": "gpt2_tiny", "max_slots": 4, "page_len": 16,
@@ -487,6 +498,93 @@ def leg_glm(p) -> dict:
     return out
 
 
+# -- leg: sala --------------------------------------------------------------
+
+SALA_KERNELS = ("sparse_attn_fwd", "sparse_attn_bwd_dq", "sparse_attn_bwd_dkv",
+                "linear_attn_fwd", "linear_attn_bwd")
+
+
+def leg_sala(p) -> dict:
+    """The decoder of minicpm-sala-train-s12288 (BENCHMARK.json) through the
+    trainer: published widths, one period of the mixers, an eighth of the
+    vocabulary, the layer body recomputed. The step is lowered and compiled
+    once and that program runs the steps."""
+    import jax
+    import jax.numpy as jnp
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models import (MiniCPMSALAConfig, MiniCPMSALAForCausalLM,
+                                   create_train_step, minicpm_sala_tiny,
+                                   run_steps)
+    from paddle_tpu.profiler import tracing
+
+    paddle.seed(SEED)
+    if p["tiny"]:
+        cfg = minicpm_sala_tiny(use_recompute=True)
+    else:
+        cfg = MiniCPMSALAConfig(
+            vocab_size=9216, mixer_types=MiniCPMSALAConfig.mixer_types[:4],
+            use_recompute=True)
+    model = MiniCPMSALAForCausalLM(cfg).bfloat16()
+    model.train()
+    opt = paddle.optimizer.AdamW(learning_rate=p["lr"], weight_decay=0.01,
+                                 parameters=model.parameters())
+    rng = np.random.RandomState(SEED)
+    ids = jnp.asarray(rng.randint(0, cfg.vocab_size,
+                                  (p["batch"], p["seq"] + 1)), jnp.int32)
+    x, y = ids[:, :-1], ids[:, 1:]
+    step, params, opt_state = create_train_step(model, opt,
+                                                donate="consume")
+    key = jax.random.key(SEED)
+    tracing.reset_tracing()
+    tracing.enable_tracing(ring_size=tracing.DEFAULT_RING_SIZE)
+    try:
+        lowered = step.lower(params, opt_state, key, x, y, p["lr"])
+        events = tracing.snapshot_events()
+    finally:
+        tracing.disable_tracing()
+        tracing.reset_tracing()
+    plans = {name: [e["args"] for e in events if e["name"] == name]
+             for name in ("sparse_attn::plan", "linear_attn::plan",
+                          "recompute::plan")}
+    sparse_layers = sum(k == "minicpm4" for k in cfg.mixer_types)
+    if len(plans["sparse_attn::plan"]) < sparse_layers or \
+            len(plans["linear_attn::plan"]) < cfg.num_layers - sparse_layers:
+        raise AssertionError(f"sala: plans {plans}")
+    text = lowered.as_text()
+    names = {n: text.count(n) for n in SALA_KERNELS}
+    if on_chip() and not all(names.values()):
+        raise AssertionError(f"sala: kernels missing from the lowered "
+                             f"step: {names}")
+    with compile_watch() as first:
+        compiled = lowered.compile()
+    mem = compiled.memory_analysis()
+    memory = {k: getattr(mem, k, None) for k in (
+        "argument_size_in_bytes", "output_size_in_bytes",
+        "temp_size_in_bytes", "alias_size_in_bytes")} if mem else {}
+    with compile_watch() as watch:
+        params, opt_state, losses = run_steps(
+            compiled, params, opt_state, [(x, y)] * p["steps"], key=key,
+            lr=p["lr"])
+    losses = [float(v) for v in losses]
+    if watch["compiles"]:
+        raise AssertionError(f"sala: {watch['compiles']} compile(s) after "
+                             "the step was compiled")
+    if not (np.isfinite(losses).all() and losses[-1] < losses[0]
+            and max(losses[1:]) < losses[0]):
+        raise AssertionError(f"sala: loss not finite and falling on one "
+                             f"repeated batch: {losses}")
+    return {"losses": [round(v, 4) for v in losses],
+            "kernels_in_step": names,
+            "mosaic_calls_in_step": text.count("tpu_custom_call"),
+            "sparse_plan": plans["sparse_attn::plan"][-1],
+            "linear_plan": plans["linear_attn::plan"][-1],
+            "recompute_plan": plans["recompute::plan"][0],
+            "recompute_policy": cfg.recompute_policy,
+            "compiler_memory": memory,
+            "compile_requests": first["compiles"]}
+
+
 # -- leg: train -------------------------------------------------------------
 
 def leg_train(p) -> dict:
@@ -769,7 +867,7 @@ def leg_four_chip(p) -> dict:
 # -- driver -----------------------------------------------------------------
 
 LEGS = {"kernels": leg_kernels, "laguna": leg_laguna, "glm": leg_glm,
-        "train": leg_train,
+        "sala": leg_sala, "train": leg_train,
         "serve": leg_serve, "four_chip": leg_four_chip}
 
 

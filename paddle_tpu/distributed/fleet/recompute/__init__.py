@@ -67,7 +67,21 @@ _POLICIES = {
     # are given in ops/pallas/flash_attention.py::_fa_fwd: ``out`` [B, S,
     # Hq, D] and the softmax statistics ``lse`` [B x Hq, S] float32.
     "flash_saveable": ("flash_out", "flash_lse"),
+    # a block whose mixer is attention over chosen key blocks
+    # (F.block_sparse_attention): what is dear and small there is the choice,
+    # ``sparse_choice`` [B, Hkv, S, blocks] bool (a byte a block, token and kv
+    # head against a softmax over every pooled key for every query head),
+    # and, as for a flash call, the sweep's ``sparse_out`` and
+    # ``sparse_lse``; below ``dense_len`` the mixer IS a flash call, so what
+    # "flash_saveable" keeps is kept too. A linear-attention mixer's chunk
+    # states are residuals of its own custom_vjp inside the replay and are
+    # not named:
+    # its forward kernel takes 1.1 ms a layer at S 12288 against the sparse
+    # forward sweep's 10.3 ms, and its states are 100 MB a layer (PERF.md,
+    # PR 34).
+    "sala_saveable": ("sparse_choice", "sparse_out", "sparse_lse"),
 }
+_POLICIES["sala_saveable"] += _POLICIES["flash_saveable"]
 
 
 def _resolve_policy(policy):
@@ -126,7 +140,9 @@ def _remat_functional(function, args, kwargs, policy=None):
     recompute saves everything-at-boundaries; 'dots_saveable'/'selective'
     keep matmul outputs so the backward re-runs only elementwise work;
     'flash_saveable' replays everything but the flash forward kernel, whose
-    output and statistics it keeps). Leaves a ``recompute::plan`` event."""
+    output and statistics it keeps; 'sala_saveable' likewise for a block
+    of chosen-block attention, and keeps the choice). Leaves a
+    ``recompute::plan`` event."""
     tensor_idx = [i for i, a in enumerate(args) if isinstance(a, Tensor)]
     arrays = [args[i]._data for i in tensor_idx]
     sg = [args[i].stop_gradient for i in tensor_idx]

@@ -9,6 +9,9 @@ from .flash_attention import (  # noqa: F401
     flash_attention, scaled_dot_product_attention, flash_attn_unpadded,
     sdp_kernel,
 )
+from .block_sparse_attention import (  # noqa: F401
+    block_sparse_attention, lightning_attention, select_attention_blocks,
+)
 from ..decode import gather_tree  # noqa: F401
 from ...tensor.creation import diag_embed  # noqa: F401
 from ...tensor.math import pdist  # noqa: F401

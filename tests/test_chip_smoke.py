@@ -85,6 +85,22 @@ def test_leg_glm_tiny(interpret_kernels):
     assert out["flash_fwd_calls_in_step"] == 4
 
 
+def test_leg_sala_tiny(interpret_kernels):
+    out = chip_smoke.leg_sala(chip_smoke.TINY["sala"])
+    assert out["losses"][-1] < out["losses"][0]
+    # interpreted kernels leave no name in the lowered step; the plans say
+    # that both mixers took their kernels' path, past dense_len
+    assert set(out["kernels_in_step"]) == set(chip_smoke.SALA_KERNELS)
+    assert (out["sparse_plan"]["path"], out["sparse_plan"]["seq"],
+            out["sparse_plan"]["topk"]) == ("kernel", 64, 4)
+    assert (out["linear_plan"]["path"], out["linear_plan"]["chunk"]) == (
+        "kernel", 64)
+    assert out["recompute_policy"] == "sala_saveable"
+    assert out["recompute_plan"]["kept_bytes"] > 0
+    assert out["compiler_memory"]["argument_size_in_bytes"] > 0
+    assert out["compile_requests"] == 1
+
+
 def test_leg_serve_tiny():
     out = chip_smoke.leg_serve(chip_smoke.TINY["serve"])
     assert out["requests"] == 5 and not out["pools_donated"]

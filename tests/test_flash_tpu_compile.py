@@ -20,6 +20,8 @@ from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops.pallas import flash_attention as fa
 from paddle_tpu.ops.pallas import grouped_matmul as gm
+from paddle_tpu.ops.pallas import linear_attention as la
+from paddle_tpu.ops.pallas import sparse_attention as sa
 
 
 @pytest.fixture(scope="module")
@@ -245,3 +247,95 @@ def test_recomputed_laguna_step_holds_one_forward_kernel_a_layer(
         "flash_fwd": twice, "flash_win_fwd": twice,
         "flash_bwd_dq": 1, "flash_bwd_dkv": 1,
         "flash_win_bwd_dq": 1, "flash_win_bwd_dkv": 1}
+
+
+# minicpm-sala-train-s12288's two mixers at the cell's shapes (B 1, S 12288,
+# heads of 128), and a sequence the chunk does not divide
+@pytest.mark.parametrize("case,s,tiles", [
+    ("sala_cell_plan", 12288, None),
+    ("sala_cell_512", 12288, sa.SparseTiles(512, 512)),
+    ("s2048", 2048, None),
+])
+def test_chosen_block_attention_compiles_for_v5e(case, s, tiles, one_chip,
+                                                 production_numerics):
+    def arg(shape, dtype="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+    sc = sa.SparseConfig(dense_len=1024)
+
+    def loss(q, k, v):
+        chosen = sa.select_blocks(q, k, sc)
+        out = sa.sparse_attention(q, k, v, chosen, 128 ** -0.5,
+                                  sc.block_size, tiles, False)
+        return out.astype(jnp.float32).sum()
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        arg((1, s, 32, 128)), arg((1, s, 2, 128)),
+        arg((1, s, 2, 128))).compile().as_text()
+    for name in ("sparse_attn_fwd", "sparse_attn_bwd_dq",
+                 "sparse_attn_bwd_dkv"):
+        assert name in text, name
+    if case == "sala_cell_plan":
+        assert sa.sparse_tile_plan(s, sc.block_size) == (1024, 1024)
+
+
+@pytest.mark.parametrize("s,chunk", [(12288, None), (12288, 512),
+                                     (1100, None)])
+def test_linear_attention_compiles_for_v5e(s, chunk, one_chip,
+                                           production_numerics):
+    def arg(shape, dtype="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    def loss(q, k, v, rates):
+        return la.linear_attention(q, k, v, rates, 128 ** -0.5, chunk,
+                                   False).astype(jnp.float32).sum()
+    x = arg((1, s, 32, 128))
+    text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
+        x, x, x, arg((32,), "float32")).compile().as_text()
+    assert "linear_attn_fwd" in text and "linear_attn_bwd" in text
+
+
+@pytest.mark.parametrize("policy", ["sala_saveable", "full"])
+def test_a_replayed_sala_block_keeps_its_choice_and_its_sweep(
+        policy, one_chip, production_numerics, monkeypatch):
+    """A step of the MiniCPM-SALA family (a sparse and a lightning layer,
+    heads of 128, 1,024 tokens past a dense_len of 512, layer bodies
+    recomputed) compiled for the described chip: under the family's default
+    the sparse forward sweep is lowered once, under "full" twice; the linear
+    scan is replayed either way."""
+    import re
+
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu import models
+    from paddle_tpu.ops.pallas import common
+
+    monkeypatch.setattr(common, "on_tpu", lambda: True)
+    paddle.seed(0)
+    cfg = models.minicpm_sala_tiny(
+        vocab_size=512, hidden_size=256, intermediate_size=512,
+        mixer_types=("minicpm4", "lightning-attn"), head_dim=128,
+        lightning_nh=2, lightning_head_dim=128, dim_model_base=256,
+        sparse=sa.SparseConfig(topk=6, window_size=128, dense_len=512),
+        max_position_embeddings=1024, lm_ce="blockwise", use_recompute=True,
+        recompute_policy=policy)
+    model = models.MiniCPMSALAForCausalLM(cfg).bfloat16()
+    model.train()
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                 parameters=model.parameters())
+    step, params, opt_state = models.create_train_step(model, opt)
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    ids = described(np.zeros((1, 1024), np.int32))
+    text = step.lower(
+        jax.tree.map(described, params), jax.tree.map(described, opt_state),
+        described(jax.random.key(0)), ids, ids, 1e-3).compile().as_text()
+    calls = collections.Counter(
+        re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*tpu_custom_call", text))
+    twice = 1 if policy == "sala_saveable" else 2
+    assert {k: n for k, n in calls.items() if "_attn_" in k} == {
+        "sparse_attn_fwd": twice, "sparse_attn_bwd_dq": 1,
+        "sparse_attn_bwd_dkv": 1, "linear_attn_fwd": 2,
+        "linear_attn_bwd": 1}
