@@ -12,6 +12,7 @@ the reference's multi_tensor_adam achieves by hand.
 """
 from __future__ import annotations
 
+import collections
 from typing import Dict, List, Optional
 
 import jax
@@ -23,7 +24,12 @@ from ..nn.clip import ClipGradBase
 from .lr import LRScheduler
 
 __all__ = ["Optimizer", "SGD", "Momentum", "Adagrad", "RMSProp", "Adam", "Rprop", "LBFGS",
-           "AdamW", "Adamax", "Lamb", "Adadelta"]
+           "AdamW", "Adamax", "Lamb", "Adadelta", "UPDATE_PLAN_TALLY"]
+
+# calls of ``apply_gradients`` by (leaves with a gradient, leaves whose
+# gradient is held apart, their parameters, their gradients' bytes): trace
+# time only, nothing a step (as flash's TILE_PLAN_TALLY)
+UPDATE_PLAN_TALLY: collections.Counter = collections.Counter()
 
 
 class Optimizer:
@@ -221,14 +227,32 @@ class Optimizer:
                         wd_mask: Optional[Dict[str, bool]] = None):
         """Pure functional update over {name: array} dicts — call inside
         jax.jit. ``wd_mask[name]=False`` skips weight decay (bias/norm
-        params), mirroring AdamW.apply_decay_param_fun."""
+        params), mirroring AdamW.apply_decay_param_fun.
+
+        A matrix's gradient (a leaf of 2+ dimensions) is held apart from
+        its update by an ``optimization_barrier`` of its own: the backward
+        pass's product writes the gradient as the value the program's types
+        say it is, and the update runs behind it as an elementwise pass over
+        the parameter and its state. Without it XLA takes the whole update
+        into the weight gradient's convolution as an epilogue, which ran at
+        half the plain product's rate at 4096-wide matrices (PERF.md,
+        "PR 35"). One barrier a leaf, never one over the tree: that would
+        keep every gradient alive until the last one is there. One
+        ``optimizer::plan`` event and one count in ``UPDATE_PLAN_TALLY`` a
+        call say how many leaves were held apart."""
+        from ..profiler.tracing import trace_event
         new_params, new_states = {}, {}
         wd = self._wd_coeff()
+        leaves, held = 0, []
         for k, p in params.items():
             g = grads[k]
             if g is None:
                 new_params[k], new_states[k] = p, states[k]
                 continue
+            leaves += 1
+            if g.ndim >= 2:
+                g = jax.lax.optimization_barrier(g)
+                held.append(g)
             g = g.astype(jnp.float32)
             p32 = p.astype(jnp.float32)
             decay = wd if (wd_mask is None or wd_mask.get(k, True)) else 0.0
@@ -238,6 +262,11 @@ class Optimizer:
                                     wd=decay if self._decoupled_weight_decay() else 0.0)
             new_params[k] = np_.astype(p.dtype)
             new_states[k] = ns_
+        plan = (leaves, len(held), sum(g.size for g in held),
+                sum(g.size * g.dtype.itemsize for g in held))
+        trace_event("optimizer::plan", cat="model", **dict(zip(
+            ("leaves", "held", "held_params", "held_bytes"), plan)))
+        UPDATE_PLAN_TALLY[plan] += 1
         return new_params, new_states
 
     def _wd_coeff(self) -> float:

@@ -339,3 +339,44 @@ def test_a_replayed_sala_block_keeps_its_choice_and_its_sweep(
         "sparse_attn_fwd": twice, "sparse_attn_bwd_dq": 1,
         "sparse_attn_bwd_dkv": 1, "linear_attn_fwd": 2,
         "linear_attn_bwd": 1}
+
+
+def test_a_matrix_update_is_no_epilogue_of_its_weight_gradient(
+        one_chip, production_numerics):
+    """The step of one 1024-wide SwiGLU block (bf16 weights, AdamW) compiled
+    for the described chip: no fused computation holds both a
+    ``convolution`` and AdamW's ``sqrt``. Until PR 35 XLA took each matrix's
+    whole update into its weight gradient's convolution
+    (``subtract_convert_fusion.N``), which ran at half the plain product's
+    rate at 4096-wide matrices; ``Optimizer.apply_gradients`` now holds a
+    matrix's gradient apart, and the norms' weights keep an update of their
+    own either way."""
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu import models
+    from tools.wgrad_fusion_probe import fusions
+
+    paddle.seed(0)
+    cfg = models.LlamaConfig(
+        vocab_size=512, hidden_size=1024, intermediate_size=2816,
+        num_layers=1, num_heads=8, num_kv_heads=8,
+        max_position_embeddings=256)
+    model = models.LlamaForCausalLM(cfg).bfloat16()
+    model.train()
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                 parameters=model.parameters())
+    step, params, opt_state = models.create_train_step(model, opt)
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    ids = described(np.zeros((8, 256), np.int32))
+    held = fusions(step.lower(
+        jax.tree.map(described, params), jax.tree.map(described, opt_state),
+        described(jax.random.key(0)), ids, ids, 1e-3).compile().as_text())
+    products = {k for k, v in held.items() if v["holds"].get("convolution")}
+    updates = {k for k, v in held.items() if v["holds"].get("sqrt")}
+    matrices = sum(v.ndim >= 2 for v in params.values())
+    assert len(products) >= 3 * 7 and len(updates) >= matrices
+    assert not products & updates, {
+        k: held[k]["out"] for k in products & updates}

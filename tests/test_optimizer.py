@@ -253,3 +253,219 @@ def test_adamw_bf16_moment_storage():
     # same descent, small numeric drift only
     assert lb16[-1] < lb16[0]
     np.testing.assert_allclose(lb16, l32, rtol=0.05, atol=0.05)
+
+
+# -- the functional update holds a matrix's gradient apart (PR 35) ----------
+
+def _parent_rule(opt, params, grads, states, lr, wd_mask):
+    """``Optimizer.apply_gradients`` as it stood until PR 35: the update
+    reads the gradient as ``jax.grad`` hands it over, no barrier between.
+    There XLA may keep a bf16 gradient at the float32 it was accumulated in
+    (``xla_allow_excess_precision``) and feed the update that; behind a
+    barrier the gradient is the value its type declares. So the rule by
+    hand rounds a bf16 gradient where its type says it is rounded, as the
+    benchmark's float32 reference does; a float32 gradient it leaves."""
+    import jax
+    import jax.numpy as jnp
+    new_params, new_states = {}, {}
+    wd, decoupled = opt._wd_coeff(), opt._decoupled_weight_decay()
+    for k, p in params.items():
+        g, p32 = grads[k].astype(jnp.float32), p.astype(jnp.float32)
+        if grads[k].dtype == jnp.bfloat16:
+            g = jax.lax.reduce_precision(g, exponent_bits=8, mantissa_bits=7)
+        decay = wd if wd_mask.get(k, True) else 0.0
+        if decay and not decoupled:
+            g = g + decay * p32
+        new, new_states[k] = opt._update(p32, g, states[k], lr,
+                                         wd=decay if decoupled else 0.0)
+        new_params[k] = new.astype(p.dtype)
+    return new_params, new_states
+
+
+_FACTORIES = {
+    "adamw": lambda ps: paddle.optimizer.AdamW(1e-2, weight_decay=0.01,
+                                               parameters=ps),
+    "sgd": lambda ps: paddle.optimizer.SGD(1e-2, weight_decay=0.01,
+                                           parameters=ps),
+    "momentum": lambda ps: paddle.optimizer.Momentum(1e-2, 0.9,
+                                                     parameters=ps),
+}
+
+
+def _tiny(family, dtype):
+    from paddle_tpu import models
+    paddle.seed(35)
+    if family == "llama":
+        model = models.LlamaForCausalLM(models.llama_tiny())
+    else:
+        model = models.GPTForCausalLM(models.gpt2_tiny())
+    model = model.bfloat16() if dtype == "bfloat16" else model
+    model.train()
+    return model
+
+
+def _assert_same_leaves(got, want):
+    """To the last bits, not bit for bit: XLA's CPU backend may order a
+    float32 sum differently on the two sides of a fusion boundary (an
+    embedding's scattered gradient added into ``wd * p``), so a leaf may
+    differ by a few roundings of its largest element (of 0.01 for a leaf
+    that is rounding noise throughout: GPT-2's key biases, whose gradient
+    is zero but for rounding)."""
+    import jax
+    for (path, a), b in zip(jax.tree_util.tree_leaves_with_path(got),
+                            jax.tree.leaves(want)):
+        a, b = np.asarray(a), np.asarray(b)
+        assert a.dtype == b.dtype and a.shape == b.shape, path
+        room = 8 * np.spacing(np.maximum(np.abs(b).max(), 1e-2).astype(
+            b.dtype)).astype(np.float64)
+        gap = np.abs(a.astype(np.float64) - b.astype(np.float64)).max()
+        assert gap <= room, (jax.tree_util.keystr(path), gap, room)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("family", ["llama", "gpt2"])
+@pytest.mark.parametrize("name", list(_FACTORIES))
+def test_train_step_equals_the_parents_update_rule(name, family, dtype):
+    """A barrier is an identity: three steps of ``create_train_step`` leave
+    what the parent's rule, applied by hand to the same gradients, leaves:
+    losses, parameters and state."""
+    import jax
+
+    from paddle_tpu.models import create_train_step, trainer
+
+    model = _tiny(family, dtype)
+    opt = _FACTORIES[name](model.parameters())
+    step, params, state = create_train_step(model, opt)
+    loss_call, by_hand, state_h, wd_mask = trainer._functional_pieces(
+        model, opt, None)
+
+    @jax.jit
+    def parent_step(params, state, key, ids, labels, lr):
+        loss, grads = jax.value_and_grad(
+            lambda p: loss_call(p, ids, labels, key))(params)
+        return (loss,) + _parent_rule(opt, params, grads, state, lr, wd_mask)
+
+    key, rng = jax.random.key(0), np.random.RandomState(35)
+    for i in range(3):
+        data = rng.randint(0, 256, (2, 17))
+        x, y = data[:, :-1], data[:, 1:]
+        loss, params, state = step(params, state, key, x, y, 1e-2)
+        loss_h, by_hand, state_h = parent_step(by_hand, state_h, key, x, y,
+                                               1e-2)
+        np.testing.assert_allclose(
+            float(loss), float(loss_h),
+            rtol=1e-6 if dtype == "float32" else 1e-3, err_msg=str(i))
+    _assert_same_leaves((params, state), (by_hand, state_h))
+
+
+@pytest.fixture
+def no_barrier(monkeypatch):
+    """A context in which a step is traced as the parent traced it: the
+    barrier gone."""
+    import contextlib
+
+    import jax
+
+    @contextlib.contextmanager
+    def gone():
+        with monkeypatch.context() as m:
+            m.setattr(jax.lax, "optimization_barrier", lambda x: x)
+            yield
+    return gone
+
+
+def test_accumulating_step_traces_and_gives_the_parents_losses(no_barrier):
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.models import create_multistep_train_step
+
+    data = np.random.RandomState(36).randint(0, 256, (2, 2, 2, 17))  # [K, M, B, S + 1]
+    xs, ys = jnp.asarray(data[..., :-1]), jnp.asarray(data[..., 1:])
+
+    def run(held):
+        model = _tiny("llama", "float32")
+        opt = _FACTORIES["adamw"](model.parameters())
+        step, p, s = create_multistep_train_step(model, opt, steps=2,
+                                                 accumulate=2)
+        assert ("optimization_barrier" in step.lower(
+            p, s, jax.random.key(2), xs, ys, 1e-2).as_text()) == held
+        losses, p, s = step(p, s, jax.random.key(2), xs, ys, 1e-2)
+        return np.asarray(losses), (p, s)
+
+    losses, leaves = run(held=True)
+    with no_barrier():
+        losses_p, leaves_p = run(held=False)
+    np.testing.assert_allclose(losses, losses_p, rtol=1e-6)
+    _assert_same_leaves(leaves, leaves_p)
+
+
+def test_sharded_step_traces_and_gives_the_parents_losses(no_barrier):
+    """The barrier sits between a gradient's reduction over ``dp`` and its
+    update: GSPMD has to carry the leaf's sharding through it."""
+    import jax
+    from jax.sharding import Mesh
+
+    from paddle_tpu.models import create_sharded_train_step, llama_param_spec
+
+    if jax.device_count() < 8:
+        pytest.skip("needs 8 devices")
+    data = np.random.RandomState(37).randint(0, 256, (4, 17))
+    mesh = Mesh(np.array(jax.devices()[:8]).reshape(2, 4), ("dp", "tp"))
+
+    def run():
+        model = _tiny("llama", "float32")
+        opt = _FACTORIES["adamw"](model.parameters())
+        step, p, s, shard = create_sharded_train_step(model, opt, mesh,
+                                                      llama_param_spec)
+        x, y = shard(data[:, :-1]), shard(data[:, 1:])
+        losses = []
+        for _ in range(2):
+            loss, p, s = step(p, s, jax.random.key(3), x, y, 1e-2)
+            losses.append(float(loss))
+        return losses, (p, s)
+
+    losses, leaves = run()
+    with no_barrier():
+        losses_p, leaves_p = run()
+    np.testing.assert_allclose(losses, losses_p, rtol=1e-6)
+    _assert_same_leaves(leaves, leaves_p)
+    w = leaves[0]["model.layers.0.mlp.gate_proj.weight"]
+    assert w.addressable_shards[0].data.shape[1] == w.shape[1] // 4
+
+
+def test_update_plan_counts_the_leaves_held_apart_once_a_lowered_step():
+    import jax
+
+    from paddle_tpu.models import create_train_step
+    from paddle_tpu.optimizer import UPDATE_PLAN_TALLY
+    from paddle_tpu.profiler import tracing
+
+    model = _tiny("llama", "bfloat16")
+    opt = _FACTORIES["adamw"](model.parameters())
+    step, params, state = create_train_step(model, opt)
+    matrices = [v for v in params.values() if v.ndim >= 2]
+    plan = (len(params), len(matrices), sum(v.size for v in matrices),
+            sum(v.size * 2 for v in matrices))
+    assert len(matrices) < len(params)      # the norms' weights stay as they are
+    data = np.random.RandomState(38).randint(0, 256, (2, 17))
+    x, y = data[:, :-1], data[:, 1:]
+    UPDATE_PLAN_TALLY.clear()
+    tracing.reset_tracing()
+    tracing.enable_tracing()
+    try:
+        compiled = step.lower(params, state, jax.random.key(0), x, y,
+                              1e-2).compile()
+        for _ in range(3):
+            _, params, state = compiled(params, state, jax.random.key(0), x,
+                                        y, 1e-2)
+        events = [e for e in tracing.snapshot_events()
+                  if e["name"] == "optimizer::plan"]
+    finally:
+        tracing.disable_tracing()
+        tracing.reset_tracing()
+    assert dict(UPDATE_PLAN_TALLY) == {plan: 1}
+    assert [e["args"] for e in events] == [dict(zip(
+        ("leaves", "held", "held_params", "held_bytes"), plan))]
+    assert "optimization_barrier" in step.lower(
+        params, state, jax.random.key(0), x, y, 1e-2).as_text()
