@@ -17,6 +17,7 @@ input-bound or compute-bound?" in one call.
 """
 from __future__ import annotations
 
+import collections
 import queue
 import threading
 import time
@@ -47,13 +48,64 @@ class PipelineMetrics(MetricsBase):
     backpressure), producer_busy_s (pull + stack + transfer work),
     dispatch_s (the consumer's own time inside the call of the step: key
     fold, schedule, program launches; fed by ``run_steps``).
+
+    The trainer loop's own record (``run_steps``, one entry per complete
+    iteration: feed_wait, dispatch, checkpoint, the lagged fetch): the
+    histogram loop_ms (the iteration less the caller's ``on_log``),
+    callback_s (time inside ``on_log``), starved_steps / starved_by /
+    starved_s (dispatches that found the previous step already done, so
+    the device had run dry, by the host phase that took longest since
+    the previous launch, and a lower bound on the idle seconds),
+    gc_pause_s / gc_collections / gc_gen2 (Python's collector, any
+    thread), and the records of the ``SLOWEST`` longest iterations and
+    of the last ``STARVED_KEPT`` starved ones (``run_steps`` documents a
+    record's keys).
     """
 
     COUNTERS = ("batches_in", "batches_out", "stacks",
-                "producer_exceptions")
-    HISTS = ("transfer_ms", "queue_depth")
+                "producer_exceptions", "starved_steps", "gc_collections",
+                "gc_gen2")
+    HISTS = ("transfer_ms", "queue_depth", "loop_ms")
     TIMES = ("host_blocked_s", "device_blocked_s", "producer_blocked_s",
-             "producer_busy_s", "dispatch_s")
+             "producer_busy_s", "dispatch_s", "callback_s", "starved_s",
+             "gc_pause_s")
+    SLOWEST = 3
+    STARVED_KEPT = 8
+
+    def __init__(self, name: str):
+        super().__init__(name)
+        self._slowest: list = []
+        self._starved = collections.deque(maxlen=self.STARVED_KEPT)
+        self._starved_by: dict = {}
+
+    def add_iteration(self, record: dict, starved_s: float,
+                      gc: tuple) -> None:
+        """One complete iteration of ``run_steps``: its record, the
+        seconds the device stood idle in it at least, and the collector's
+        ``(pause_s, collections, gen2)`` since the last call."""
+        ms, cause = record["loop_ms"], record["starved"]
+        with self._lock:
+            self._hists["loop_ms"].observe(ms)
+            slowest = self._slowest
+            if len(slowest) < self.SLOWEST or ms > slowest[-1]["loop_ms"]:
+                slowest.append(record)
+                slowest.sort(key=lambda r: -r["loop_ms"])
+                del slowest[self.SLOWEST:]
+            if cause is not None:
+                self._counters["starved_steps"] += 1
+                self._starved_by[cause] = self._starved_by.get(cause, 0) + 1
+                self._times["starved_s"] += starved_s
+                self._starved.append(record)
+        if gc[1]:
+            self.add_gc(gc)
+
+    def add_gc(self, gc: tuple) -> None:
+        """The collector's ``(pause_s, collections, gen2)``."""
+        pause_s, n, gen2 = gc
+        with self._lock:
+            self._times["gc_pause_s"] += pause_s
+            self._counters["gc_collections"] += n
+            self._counters["gc_gen2"] += gen2
 
     def snapshot(self) -> dict:
         with self._lock:
@@ -62,6 +114,11 @@ class PipelineMetrics(MetricsBase):
             out.update({k: round(v, 6) for k, v in self._times.items()})
             for k, h in self._hists.items():
                 out[k] = h.snapshot()
+            # records are never changed once added: a shallow copy is a
+            # consistent one
+            out["slowest"] = list(self._slowest)
+            out["starved"] = list(self._starved)
+            out["starved_by"] = dict(self._starved_by)
         out["queue_depth_now"] = self._read_gauge()
         host, dev = out["host_blocked_s"], out["device_blocked_s"]
         # the one-word answer: where did the step loop actually wait?

@@ -7,6 +7,10 @@ reducers, and fused optimizer kernels.
 """
 from __future__ import annotations
 
+import logging
+import statistics
+import time
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -338,6 +342,145 @@ def restore_training_state(checkpoint_manager, params, opt_state):
     return params, opt_state, step
 
 
+_log = logging.getLogger("paddle_tpu.train")
+
+
+def _own(x, y):
+    """Seconds from mark ``x`` to mark ``y`` less the collector's pauses
+    between them."""
+    return (y[0] - x[0]) * 1e-9 - (y[1] - x[1])
+
+
+class _LoopRecord:
+    """``run_steps``' record of its own iterations, kept in the loop's
+    ``PipelineMetrics``. A mark is ``(time.time_ns(), gc pause seconds so
+    far, generation-2 collections so far)``: ``time.time_ns`` is the
+    profiler's clock (an xplane's ``profile_start_time`` + an event's
+    ``start_ns``), so a record's stamps sit on a profile's timeline.
+
+    An iteration's marks ``(a, b, c, d, e, f, g)`` open feed_wait, end it,
+    open dispatch, end it (= open checkpoint), end the checkpoint (= open
+    the fetch), end the fetch's wait on the device (= open the caller's
+    on_log) and end that; the iteration runs from the last mark of the
+    one before to ``g``. Each phase leaves out the collector's pauses,
+    which are the phase ``gc``; ``rest`` is the loop's own Python between
+    the phases."""
+
+    SLOW_RING = 32      # the slow check's median is over these last...
+    SLOW_MIN = 4        # ...once there are this many
+    SLOW_MS = 100.0     # and an iteration is slow by this much over it
+
+    def __init__(self, metrics):
+        from ..profiler.tracing import trace_span
+        self.metrics = metrics
+        self._trace_span = trace_span
+        # seconds paused, collections, generation-2 collections since the
+        # call began: written by the collector's callback alone, which may
+        # take no lock (a collection can start while one is held)
+        self._collected = [0.0, 0, 0]
+        self._gc_taken = (0.0, 0, 0)
+        self._gc_open = None
+        self.prev = None        # the last iteration's marks
+        self._ring = []
+        self._median = None
+        self._seen = 0
+
+    def mark(self):
+        acc = self._collected
+        return time.time_ns(), acc[0], acc[2]
+
+    def on_gc(self, phase, info):
+        """A ``gc.callbacks`` entry: the pause, and a ``gc::collect``
+        span on the collecting thread."""
+        if phase == "start":
+            span = self._trace_span("gc::collect", cat="gc",
+                                    generation=info["generation"])
+            self._gc_open = (span, time.perf_counter())
+            return
+        if self._gc_open is None:   # began before the callback was added
+            return
+        span, t0 = self._gc_open
+        self._gc_open = None
+        pause = time.perf_counter() - t0
+        span.set(collected=info["collected"])
+        span.end()
+        acc = self._collected
+        acc[0] += pause
+        acc[1] += 1
+        if info["generation"] == 2:
+            acc[2] += 1
+
+    def take_gc(self):
+        """The collector's ``(pause_s, collections, gen2)`` since the last
+        call."""
+        now, was = tuple(self._collected), self._gc_taken
+        self._gc_taken = now
+        return tuple(x - y for x, y in zip(now, was))
+
+    def iteration(self, step, marks, dry, overran, ready):
+        """One whole iteration: dispatch(step) and fetch(step - 1).
+        ``dry``: the previous step was done before this one was launched;
+        ``overran``: it was done right after the launch, not before;
+        ``ready``: this step's ``is_ready`` (asked only of a slow
+        iteration, at its end: done already means the device stands idle
+        while the host is still behind, not done that the device itself
+        took the time)."""
+        start = self.prev[-1]
+        a, b, c, d, e, f, g = marks
+        ms = {"feed_wait": _own(a, b), "dispatch": _own(c, d),
+              "checkpoint": _own(d, e), "fetch_wait": _own(e, f),
+              "callback": _own(f, g), "gc": g[1] - start[1],
+              "rest": _own(start, a) + _own(b, c)}
+        cause = "dispatch" if overran else None
+        if dry:
+            # the largest host phase since the previous launch ended; the
+            # fetch's wait is the device's time, not the host's
+            pd, pe, pf, pg = self.prev[3:]
+            since = {"feed_wait": ms["feed_wait"],
+                     "callback": _own(pf, pg), "gc": c[1] - pd[1],
+                     "checkpoint": _own(pd, pe),
+                     "rest": _own(pg, a) + _own(b, c)}
+            cause = max(since, key=since.get)
+        self.prev = marks
+        loop_ms = ((g[0] - start[0]) * 1e-9 - ms["callback"]) * 1e3
+        record = {"step": step, "loop_ms": loop_ms,
+                  "t_ns": {"start": start[0], "feed_wait": a[0],
+                           "dispatch": c[0], "checkpoint": d[0],
+                           "fetch_wait": e[0], "callback": f[0],
+                           "end": g[0]},
+                  "ms": {k: v * 1e3 for k, v in ms.items()},
+                  "gc_gen2": g[2] - start[2], "starved": cause}
+        self.metrics.add_iteration(
+            record, (d[0] - c[0]) * 1e-9 if dry else 0.0, self.take_gc())
+        self._check_slow(record, ready)
+
+    def _check_slow(self, record, ready):
+        """One WARNING line for an iteration over twice the median of the
+        last ``SLOW_RING`` and ``SLOW_MS`` over it. The ring is sorted only
+        when it wraps or when an iteration passes twice the last median."""
+        ms, ring = record["loop_ms"], self._ring
+        if len(ring) >= self.SLOW_MIN and (
+                self._median is None or self._seen % self.SLOW_RING == 0
+                or ms > 2 * self._median):
+            self._median = med = statistics.median(ring)
+            if ms > 2 * med and ms - med >= self.SLOW_MS:
+                _log.warning(
+                    "run_steps: step %d took %.1f ms, the median of the "
+                    "last %d %.1f ms: %s ms; generation-2 collections %d; "
+                    "the device ran dry before the launch: %s; idle at the "
+                    "end: %s", record["step"], ms, len(ring), med,
+                    ", ".join(f"{k} {v:.1f}"
+                              for k, v in record["ms"].items()),
+                    record["gc_gen2"], record["starved"] or "no",
+                    "unknown" if ready is None else
+                    "yes" if ready() else "no")
+        if len(ring) < self.SLOW_RING:
+            ring.append(ms)
+        else:
+            ring[self._seen % self.SLOW_RING] = ms
+        self._seen += 1
+
+
 def run_steps(step, params, opt_state, feed, *, key=None, lr=1e-3,
               log_every=0, on_log=None, name=None, start_step=0,
               checkpoint_manager=None, on_fault=None):
@@ -370,6 +513,26 @@ def run_steps(step, params, opt_state, feed, *, key=None, lr=1e-3,
     pipeline); otherwise a fresh source named ``name`` (default
     ``"run_steps"``) is registered for the duration of the run.
 
+    The loop also records itself there, always on. Each whole iteration
+    (feed_wait, dispatch and checkpoint of step ``i``, fetch of ``i-1``)
+    is stamped with ``time.time_ns()``, the profiler's clock, into a
+    record: ``step``, ``loop_ms`` (the iteration less the caller's
+    ``on_log``), ``t_ns`` (where each phase began, and ``end``), ``ms``
+    (feed_wait, dispatch, checkpoint, fetch_wait, callback, gc, rest),
+    ``gc_gen2`` and ``starved``. ``loop_ms`` goes to a histogram, the
+    three longest records to ``slowest``. Right before and after each
+    launch the loop asks the previous loss ``is_ready()``: done before
+    the launch, the device had nothing queued, so it waited on the host
+    (``starved_steps``, ``starved_s``, and ``starved_by`` the largest
+    host phase since the previous launch: feed_wait, callback, gc,
+    checkpoint or rest; done only after it, ``dispatch``); the last
+    eight such records are kept in ``starved``. For the call's length a
+    ``gc.callbacks`` entry counts Python's collections (``gc_pause_s``,
+    ``gc_collections``, ``gc_gen2``) and spans each as ``gc::collect``.
+    An iteration over twice the median of the last 32 and 100 ms over
+    it logs one WARNING on ``logging.getLogger("paddle_tpu.train")``
+    with its phases.
+
     Preemption tolerance (``distributed.resilience``): with
     ``checkpoint_manager=`` the loop calls ``maybe_save(i, state)``
     after dispatching step ``i`` with the post-step trees under
@@ -388,7 +551,7 @@ def run_steps(step, params, opt_state, feed, *, key=None, lr=1e-3,
     start+1, ...``; ``start_step`` offsets the whole run (resuming a
     previous process at the step after its restored checkpoint).
     """
-    import time
+    import gc
 
     from ..io.prefetch import DevicePrefetcher, PipelineMetrics
     from ..profiler import tracing
@@ -416,23 +579,34 @@ def run_steps(step, params, opt_state, feed, *, key=None, lr=1e-3,
 
     losses = []
     pending = None
+    loop = _LoopRecord(metrics)
+    mark = loop.mark
 
-    def fetch(val, i):
-        t0 = time.perf_counter()
+    def fetch(val, i, start):
+        # -> the marks where the wait for the device and the caller's
+        # on_log end
         with tracing.trace_span("train::fetch", cat="train", step=i):
             got = jax.device_get(val)
-        metrics.add_time("device_blocked_s", time.perf_counter() - t0)
+        waited = mark()
+        metrics.add_time("device_blocked_s", (waited[0] - start[0]) * 1e-9)
         losses.append(got)
-        if log_every and on_log is not None and i % log_every == 0:
+        if not (log_every and on_log is not None and i % log_every == 0):
+            return waited, waited
+        with tracing.trace_span("train::callback", cat="train", step=i):
             on_log(i, got)
+        called = mark()
+        metrics.add_time("callback_s", (called[0] - waited[0]) * 1e-9)
+        return waited, called
 
     i0 = start_step
+    on_gc = loop.on_gc
+    gc.callbacks.append(on_gc)
     try:
         it = iter(feed(i0) if feed_is_factory else feed)
         i = i0
         while True:
             try:
-                t0 = time.perf_counter()
+                a = mark()
                 # span handle, not a with-block: a StopIteration break
                 # drops it unrecorded instead of logging a bogus wait
                 feed_span = tracing.trace_span("train::feed_wait",
@@ -443,30 +617,47 @@ def run_steps(step, params, opt_state, feed, *, key=None, lr=1e-3,
                     feed_span.drop()
                     break
                 feed_span.end()
+                b = mark()
                 if owns_metrics:
-                    metrics.add_time("host_blocked_s",
-                                     time.perf_counter() - t0)
+                    metrics.add_time("host_blocked_s", (b[0] - a[0]) * 1e-9)
                     metrics.inc("batches_out")
                 ids, labels = batch
+                # the previous step already done before this one is
+                # launched: the device has nothing queued, it waits on
+                # the host (is_ready does not block)
+                ready = getattr(pending, "is_ready", None)
+                dry = ready is not None and ready()
                 # dispatch_s: the host's own work a step (the key fold,
                 # the schedule, flattening the trees, the launches),
                 # always on like host_blocked_s and device_blocked_s
-                t0 = time.perf_counter()
+                c = mark()
                 with tracing.trace_step("train::dispatch", i, cat="train"):
                     loss, params, opt_state = step(
                         params, opt_state, jax.random.fold_in(key, i),
                         ids, labels, lr_fn(i))
-                metrics.add_time("dispatch_s", time.perf_counter() - t0)
+                d = mark()
+                metrics.add_time("dispatch_s", (d[0] - c[0]) * 1e-9)
+                # done now and not before: the launch itself outran the
+                # device
+                overran = ready is not None and not dry and ready()
+                e = d
                 if checkpoint_manager is not None:
-                    checkpoint_manager.maybe_save(
-                        i, {"params": params, "opt_state": opt_state,
-                            "step": i})
+                    with tracing.trace_span("train::checkpoint", cat="train",
+                                            step=i):
+                        checkpoint_manager.maybe_save(
+                            i, {"params": params, "opt_state": opt_state,
+                                "step": i})
+                    e = mark()
                 if pending is not None:
-                    fetch(pending, i - 1)
+                    f, g = fetch(pending, i - 1, e)
+                    loop.iteration(i, (a, b, c, d, e, f, g), dry, overran,
+                                   getattr(loss, "is_ready", None))
+                else:       # no fetch: not a whole iteration
+                    loop.prev = (a, b, c, d, e, e, e)
                 pending = loss
                 i += 1
-            except recoverable as e:
-                recovered = on_fault(e, i)
+            except recoverable as exc:
+                recovered = on_fault(exc, i)
                 if recovered is None:
                     raise
                 params, opt_state, resume = recovered
@@ -474,16 +665,19 @@ def run_steps(step, params, opt_state, feed, *, key=None, lr=1e-3,
                     # the lagged loss of step i-1 is BEFORE the resume
                     # point: part of the kept trajectory, fetch it (its
                     # step completed; the fault hit a later boundary)
-                    fetch(pending, i - 1)
+                    fetch(pending, i - 1, mark())
                 del losses[max(0, resume - i0):]
                 pending = None
+                loop.prev = None
                 i = int(resume)
                 it = iter(feed(i))
                 if checkpoint_manager is not None:
                     checkpoint_manager.record_restart()
         if pending is not None:
-            fetch(pending, i - 1)
+            fetch(pending, i - 1, mark())
     finally:
+        gc.callbacks.remove(on_gc)
+        metrics.add_gc(loop.take_gc())
         if owns_metrics:
             from .. import profiler
             profiler.unregister_pipeline_source(metrics.name, metrics)

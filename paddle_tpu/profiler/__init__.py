@@ -677,28 +677,3 @@ class SortedKeys:
     GPUAvg = 5
     GPUMax = 6
     GPUMin = 7
-
-
-def export_protobuf(dir_name=None, worker_name=None):
-    """Return an on-trace-ready handler that dumps the profile in a
-    serialized form next to the chrome trace (parity:
-    paddle.profiler.export_protobuf; this build serializes the collected
-    host events with pickle — the reference's .pb payload is its own
-    proto)."""
-    import os
-    import pickle
-    import socket
-    import time
-
-    def handle(prof):
-        d = dir_name or "./profiler_log"
-        os.makedirs(d, exist_ok=True)
-        worker = worker_name or \
-            f"host_{socket.gethostname()}_{os.getpid()}"
-        path = os.path.join(d, f"{worker}_{int(time.time())}.pb.pkl")
-        events = getattr(prof, "_events", [])
-        with open(path, "wb") as f:
-            pickle.dump([e.__dict__ if hasattr(e, "__dict__") else e
-                         for e in events], f)
-        prof._last_protobuf_path = path
-    return handle

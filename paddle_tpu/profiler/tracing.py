@@ -227,6 +227,14 @@ class _Span:
         self.end()
         return False
 
+    def set(self, **attrs) -> None:
+        """Add attributes known only once the span is under way (a
+        collection's ``collected``): to the open annotation's stats and
+        to the record ``end`` makes."""
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
+        self.attrs.update(attrs)
+
     def drop(self) -> None:
         """Close without recording: for a handle whose interval turned
         out not to be one (the feed's StopIteration, a failover that

@@ -3,6 +3,8 @@ trainer.run_steps, profiler.pipeline_stats, place_by_spec fallback
 visibility. Oracles: the async pipeline must be the SAME math as the
 synchronous loop (ordering determinism + loss parity), with the overlap
 machinery observable through the profiler registry."""
+import gc
+import logging
 import threading
 import time
 
@@ -283,6 +285,149 @@ class TestRunSteps:
                       "run_steps" in profiler.pipeline_stats()))
         assert during and all(during)
         assert "run_steps" not in profiler.pipeline_stats()
+
+
+class _Loss:
+    """A loss whose readiness the test decides: done from the start
+    (``ready=True``: by the next launch the device has run dry), or done
+    only once ``device_get`` asks for it (no launch ever finds it done)."""
+
+    def __init__(self, ready):
+        self.ready = ready
+
+    def is_ready(self):
+        return self.ready
+
+    def copy_to_host_async(self):       # jax.device_get calls it first
+        self.ready = True
+
+
+def _stub_run(monkeypatch, n=10, ready=True, on_log=None, feed_sleep=None):
+    """``run_steps`` over a step that launches nothing (it returns a
+    ``_Loss``) and a plain feed; ``feed_sleep=(i, s)`` sleeps before batch
+    i. Returns the loop's own source's last snapshot."""
+    def step(params, opt_state, key, ids, labels, lr):
+        return _Loss(ready), params, opt_state
+
+    def feed():
+        for i, b in enumerate(_batches(n)):
+            if feed_sleep and i == feed_sleep[0]:
+                time.sleep(feed_sleep[1])
+            yield b
+
+    kept = {}
+    orig = profiler.unregister_pipeline_source
+
+    def keep(name, metrics=None):
+        kept["snap"] = metrics.snapshot()
+        orig(name, metrics)
+    monkeypatch.setattr(profiler, "unregister_pipeline_source", keep)
+    run_steps(step, {}, {}, feed(), log_every=1 if on_log else 0,
+              on_log=on_log)
+    return kept["snap"]
+
+
+def _record(snap, step):
+    return next(r for r in snap["starved"] + snap["slowest"]
+                if r["step"] == step)
+
+
+def _sleep_on(at, s):
+    return lambda i, loss: time.sleep(s) if i == at else None
+
+
+def _check_callback(snap, logged):
+    # on_log(5) runs in iteration 6 (dispatch 6, fetch 5): its time is the
+    # record's callback and no part of loop_ms; dispatch 7 found the
+    # device dry because of it
+    assert _record(snap, 6)["ms"]["callback"] >= 45
+    assert snap["loop_ms"]["max"] < 45 and snap["callback_s"] >= 0.045
+    assert _record(snap, 7)["starved"] == "callback"
+    assert snap["starved_by"]["callback"] >= 1
+
+
+def _check_feed(snap, logged):
+    rec = _record(snap, 5)
+    assert rec["ms"]["feed_wait"] >= 45 and rec["loop_ms"] >= 45
+    assert rec["starved"] == "feed_wait"
+    assert snap["starved_by"]["feed_wait"] >= 1
+
+
+def _check_never_ready(snap, logged):
+    assert snap["starved_steps"] == 0 and snap["starved_by"] == {}
+    assert snap["starved"] == [] and snap["starved_s"] == 0.0
+    # every dispatch after the first fetched one whole iteration
+    assert snap["loop_ms"]["count"] == 9
+
+
+def _check_always_ready(snap, logged):
+    assert snap["starved_steps"] == 9          # every dispatch but the first
+    assert sum(snap["starved_by"].values()) == 9
+    assert [r["step"] for r in snap["starved"]] == list(range(2, 10))
+
+
+def _check_gc(snap, logged):
+    assert snap["gc_collections"] >= 1 and snap["gc_gen2"] >= 1
+    assert snap["gc_pause_s"] > 0
+    rec = _record(snap, 6)
+    assert rec["gc_gen2"] >= 1 and rec["ms"]["gc"] > 0
+    # the pause is the gc phase, not the callback's
+    assert rec["ms"]["callback"] < rec["ms"]["gc"] + 1.0
+
+
+def _check_slow_warning(snap, logged):
+    (line,) = logged
+    assert "step 8 took" in line and "feed_wait 3" in line
+
+
+def _check_steady_no_warning(snap, logged):
+    assert logged == []
+
+
+_LOOP_CASES = {
+    "callback_sleeps": (dict(on_log=_sleep_on(5, 0.05)), _check_callback),
+    "feed_sleeps": (dict(feed_sleep=(5, 0.05)), _check_feed),
+    "never_ready": (dict(ready=False, on_log=_sleep_on(5, 0.05)),
+                    _check_never_ready),
+    "always_ready": (dict(), _check_always_ready),
+    "gc_in_on_log": (dict(on_log=lambda i, v: gc.collect() if i == 5
+                          else None), _check_gc),
+    "slow_iteration_warns": (dict(ready=False, feed_sleep=(8, 0.3)),
+                             _check_slow_warning),
+    "steady_run_is_silent": (dict(ready=False, n=40),
+                             _check_steady_no_warning),
+}
+
+
+class TestLoopRecord:
+    """``run_steps``' record of its own iterations (ISSUE 36): phases,
+    the device run dry and why, the collector, the slow-iteration line.
+    Readiness is the stub's, never the clock's."""
+
+    @pytest.mark.parametrize("case", sorted(_LOOP_CASES))
+    def test_the_loop_records_each_iteration(self, monkeypatch, caplog,
+                                             case):
+        kwargs, check = _LOOP_CASES[case]
+        with caplog.at_level(logging.WARNING, logger="paddle_tpu.train"):
+            snap = _stub_run(monkeypatch, **kwargs)
+        assert snap["loop_ms"]["count"] == kwargs.get("n", 10) - 1
+        check(snap, [r.getMessage() for r in caplog.records
+                     if r.name == "paddle_tpu.train"])
+
+    @pytest.mark.parametrize("raises", [False, True])
+    def test_the_gc_callback_is_gone_after_the_call(self, raises):
+        before = list(gc.callbacks)
+
+        def step(params, opt_state, key, ids, labels, lr):
+            if raises:
+                raise RuntimeError("planted")
+            return _Loss(True), params, opt_state
+        if raises:
+            with pytest.raises(RuntimeError, match="planted"):
+                run_steps(step, {}, {}, _batches(3))
+        else:
+            run_steps(step, {}, {}, _batches(3))
+        assert gc.callbacks == before
 
 
 class TestPipelineStats:

@@ -9,6 +9,7 @@ No sleeps and no thread races: the traced session is one module fixture
 (starting a trace costs seconds) and everything else is arithmetic on plain
 lists or a look at lowered text.
 """
+import gc
 import json
 import os
 import sys
@@ -60,8 +61,10 @@ def _clean():
 @pytest.fixture(scope="module")
 def session(tmp_path_factory):
     """ONE profiler session with every kind of span in it. Returns the host
-    plane as ``{name: [stats dict, ...]}``, what the ring held, and what
-    the recording Profiler held."""
+    plane as ``{name: [stats dict, ...]}`` (each with its ``start_ns`` and
+    ``duration_ns``), the profile's origin on ``time.time_ns``, what the
+    ring held, what the recording Profiler held, and the feed's snapshot
+    of a ``run_steps`` whose ``on_log`` collects garbage once."""
     _run_toy(_batches(2))               # compile outside the session
     d = str(tmp_path_factory.mktemp("xplane"))
     tracing.reset_tracing()
@@ -81,10 +84,18 @@ def session(tmp_path_factory):
             ev = RecordEvent("clock::begin_end")
             ev.begin()
             ev.end()
+        feed = prefetch_to_device(_batches(3), depth=2, name="clock_loop")
+        try:
+            models.run_steps(
+                _toy_step, {"w": jnp.ones((4,))}, {}, feed, lr=0.1,
+                log_every=1,
+                on_log=lambda i, v: gc.collect() if i == 1 else None)
+            loop = feed.metrics.snapshot()
+        finally:
+            feed.close()
         tracing.disable_tracing()       # ring off, annotation still there
         with tracing.trace_span("clock::ring_off", cat="t"):
             pass
-        _run_toy(_batches(3))
     finally:
         jax.profiler.stop_trace()
     ring = tracing.snapshot_events()
@@ -99,9 +110,11 @@ def session(tmp_path_factory):
             for e in line.events:
                 if trace_gaps.PROGRAM_SPAN.match(e.name):
                     host.setdefault(e.name, []).append(
-                        dict(e.stats, duration_ns=e.duration_ns))
-    return {"dir": d, "host": host, "ring": ring, "profiler": prof.events,
-            "dropped": handle}
+                        dict(e.stats, start_ns=e.start_ns,
+                             duration_ns=e.duration_ns))
+    return {"dir": d, "host": host, "origin_ns": trace_gaps.origin_ns(data),
+            "ring": ring, "profiler": prof.events, "dropped": handle,
+            "loop": loop}
 
 
 # -- the profiler's clock ----------------------------------------------------
@@ -156,6 +169,33 @@ def test_run_steps_spans_are_on_the_profilers_clock(session):
     assert [s["step"] for s in host["train::fetch"]] == [0, 1, 2]
     # the fourth feed_wait met StopIteration: dropped, yet a whole event
     assert [s["step"] for s in host["train::feed_wait"]] == [0, 1, 2, 3]
+    # the caller's on_log, timed apart from the loop's own phases
+    assert [s["step"] for s in host["train::callback"]] == [0, 1, 2]
+
+
+def test_loop_record_stamps_are_on_the_profilers_clock(session):
+    """``profile_start_time + start_ns`` is ``time.time_ns()``: each whole
+    iteration's dispatch stamp lies within 1 ms of its ``train::dispatch``
+    span (dispatch 0 fetched nothing, so it has no record)."""
+    origin = session["origin_ns"]
+    starts = {s["step"]: s["start_ns"]
+              for s in session["host"]["train::dispatch"]}
+    records = session["loop"]["slowest"]
+    assert origin is not None
+    assert sorted(r["step"] for r in records) == [1, 2]
+    for r in records:
+        assert abs(origin + starts[r["step"]] - r["t_ns"]["dispatch"]) < 1e6
+
+
+def test_a_collection_is_counted_and_spanned(session):
+    loop = session["loop"]
+    assert loop["gc_collections"] >= 1 and loop["gc_gen2"] >= 1
+    assert loop["gc_pause_s"] > 0
+    # on_log(1)'s gc.collect(): generation 2, with what it collected
+    assert any(s["generation"] == 2 and s["collected"] >= 0
+               for s in session["host"]["gc::collect"])
+    assert any(e["args"]["generation"] == 2 and "collected" in e["args"]
+               for e in session["ring"] if e["name"] == "gc::collect")
 
 
 def test_trace_gaps_loads_the_program_spans_of_a_real_profile(session):

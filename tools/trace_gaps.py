@@ -36,8 +36,8 @@ import os
 import re
 import sys
 
-__all__ = ["load", "step_window", "device_gaps", "open_spans", "attribute",
-           "report", "main"]
+__all__ = ["load", "origin_ns", "step_window", "device_gaps", "open_spans",
+           "attribute", "report", "main"]
 
 MODULE_LINE = "XLA Modules"
 OP_LINE = "XLA Ops"
@@ -54,6 +54,18 @@ def find_xplane(path: str) -> str:
     if not found:
         raise FileNotFoundError(f"no .xplane.pb under {path}")
     return found[-1]
+
+
+def origin_ns(data) -> int | None:
+    """The profile's origin on ``time.time_ns()``'s clock: the stat
+    ``profile_start_time`` of the ``Task Environment`` plane of a
+    ``jax.profiler.ProfileData``. ``origin + start_ns`` puts an event on
+    the clock of the program's own stamps (``run_steps``' records)."""
+    for plane in data.planes:
+        if plane.name == "Task Environment":
+            t = dict(plane.stats).get("profile_start_time")
+            return None if t is None else int(t)
+    return None
 
 
 def load(path: str) -> dict:
