@@ -192,7 +192,7 @@ class MLAttention(nn.Layer):
             size = jnp.dtype(q.dtype).itemsize
             tiles = " ".join(
                 f"{name}={t.bq}x{t.bk}/{t.sub_q}x{t.sub_k}" for name, t in
-                tile_plan(s, s, d, size, size, size)._asdict().items())
+                tile_plan(s, s, d, size, size, size).lowered().items())
         MLA_PLAN_TALLY[(heads, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
                         cfg.v_head_dim, cfg.q_lora_rank, cfg.kv_lora_rank,
                         b * s, route.impl)] += 1

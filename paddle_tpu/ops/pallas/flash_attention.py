@@ -21,8 +21,8 @@ Design (online-softmax, Dao et al. 2022, re-derived for the MXU):
   scoped VMEM asks for what it needs (``_compiler_params``). Explicit
   block arguments win over the plan. Every call leaves a
   ``flash::tile_plan`` trace event and a count in ``TILE_PLAN_TALLY``; the
-  three Mosaic calls are jitted on their own, so a model's equal layers
-  share one trace and one lowering of each.
+  Mosaic calls are jitted on their own, so a model's equal layers share
+  one trace and one lowering of each.
 - sub-tiles: a grid step does not compute a (block_q, block_k) tile in one
   piece where an edge of what attention may see crosses it. It walks it in
   the plan's (sub_q, sub_k) sub-tiles, static slices of the refs it already
@@ -41,11 +41,16 @@ Design (online-softmax, Dao et al. 2022, re-derived for the MXU):
   (acc, m, l) across k blocks; causal blocks above the diagonal are skipped
   with pl.when, and their index maps are clamped at the diagonal, so a
   skipped step asks for the block it already holds and moves no data.
-- backward: one kernel for dq (+ dbias when bias is given), one for dk/dv
-  (grid (batch*kv_heads, k_blocks, group_heads, q_blocks) — the last two
-  dims sweep the kv head's q-head group with affine index maps);
-  recomputes p from q,k and the saved lse instead of storing the S×S
-  probability matrix.
+- backward: recomputes p from q,k and the saved lse instead of storing the
+  S×S probability matrix. One kernel (``flash_bwd``) on the grid
+  (batch*kv_heads, k_blocks, group_heads, q_blocks) — the last two dims
+  sweep the kv head's q-head group with affine index maps — computes each
+  visible piece's scores, p, dp and ds once for dk, dv and dq: dk/dv
+  accumulate per key block, dq in float32 for all the group's q rows
+  across the sweep, leaving by DMA when a query block's last key block is
+  done. Where that accumulator does not fit, or a full bias asks for dbias
+  tiles, the call keeps two kernels: one for dq (+ dbias) on the forward's
+  grid, one for dk/dv on that grid (``backward_rule``).
 - GQA is expressed in the BlockSpec index maps (kv block index derived from
   the q head index), so kv tensors are never materialised per-q-head in
   the forward; the dkv kernel accumulates dk/dv over the group's q-heads
@@ -66,7 +71,7 @@ from __future__ import annotations
 import collections
 import functools
 import math
-from typing import NamedTuple, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -93,11 +98,11 @@ _F1 = np.float32(1.0)
 _LANES = 128
 # the names the device trace finds the kernels by (benchmarks/metrics)
 _KERNEL_NAMES = {"fwd": "flash_fwd", "dq": "flash_bwd_dq",
-                 "dkv": "flash_bwd_dkv"}
+                 "dkv": "flash_bwd_dkv", "bwd": "flash_bwd"}
 # a call with a window is another kernel to the trace's readers
 # (benchmarks/metrics/window_attn_roofline.py)
 _WIN_KERNEL_NAMES = {"fwd": "flash_win_fwd", "dq": "flash_win_bwd_dq",
-                     "dkv": "flash_win_bwd_dkv"}
+                     "dkv": "flash_win_bwd_dkv", "bwd": "flash_win_bwd"}
 # one count per lowered pallas_call, by (kernel name, bq, bk, sub_q, sub_k):
 # trace time only, nothing per step
 TILE_PLAN_TALLY: collections.Counter = collections.Counter()
@@ -616,6 +621,14 @@ def _fwd_kernel(*refs, scale, causal, offset, bq, bk, sub_q, sub_k, kinds,
                                _NEG_INF)
 
 
+def _last_key_block(qi, bq, bk, offset, nk):
+    """The last of the ``nk`` key blocks that query block ``qi`` sees
+    causally (block 0 where it sees none): traced, all i32."""
+    return jnp.minimum(
+        jnp.maximum(qi * np.int32(bq) + np.int32(bq - 1 + offset),
+                    np.int32(0)) // np.int32(bk), np.int32(nk - 1))
+
+
 def _key_block(causal, bq, bk, offset, nk, window=None, nq=None):
     """``kblk(qi, ki)``: the key block step (qi, ki) of the fwd/dq grid
     asks for. A causally skipped step asks for the last block its query
@@ -627,12 +640,8 @@ def _key_block(causal, bq, bk, offset, nk, window=None, nq=None):
     if window is not None:
         k_first, k_last, _, _ = _band(bq, bk, offset, window, nq, nk)
         return lambda qi, kj: jnp.minimum(k_first(qi) + kj, k_last(qi))
-
-    def kblk(qi, ki):
-        last = jnp.maximum(qi * np.int32(bq) + np.int32(bq - 1 + offset),
-                           np.int32(0)) // np.int32(bk)
-        return jnp.minimum(ki, jnp.minimum(last, np.int32(nk - 1)))
-    return kblk
+    return lambda qi, ki: jnp.minimum(
+        ki, _last_key_block(qi, bq, bk, offset, nk))
 
 
 def _query_block(causal, bq, bk, offset, nq, window=None, nk=None):
@@ -671,7 +680,7 @@ def _sub_tile_counts(kernel, bq, bk, sub_q, sub_k, nq, nk, *, causal, offset,
             entered, plain = entered + t_hi - t_lo, plain + t_b - t_a
             continue
         for ki in range(t_lo, t_hi):
-            if kernel == "dkv":
+            if kernel in ("dkv", "bwd"):
                 walks = (_query_subs(ki * bk + j * sub_k, sub_k, qi * bq,
                                      sub_q, bq // sub_q, **edges)
                          for j in range(bk // sub_k))
@@ -684,12 +693,20 @@ def _sub_tile_counts(kernel, bq, bk, sub_q, sub_k, nq, nk, *, causal, offset,
     return entered, entered if segments else entered - plain
 
 
+def _dq_rows(kernel, q3, kx):
+    """The q rows whose dq ``kernel`` holds at once: all the kv head's
+    (rep x Sq) in the one backward kernel, none elsewhere."""
+    return (q3.shape[0] // kx.shape[0]) * q3.shape[1] if kernel == "bwd" \
+        else 0
+
+
 def _call_vmem(kernel, q3, kx, vx, bias3, dbias, has_seg, rate, tile):
     return _vmem_bytes(kernel, tile.bq, tile.bk, q3.shape[2],
                        q3.dtype.itemsize, kx.dtype.itemsize,
                        vx.dtype.itemsize,
                        bias3.dtype.itemsize if bias3 is not None else 0,
-                       dbias, has_seg, rate > 0.0)
+                       dbias, has_seg, rate > 0.0,
+                       dq_rows=_dq_rows(kernel, q3, kx))
 
 
 def _compiler_params(semantics, vmem):
@@ -705,11 +722,14 @@ def _compiler_params(semantics, vmem):
 
 
 def _record_call(kernel, q3, kx, vx, bias3, dbias, has_seg, rate, tile,
-                 offset, causal, sk_real, window=None):
-    """One call of ``kernel`` ("fwd", "dq", "dkv") on its way to be lowered:
-    a ``flash::tile_plan`` trace event and a count in ``TILE_PLAN_TALLY``.
-    Python at trace time, outside the jitted call, so every call counts
-    though equal calls share one lowering."""
+                 offset, causal, sk_real, window=None, split_by=None):
+    """One call of ``kernel`` ("fwd", "dq", "dkv", "bwd") on its way to be
+    lowered: a ``flash::tile_plan`` trace event and a count in
+    ``TILE_PLAN_TALLY``. Python at trace time, outside the jitted call, so
+    every call counts though equal calls share one lowering. The one
+    backward kernel's event carries ``dq_resident_bytes``, its float32 dq
+    accumulator; the split kernels' carry ``split_by``, why the call did
+    not take it."""
     from ...profiler.tracing import trace_event
     bq, bk, sub_q, sub_k, _ = tile
     bhq, sq, _ = q3.shape
@@ -727,6 +747,11 @@ def _record_call(kernel, q3, kx, vx, bias3, dbias, has_seg, rate, tile,
                  sub_tiles_masked=bhq * masked,
                  vmem_bytes=_call_vmem(kernel, q3, kx, vx, bias3, dbias,
                                        has_seg, rate, tile))
+    if kernel == "bwd":
+        attrs["dq_resident_bytes"] = _dq_acc_bytes(_dq_rows(kernel, q3, kx),
+                                                   q3.shape[2])
+    elif kernel in ("dq", "dkv"):
+        attrs["split_by"] = split_by
     if window is None:
         name = _KERNEL_NAMES[kernel]
         attrs.update(grid_steps=bhq * nq * nk, skipped_steps=bhq * skipped)
@@ -736,7 +761,7 @@ def _record_call(kernel, q3, kx, vx, bias3, dbias, has_seg, rate, tile,
         # are skipped in the kernel
         k_first, k_last, q_first, q_last = _band(bq, bk, offset, window, nq,
                                                  nk, py=True)
-        if kernel == "dkv":
+        if kernel in ("dkv", "bwd"):
             steps = nk * _band_extent(q_first, q_last, nk)
             run = sum(max(q_last(i) - q_first(i) + 1, 0) for i in range(nk))
         else:
@@ -1012,7 +1037,8 @@ def _dq_kernel(*refs, scale, causal, offset, bq, bk, sub_q, sub_k, kinds,
 
 def _dkv_kernel(*refs, scale, causal, offset, bq, bk, sub_q, sub_k, kinds, nq,
                 nk_grid, rep, sk_real, pad_keys, has_bias, has_seg,
-                seg_causal, rate, window=None, nq_all=None, nk_all=None):
+                seg_causal, rate, with_dq=False, window=None, nq_all=None,
+                nk_all=None):
     """Grid (B*Hk, nk, rep, nq): one kv-head block accumulates dk/dv over
     ALL rep q-heads of its group (GQA-native — no rep-expanded K/V in HBM
     and no post-kernel sum over q-head groups). rep == 1 is plain MHA.
@@ -1021,7 +1047,14 @@ def _dkv_kernel(*refs, scale, causal, offset, bq, bk, sub_q, sub_k, kinds, nq,
     blocks Mosaic's cross-iteration DMA pipelining (suspected cause of
     the r3 GQA fwd_bwd 0.837; on-chip recapture verifies). A tile is
     walked the other way round from fwd and dq: for each ``sub_k`` keys,
-    the query rows that see them."""
+    the query rows that see them.
+
+    ``with_dq`` makes it the one backward kernel (``flash_bwd``): each
+    piece's ``ds`` enters dq too, summed in float32 for all the kv head's
+    q rows (``dq_acc``, (rep, Sq, D)) over the whole sweep. A query block's
+    dq is final at the last key block it sees, and leaves then, in q's
+    dtype, by a DMA to the output in HBM that the next write (or the kv
+    head's last step) waits for."""
     scale = np.float32(scale)  # strong f64 scalars poison Mosaic under x64
     it = iter(refs)
     q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref = (
@@ -1031,7 +1064,11 @@ def _dkv_kernel(*refs, scale, causal, offset, bq, bk, sub_q, sub_k, kinds, nq,
     kseg_ref = next(it) if has_seg else None
     seed_ref = next(it) if rate > 0.0 else None
     dk_ref, dv_ref = next(it), next(it)
+    dq_hbm = next(it) if with_dq else None
     dk_acc, dv_acc = next(it), next(it)
+    if with_dq:
+        dq_acc, dq_out, dq_sem, dq_pending = (next(it), next(it), next(it),
+                                              next(it))
 
     ki = pl.program_id(1)
     r = pl.program_id(2)                  # q-head within the kv group
@@ -1049,6 +1086,27 @@ def _dkv_kernel(*refs, scale, causal, offset, bq, bk, sub_q, sub_k, kinds, nq,
     def _init():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    if with_dq:
+        @pl.when(jnp.logical_and(ki == 0, jnp.logical_and(r == 0, qj == 0)))
+        def _init_dq():
+            dq_acc[...] = jnp.zeros_like(dq_acc)
+            dq_pending[0] = np.int32(0)
+
+        def dq_rows(r0, n):
+            start = q_start + np.int32(r0)
+            return pl.ds(pl.multiple_of(start, 8) if bq % 8 == 0 else start,
+                         n)
+
+        def dq_copy(start):
+            return pltpu.make_async_copy(
+                dq_out, dq_hbm.at[bh, pl.ds(start, bq), :], dq_sem)
+
+        def dq_wait():
+            @pl.when(dq_pending[0] == 1)
+            def _():
+                dq_copy(np.int32(0)).wait()
+                dq_pending[0] = np.int32(0)
 
     def attend(rows, cols, hide):
         """dk and dv of the keys ``cols`` from the query rows ``rows`` of
@@ -1086,15 +1144,44 @@ def _dkv_kernel(*refs, scale, causal, offset, bq, bk, sub_q, sub_k, kinds, nq,
         ds = p * (dp - delta_ref[0, rows, :])
         # s = scale * (q . k) with q unscaled on load, so dk = scale *
         # ds^T @ q carries the factor explicitly
+        ds_q = ds.astype(q.dtype)
         dk_acc[cols, :] += jax.lax.dot_general(
-            ds.astype(q.dtype), q, (((0,), (0,)), ((), ())),
+            ds_q, q, (((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32) * scale          # (sk, d)
+        if with_dq:     # the dq kernel's product, of the same ds
+            r0, r1 = rows.start or 0, bq if rows.stop is None else rows.stop
+            dq_acc[r, dq_rows(r0, r1 - r0), :] += jax.lax.dot(
+                ds_q if k.dtype == q.dtype else ds.astype(k.dtype), k,
+                preferred_element_type=jnp.float32) * scale      # (sq, d)
 
     _attend_tile(
         attend, "q", kinds, q_start, k_start, ki, qseg_ref, kseg_ref, bq=bq,
         bk=bk, sub_q=sub_q, sub_k=sub_k, nk_all=nk_grid, pad_keys=pad_keys,
         causal=causal, offset=offset, sk_real=sk_real, window=window,
         inside=inside, seg_causal=seg_causal)
+
+    if with_dq:
+        # the query block's last key block: every key block when not causal
+        done = ki == np.int32(nk_grid - 1)
+        if causal:
+            done = ki == _last_key_block(qi, bq, bk, offset, nk_grid)
+        if window is not None:
+            done = jnp.logical_and(done, inside)
+
+        @pl.when(done)
+        def _write_dq():
+            dq_wait()
+            # a head under 128 lanes leaves in whole lanes, the rest unread
+            d = dq_acc.shape[2]
+            dq_out[:, :d] = dq_acc[r, dq_rows(0, bq), :].astype(dq_out.dtype)
+            dq_copy(q_start).start()
+            dq_pending[0] = np.int32(1)
+
+        @pl.when(jnp.logical_and(
+            ki == np.int32(nk_grid - 1), jnp.logical_and(
+                r == np.int32(rep - 1), qj == np.int32(nq - 1))))
+        def _drain():
+            dq_wait()
 
     @pl.when(jnp.logical_and(r == np.int32(rep - 1),
                              qj == np.int32(nq - 1)))
@@ -1185,15 +1272,17 @@ def _bwd_dq(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
     return dq_outs if emit_dbias else (dq_outs, None)
 
 
-@functools.partial(jax.jit, static_argnames=_CALL_STATICS)
+@functools.partial(jax.jit, static_argnames=_CALL_STATICS + ("with_dq",))
 def _bwd_dkv(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
              offset, sk_real, tile, bias_maps, interpret, qseg3, kseg3,
-             hq, hk, window=None):
+             hq, hk, window=None, with_dq=False):
     """dk/dv per KV head on the (kv-head, k-block, r, qi) grid: the
     (q-head-of-group, q-block) sweep as two AFFINE dims, all i32 (index
     maps lower through Mosaic), accumulated in-grid over the group's
-    q-heads (no per-q-head dk/dv in HBM)."""
-    _, sq, d = q3.shape
+    q-heads (no per-q-head dk/dv in HBM). ``with_dq``: the one backward
+    kernel, which returns dq too (``_dkv_kernel``); its dq accumulator
+    lives across the key blocks, so only the kv head's axis is parallel."""
+    bhq, sq, d = q3.shape
     bhk, sk = kx.shape[0], kx.shape[1]
     rep = hq // hk
     bq, bk, sub_q, sub_k, kinds = tile
@@ -1238,7 +1327,32 @@ def _bwd_dkv(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
             (1,), lambda bh, ki, r, qi: (_Z,), memory_space=pltpu.SMEM))
         kq_args.append(seed)
 
-    return pl.pallas_call(
+    out_specs = [
+        pl.BlockSpec((1, bk, d), lambda bh, ki, r, qi: (bh, ki, _Z)),
+        pl.BlockSpec((1, bk, d), lambda bh, ki, r, qi: (bh, ki, _Z)),
+    ]
+    out_shape = [
+        jax.ShapeDtypeStruct((bhk, sk, d), q3.dtype),
+        jax.ShapeDtypeStruct((bhk, sk, d), q3.dtype),
+    ]
+    scratch = [pltpu.VMEM((bk, d), jnp.float32),
+               pltpu.VMEM((bk, d), jnp.float32)]
+    kernel, semantics = "dkv", ("parallel", "parallel", "arbitrary",
+                                "arbitrary")
+    if with_dq:
+        # dq stays in HBM (``memory_space=pl.ANY``): the kernel writes each
+        # query block once, when it is final, by a DMA of whole lanes (a
+        # head of 64 takes 128 and drops the rest below)
+        lanes = _round_up(d, _LANES)
+        out_specs.append(pl.BlockSpec(memory_space=pl.ANY))
+        out_shape.append(jax.ShapeDtypeStruct((bhq, sq, lanes), q3.dtype))
+        scratch += [pltpu.VMEM((rep, sq, d), jnp.float32),
+                    pltpu.VMEM((bq, lanes), q3.dtype),
+                    pltpu.SemaphoreType.DMA(()),
+                    pltpu.SMEM((1,), jnp.int32)]
+        kernel, semantics = "bwd", ("parallel", "arbitrary", "arbitrary",
+                                    "arbitrary")
+    outs = pl.pallas_call(
         functools.partial(_dkv_kernel, scale=scale, causal=causal,
                           offset=offset, bq=bq, bk=bk, sub_q=sub_q,
                           sub_k=sub_k, kinds=kinds, nq=nq, nk_grid=nk,
@@ -1246,34 +1360,31 @@ def _bwd_dkv(q3, kx, vx, do3, lse3, delta3, bias3, seed, causal, scale,
                           sk_real=sk_real, pad_keys=sk != sk_real,
                           has_bias=has_bias, has_seg=has_seg,
                           seg_causal=bias_maps.get("seg_causal", False),
-                          rate=rate, **band),
+                          rate=rate, with_dq=with_dq, **band),
         grid=(bhk, nk, rep, nq),
         in_specs=kq_specs,
-        out_specs=[
-            pl.BlockSpec((1, bk, d), lambda bh, ki, r, qi: (bh, ki, _Z)),
-            pl.BlockSpec((1, bk, d), lambda bh, ki, r, qi: (bh, ki, _Z)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bhk, sk, d), q3.dtype),
-            jax.ShapeDtypeStruct((bhk, sk, d), q3.dtype),
-        ],
-        scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                        pltpu.VMEM((bk, d), jnp.float32)],
+        out_specs=out_specs,
+        out_shape=out_shape,
+        scratch_shapes=scratch,
         compiler_params=_compiler_params(
-            ("parallel", "parallel", "arbitrary", "arbitrary"),
-            _call_vmem("dkv", q3, kx, vx, bias3, False, has_seg, rate,
-                       tile)),
+            semantics, _call_vmem(kernel, q3, kx, vx, bias3, False, has_seg,
+                                  rate, tile)),
         interpret=interpret,
-        name=names["dkv"],
+        name=names[kernel],
     )(*kq_args)
+    if with_dq:
+        dk, dv, dq = outs
+        return dk, dv, dq[..., :d]
+    return outs
 
 
 def _bwd_impl(q3, kx, vx, do3, lse, delta, bias3, seed, causal, scale,
               offset, sk_real, plan, bias_maps, interpret, qseg3=None,
               kseg3=None, hq=None, hk=None, window=None):
-    """Both backward kernels over operands padded to lengths that the dq
-    and the dkv tile of ``plan`` divide. hq == hk is plain MHA. Returns
-    (dq, dk (BHk), dv (BHk), dbias_blocks)."""
+    """The backward pass over operands padded to ``_bwd_lengths``: the one
+    kernel where ``plan.bwd`` is a tile, else the dq and the dkv kernel.
+    hq == hk is plain MHA. Returns (dq, dk (BHk), dv (BHk),
+    dbias_blocks)."""
     bhq = q3.shape[0]
     hq = hq if hq is not None else bhq
     hk = hk if hk is not None else bhq
@@ -1281,14 +1392,29 @@ def _bwd_impl(q3, kx, vx, do3, lse, delta, bias3, seed, causal, scale,
               seed, causal, scale, offset, sk_real)
     rest = (_Static(bias_maps), interpret, qseg3, kseg3, hq, hk, window)
     has_seg, rate = qseg3 is not None, bias_maps["rate"]
+    if plan.bwd is not None:
+        _record_call("bwd", q3, kx, vx, bias3, False, has_seg, rate,
+                     plan.bwd, offset, causal, sk_real, window)
+        dk, dv, dq = _bwd_dkv(*common, plan.bwd, *rest, with_dq=True)
+        return dq, dk, dv, None
     for kernel, tile, dbias in (
             ("dq", plan.dq, bias3 is not None and bias_maps["full"]),
             ("dkv", plan.dkv, False)):
         _record_call(kernel, q3, kx, vx, bias3, dbias, has_seg, rate, tile,
-                     offset, causal, sk_real, window)
+                     offset, causal, sk_real, window, split_by=plan.split_by)
     dq, dbias_blocks = _bwd_dq(*common, plan.dq, *rest)
     dk, dv = _bwd_dkv(*common, plan.dkv, *rest)
     return dq, dk, dv, dbias_blocks
+
+
+def _bwd_lengths(plan):
+    """(q, k) block lengths the backward's operands are padded to a
+    multiple of: the one kernel's tile, or one length both split kernels'
+    tiles divide."""
+    if plan.bwd is not None:
+        return plan.bwd.bq, plan.bwd.bk
+    return math.lcm(plan.dq.bq, plan.dkv.bq), math.lcm(plan.dq.bk,
+                                                       plan.dkv.bk)
 
 
 # ---------------------------------------------------------------------------
@@ -1350,7 +1476,7 @@ def _dbias_broadcast(q3, kx, vx, do3, lse_p, delta, bias3, seed, maps,
 
 
 # ---------------------------------------------------------------------------
-# the tile plan: how the three kernels cut attention into grid steps
+# the tile plan: how the kernels cut attention into grid steps
 # ---------------------------------------------------------------------------
 
 class Tile(NamedTuple):
@@ -1369,10 +1495,20 @@ class Tile(NamedTuple):
 class TilePlan(NamedTuple):
     """The ``Tile`` of each kernel. They need not agree: fwd and dq hold one
     (bq, d) accumulator and sweep k, dkv holds two (bk, d) accumulators and
-    sweeps q."""
+    sweeps q. ``bwd`` is the one backward kernel's (dkv's grid and walk,
+    with dq besides), or None where the call keeps dq and dkv apart;
+    ``split_by`` then says why (``backward_rule``)."""
     fwd: Tile
     dq: Tile
     dkv: Tile
+    bwd: Optional[Tile] = None
+    split_by: Optional[str] = None
+
+    def lowered(self):
+        """{kernel: Tile} of the kernels a call of this plan lowers."""
+        if self.bwd is not None:
+            return {"fwd": self.fwd, "bwd": self.bwd}
+        return {"fwd": self.fwd, "dq": self.dq, "dkv": self.dkv}
 
 
 # Mosaic's scoped VMEM: 16 MiB unless the call asks for more (a v5e core
@@ -1383,7 +1519,7 @@ _VMEM_MAX_LIMIT = 64 << 20
 # 128 fit (24.5 MiB in dkv), and 1.5 times it stays under the limit above
 _VMEM_BUDGET = 32 << 20
 # the (bq, bk) float32 score-sized temporaries a body keeps alive
-_SCORE_TEMPS = {"fwd": 3, "dq": 4, "dkv": 4}
+_SCORE_TEMPS = {"fwd": 3, "dq": 4, "dkv": 4, "bwd": 4}
 _MAX_BLOCK = 1024
 
 
@@ -1391,15 +1527,23 @@ def _round_up(n, m):
     return -(-n // m) * m
 
 
+def _dq_acc_bytes(rows, d):
+    """The one backward kernel's float32 dq accumulator of ``rows`` q rows
+    (rep x the padded Sq), whole 128-lane tiles."""
+    return rows * _round_up(d, _LANES) * 4
+
+
 def _vmem_bytes(kernel, bq, bk, d, q_bytes, k_bytes, v_bytes, bias_bytes,
-                dbias, segments, dropout):
-    """Estimated VMEM of one call of ``kernel`` ("fwd", "dq", "dkv") at a
-    (bq, bk) tile: in/out blocks double-buffered by the pipeline, scratch,
-    and the score-sized temporaries of the body counted at the whole tile,
-    which a tile computed in one piece needs (a walked tile's pieces are
-    smaller; the estimate does not follow them, so a call asks for the
-    limit it asked for before tiles were walked). A (bq, 1) column block
-    occupies whole 128-lane tiles."""
+                dbias, segments, dropout, dq_rows=0):
+    """Estimated VMEM of one call of ``kernel`` ("fwd", "dq", "dkv", "bwd")
+    at a (bq, bk) tile: in/out blocks double-buffered by the pipeline,
+    scratch, and the score-sized temporaries of the body counted at the
+    whole tile, which a tile computed in one piece needs (a walked tile's
+    pieces are smaller; the estimate does not follow them, so a call asks
+    for the limit it asked for before tiles were walked). A (bq, 1) column
+    block occupies whole 128-lane tiles. "bwd" holds dkv's blocks and the
+    dq of ``dq_rows`` q rows besides, and one q block of it on its way
+    out."""
     dl = _round_up(d, _LANES)
     col = _round_up(bq, 8) * _LANES * 4
     q_blk, k_blk, v_blk = bq * dl * q_bytes, bk * dl * k_bytes, \
@@ -1413,6 +1557,8 @@ def _vmem_bytes(kernel, bq, bk, d, q_bytes, k_bytes, v_bytes, bias_bytes,
     else:                     # q, k, v, do, lse, delta in; dk, dv out
         blocks = 2 * q_blk + k_blk + v_blk + 2 * col + 2 * bk * dl * q_bytes
         scratch = 2 * bk * dl * 4
+        if kernel == "bwd":
+            scratch += _dq_acc_bytes(dq_rows, d) + q_blk
     temps = _SCORE_TEMPS[kernel] + (2 if dropout else 0)
     if bias_bytes:
         blocks += bq * bk * bias_bytes
@@ -1447,13 +1593,18 @@ def _sub_tile(kernel, bq, bk, d, window=None):
     and 512 over a wider head, where the MXU wants long pieces. Measured at
     heads of 64 and 128 in bf16, at 1024 x 1024 tiles (512 x 512 too under
     the window): a head of 256, or of 32, follows its neighbour's rule
-    unmeasured. No piece is longer than the window rounded up to 128, or
+    unmeasured. The one backward kernel walks as dkv does and takes its
+    256: fastest, or within 1% of 512, at heads of 64, 128 and 256 and
+    under the window 512 (PERF.md, section 6: the one kernel's sweep). No
+    piece is longer than the
+    window rounded up to 128, or
     the band skips nothing (measured at the window 512 alone). A side that
     no multiple of 128 divides (a short one, taken whole) is one piece; a
     query side under 8 rows (decode) leaves nothing to skip."""
     if bq < 8:
         return bq, bk
-    most = {"fwd": 128 if d <= 64 else 512, "dq": 128, "dkv": 256}[kernel]
+    most = {"fwd": 128 if d <= 64 else 512, "dq": 128, "dkv": 256,
+            "bwd": 256}[kernel]
     if window is not None:
         most = min(most, _round_up(window, _LANES))
 
@@ -1490,18 +1641,51 @@ def _tile(kernel, bq, bk, sq, sk, d, *, causal, window=None, sub=None):
     return Tile(bq, bk, sub_q, sub_k, tuple(sorted(kinds, key=str)))
 
 
+def backward_rule(dkv, sq, sk, d, q_bytes, k_bytes, v_bytes, *, rep,
+                  bias_bytes, dbias, segments, dropout, window, causal):
+    """(the one backward kernel's ``Tile``, None), or (None, why the call
+    keeps the dq and the dkv kernel apart), from the facts ``tile_plan``
+    sees and the dkv kernel's ``Tile``, whose grid and walk it takes. The
+    one kernel computes each visible piece's scores once for dq, dk and dv
+    (five products where the split kernels take seven, one ``exp`` pass
+    where they take two), and holds dq for all the kv head's ``rep`` q rows
+    in float32 across the sweep. The split kernels stay only where:
+
+    - "dbias": a full per-(batch, head) bias asks for dbias tiles, an
+      output only the dq kernel's grid emits;
+    - "rows_unseen": a window on more queries than keys leaves query
+      blocks that no step of the band's grid visits, whose dq would go
+      unwritten;
+    - "vmem": the estimate with the accumulator passes the largest scoped
+      VMEM a call asks for."""
+    if dbias:
+        return None, "dbias"
+    if window is not None and sq > sk:
+        return None, "rows_unseen"
+    rows = rep * _round_up(sq, dkv.bq)
+    if _vmem_bytes("bwd", dkv.bq, dkv.bk, d, q_bytes, k_bytes, v_bytes,
+                   bias_bytes, False, segments, dropout,
+                   dq_rows=rows) > _VMEM_MAX_LIMIT:
+        return None, "vmem"
+    return _tile("bwd", dkv.bq, dkv.bk, sq, sk, d, causal=causal,
+                 window=window), None
+
+
 def tile_plan(sq, sk, d, q_bytes=2, k_bytes=2, v_bytes=2, *, bias_bytes=0,
               dbias=False, segments=False, dropout=False,
-              vmem_budget=_VMEM_BUDGET, window=None, causal=True) -> TilePlan:
-    """The ``Tile`` of the three kernels, from what a call can see at trace
+              vmem_budget=_VMEM_BUDGET, window=None, causal=True,
+              rep=1) -> TilePlan:
+    """The ``Tile`` of each kernel, from what a call can see at trace
     time: the lengths, the head dim, the operands' item sizes, whether a
     bias block (and its dbias tile), segment ids or dropout ride along, a
-    VMEM budget, and whether the kernels hide what lies above the diagonal
+    VMEM budget, whether the kernels hide what lies above the diagonal
     (``causal``; under segment ids they do not, the diagonals ride in the
-    segment words) or outside a ``window``. Each kernel takes the largest
+    segment words) or outside a ``window``, and the q heads a kv head
+    serves in the backward pass (``rep``). Each kernel takes the largest
     tile whose sides suit the lengths and whose estimate fits the budget
     (PERF.md, "PR 26": the sweep on the chip; a grid step costs about half
-    a microsecond whatever is in it).
+    a microsecond whatever is in it). The one backward kernel takes the
+    dkv kernel's tile where ``backward_rule`` lets the call have it.
 
     What a large tile would waste it does not compute: a step walks its
     tile in ``_sub_tile``'s pieces, enters only those that hold a score
@@ -1532,32 +1716,44 @@ def tile_plan(sq, sk, d, q_bytes=2, k_bytes=2, v_bytes=2, *, bias_bytes=0,
         bq, bk = max(fits, key=lambda t: (t[0] * t[1], t[1]),
                      default=(min(qs), min(ks)))
         return _tile(kernel, bq, bk, sq, sk, d, causal=causal, window=window)
-    return TilePlan(pick("fwd"), pick("dq"), pick("dkv"))
+    return _with_backward(
+        pick("fwd"), pick("dq"), pick("dkv"), sq, sk, d, q_bytes, k_bytes,
+        v_bytes, rep=rep, bias_bytes=bias_bytes, dbias=dbias,
+        segments=segments, dropout=dropout, window=window, causal=causal)
+
+
+def _with_backward(fwd, dq, dkv, *args, **facts) -> TilePlan:
+    return TilePlan(fwd, dq, dkv, *backward_rule(dkv, *args, **facts))
 
 
 def _blocks(block_q, block_k, q, k, v, bias, segments, rate, causal,
             window=None):
     """TilePlan of a call on q [B,Sq,Hq,D], k/v [B,Sk,Hk,D]: explicit
-    ``block_q`` and ``block_k`` (cut to the length) go to all three
-    kernels, each with its own sub-tile; both None, the plan chooses.
-    ``causal`` is the call's; under ``segments`` the kernels see none."""
+    ``block_q`` and ``block_k`` (cut to the length) go to every kernel,
+    each with its own sub-tile; both None, the plan chooses. ``causal`` is
+    the call's; under ``segments`` the kernels see none. A bias on grouped
+    heads expands K and V for the backward pass (``_fa_bwd``), which then
+    sees one q head a kv head."""
     B, Sq, Hq, D = q.shape
-    Sk = k.shape[1]
+    Sk, Hk = k.shape[1], k.shape[2]
     causal = causal and not segments
     if (block_q is None) != (block_k is None):
         raise ValueError("block_q and block_k are given together or not at "
                          f"all, got {block_q!r} and {block_k!r}")
-    if block_q is not None:
-        bq, bk = min(block_q, Sq), min(block_k, Sk)
-        return TilePlan(*(_tile(kernel, bq, bk, Sq, Sk, D, causal=causal,
-                                window=window)
-                          for kernel in ("fwd", "dq", "dkv")))
-    return tile_plan(
-        Sq, Sk, D, q.dtype.itemsize, k.dtype.itemsize, v.dtype.itemsize,
+    facts = dict(
         bias_bytes=jnp.asarray(bias).dtype.itemsize if bias is not None
         else 0,
         dbias=bias is not None and _bias_shape4(bias) == (B, Hq, Sq, Sk),
         segments=segments, dropout=rate > 0.0, window=window, causal=causal)
+    sizes = (q.dtype.itemsize, k.dtype.itemsize, v.dtype.itemsize)
+    rep = 1 if bias is not None else Hq // Hk
+    if block_q is not None:
+        bq, bk = min(block_q, Sq), min(block_k, Sk)
+        return _with_backward(
+            *(_tile(kernel, bq, bk, Sq, Sk, D, causal=causal, window=window)
+              for kernel in ("fwd", "dq", "dkv")), Sq, Sk, D, *sizes,
+            rep=rep, **facts)
+    return tile_plan(Sq, Sk, D, *sizes, rep=rep, **facts)
 
 
 # ---------------------------------------------------------------------------
@@ -1774,10 +1970,7 @@ def _fa_bwd(causal, scale, dropout_rate, block_q, block_k, interpret, window,
     rep = Hq // Hk
     plan = _blocks(block_q, block_k, q, k, v, bias, q_seg is not None,
                    dropout_rate, causal, window)
-    # the two kernels share their operands: pad each side to a length both
-    # of its blocks divide
-    bq, bk = math.lcm(plan.dq.bq, plan.dkv.bq), \
-        math.lcm(plan.dq.bk, plan.dkv.bk)
+    bq, bk = _bwd_lengths(plan)
     offset = Sk - Sq
 
     q3 = _pad_seq(q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, D), bq)
@@ -1914,8 +2107,7 @@ def flash_chunk_bwd(q, k, v, do, lse, delta, causal, scale, block_q=None,
     B, Sq, Hq, D = q.shape
     Sk, Hk = k.shape[1], k.shape[2]
     plan = _blocks(block_q, block_k, q, k, v, None, False, 0.0, causal)
-    bq, bk = math.lcm(plan.dq.bq, plan.dkv.bq), \
-        math.lcm(plan.dq.bk, plan.dkv.bk)
+    bq, bk = _bwd_lengths(plan)
     q3 = _pad_seq(q.transpose(0, 2, 1, 3).reshape(B * Hq, Sq, D), bq)
     kx = _pad_seq(k.transpose(0, 2, 1, 3).reshape(B * Hk, Sk, D), bk)
     vx = _pad_seq(v.transpose(0, 2, 1, 3).reshape(B * Hk, Sk, D), bk)

@@ -7,6 +7,7 @@ benchmark's readers find it by: the tile, grid, names, operand shapes and
 VMEM limit at the two accepted benchmark cells' shapes are pinned here as
 constants read off PR 26's commit. The whole jaxpr is pinned too, as of
 PR 31, which changed the kernels' bodies (a tile is walked in sub-tiles).
+The backward pass of these calls is the one backward kernel.
 """
 import hashlib
 import math
@@ -101,6 +102,34 @@ def test_windowed_sub_tiles_match_masked_plain_attention(s, hq, hk, window,
         np.testing.assert_allclose(a, b, atol=2e-4)
 
 
+@pytest.mark.parametrize("s,hq,hk,window,block", [
+    (1200, 8, 1, 512, 512),     # the cell's window and group, on a length
+    # the block does not divide
+    (1024, 6, 2, 200, 256)], ids=["w512_rep8", "w200_rep3"])
+def test_one_backward_kernel_under_a_window_matches_the_split_kernels(
+        s, hq, hk, window, block, monkeypatch):
+    """The one backward kernel on the band's grid: dq, dk and dv equal the
+    split kernels' to float32 rounding (dq's partial sums are added in
+    another order) and plain masked attention's."""
+    q, k, v, do = _mk(1, s, hq, hk, 32, seed=s + hq)
+
+    def grads():
+        _, vjp = jax.vjp(lambda *a: _flash(*a, window, block, block), q, k,
+                         v)
+        return vjp(do)
+    plan = fa._blocks(block, block, q, k, v, None, False, 0.0, True, window)
+    assert plan.split_by is None and plan.bwd[:2] == (block, block)
+    one = grads()
+    _, vjp = jax.vjp(lambda *a: _plain(*a, window), q, k, v)
+    want = vjp(do)
+    monkeypatch.setattr(fa, "backward_rule",
+                        lambda *a, **kw: (None, "vmem"))
+    split = grads()
+    for a, b, w in zip(one, split, want):
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(a, w, atol=2e-4)
+
+
 def test_the_plans_tile_stops_at_twice_the_window():
     """512-wide bands take 1024 x 1024 tiles walked in pieces no longer than
     the window (the sweep on the chip, PERF.md "PR 31"; 512 x 512 while a
@@ -109,13 +138,18 @@ def test_the_plans_tile_stops_at_twice_the_window():
         return [(side, side, sub, sub) for sub in (fwd, dq, dkv)]
 
     def plan(window=None):
-        return [t[:4] for t in fa.tile_plan(8192, 8192, 128, window=window)]
+        return [t[:4] for t in fa.tile_plan(8192, 8192, 128,
+                                            window=window)[:3]]
     assert plan(512) == plan() == plan(4096) == tile(512, 128, 256)
     assert plan(8) == tile(128, 128, 128, side=256)
     assert plan(200) == tile(256, 128, 256, side=512)
+    # the one backward kernel takes dkv's tile and walk; Laguna's window
+    # layers (64 q heads over 8) hold their dq in it
+    laguna = fa.tile_plan(8192, 8192, 128, window=512, rep=8)
+    assert laguna.bwd == laguna.dkv and laguna.split_by is None
     # a 1024 tile under the window 512 meets the diagonal at its own
     # corner, and the band's lower edge in the tile to its left
-    assert {t.kinds for t in fa.tile_plan(8192, 8192, 128, window=512)} == \
+    assert {t.kinds for t in laguna.lowered().values()} == \
         {((0, None), (1024, None))}
 
 
@@ -140,8 +174,7 @@ def test_a_windowed_call_sweeps_the_band_only_and_says_so():
     finally:
         tracing.disable_tracing()
         tracing.reset_tracing()
-    assert set(events) == {"flash_win_fwd", "flash_win_bwd_dq",
-                           "flash_win_bwd_dkv"}
+    assert set(events) == {"flash_win_fwd", "flash_win_bwd"}
     for name, a in events.items():
         assert (a["bq"], a["bk"], a["window"]) == (256, 256, 128)
         assert (a["sub_q"], a["sub_k"]) == (128, 128)
@@ -159,8 +192,7 @@ def test_a_windowed_call_sweeps_the_band_only_and_says_so():
     calls = _pallas_calls(jax.grad(
         lambda q, k, v: _flash(q, k, v, 128).sum(), (0, 1, 2)), q, k, v)
     assert [(c["name"], c["grid"]) for c in calls] == [
-        ("flash_win_fwd", (2, 4, 2)), ("flash_win_bwd_dq", (2, 4, 2)),
-        ("flash_win_bwd_dkv", (1, 4, 2, 2))]
+        ("flash_win_fwd", (2, 4, 2)), ("flash_win_bwd", (1, 4, 2, 2))]
 
 
 def test_the_xla_fallback_masks_the_same_window():
@@ -241,9 +273,13 @@ def _cell_grad(b, s, hq, hk, d, qk_dtype, v_dtype):
 
 def _row(name, grid, d, q_rows, kv_rows, s, n_in, vmem):
     big, col = (1, 1024, d), (1, 1024, 1)
+    # the one backward kernel's dq is the whole array in HBM, in whole lanes
     blocks = {"flash_fwd": (big,) * 4 + (col,),
               "flash_bwd_dq": (big,) * 4 + (col, col, big),
-              "flash_bwd_dkv": (big,) * 4 + (col, col, big, big)}[name]
+              "flash_bwd_dkv": (big,) * 4 + (col, col, big, big),
+              "flash_bwd": (big,) * 4 + (col, col, big, big,
+                                        (q_rows, s, -(-d // 128) * 128)),
+              }[name]
     q3, kv3, c3 = (q_rows, s, d), (kv_rows, s, d), (q_rows, s, 1)
     operands = (q3, kv3, kv3) if n_in == 3 else (q3, kv3, kv3, q3, c3, c3)
     return {"name": name, "grid": grid, "blocks": blocks,
@@ -251,23 +287,22 @@ def _row(name, grid, d, q_rows, kv_rows, s, n_in, vmem):
 
 
 # names, grids, blocks, operands and VMEM limits read off PR 26's commit
-# (97959e4) with this file's _pallas_calls; the digests are PR 31's, whose
-# kernels walk a tile in sub-tiles and are jitted on their own
+# (97959e4) with this file's _pallas_calls for the forward; the backward is
+# the one kernel, on the dkv kernel's grid with dq as one more output; the
+# digests are of the tree that made the backward one kernel
 _PINNED = {
     "gpt2s-train-s1024": (
         (32, 1024, 12, 12, 64, jnp.bfloat16, jnp.bfloat16),
         [_row("flash_fwd", (384, 1, 1), 64, 384, 384, 1024, 3, 25952256),
-         _row("flash_bwd_dq", (384, 1, 1), 64, 384, 384, 1024, 6, 33030144),
-         _row("flash_bwd_dkv", (384, 1, 1, 1), 64, 384, 384, 1024, 6,
-              34603008)],
-        "8005a5d08f1ae177"),
+         _row("flash_bwd", (384, 1, 1, 1), 64, 384, 384, 1024, 6,
+              35782656)],
+        "4f7776189ecc0b09"),
     "mistral7b-l2-train-s4096": (
         (4, 4096, 32, 8, 128, jnp.float32, jnp.bfloat16),
         [_row("flash_fwd", (128, 4, 4), 128, 128, 32, 4096, 3, 28311552),
-         _row("flash_bwd_dq", (128, 4, 4), 128, 128, 32, 4096, 6, 36175872),
-         _row("flash_bwd_dkv", (32, 4, 4, 4), 128, 128, 32, 4096, 6,
-              38535168)],
-        "930ee281d5c13e81"),
+         _row("flash_bwd", (32, 4, 4, 4), 128, 128, 32, 4096, 6,
+              51904512)],
+        "2186ba4d9856dc10"),
 }
 
 

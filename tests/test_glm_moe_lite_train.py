@@ -108,7 +108,7 @@ def test_plans_are_recorded_once_a_lowered_layer_and_step(interpret_kernels):  #
             a["v_head_dim"], a["q_lora_rank"], a["kv_lora_rank"],
             a["tokens"]) == (4, 12, 4, 16, 24, 16, 64)
     assert (a["route"], a["rule"]) == ("kernel", "default")
-    assert a["tiles"] == "fwd=32x32/32x32 dq=32x32/32x32 dkv=32x32/32x32"
+    assert a["tiles"] == "fwd=32x32/32x32 bwd=32x32/32x32"
     mtp = [e["args"] for e in events if e["name"] == "mtp::plan"]
     assert len(mtp) == 1
     assert mtp[0] == {"depth": 1, "weight": 0.3, "positions": 64,

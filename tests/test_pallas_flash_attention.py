@@ -600,6 +600,16 @@ def test_tile_plan_is_legal(row):
         assert fa._vmem_bytes(kernel, bq, bk, d, qb, qb, vb, bias_b,
                               dbias and kernel == "dq", segs, drop) \
             <= fa._VMEM_BUDGET
+    # the one backward kernel takes dkv's tile, its accumulator counted
+    # against the largest limit a call asks for; a full bias splits
+    if dbias:
+        assert (plan.bwd, plan.split_by) == (None, "dbias")
+    else:
+        assert plan.split_by is None and plan.bwd[:2] == plan.dkv[:2]
+        assert fa._vmem_bytes(
+            "bwd", *plan.bwd[:2], d, qb, qb, vb, bias_b, False, segs, drop,
+            dq_rows=-(-sq // plan.bwd.bq) * plan.bwd.bq) \
+            <= fa._VMEM_MAX_LIMIT
     # the backward's two kernels share operands padded once: both tiles
     # divide the padded lengths
     for s, a, b_ in ((sq, plan.dq.bq, plan.dkv.bq),
@@ -613,7 +623,7 @@ def test_tile_plan_of_the_benchmark_cells():
     the chip) records as the fastest measured at the cells' attention
     shapes."""
     def sides(plan):
-        return [t[:4] for t in plan]
+        return [t[:4] for t in plan[:3]]
 
     def want(fwd, dq, dkv):
         return [(1024, 1024, sub, sub) for sub in (fwd, dq, dkv)]
@@ -621,7 +631,8 @@ def test_tile_plan_of_the_benchmark_cells():
     # diagonal
     gpt2 = fa.tile_plan(1024, 1024, 64, 2, 2, 2)
     assert sides(gpt2) == want(128, 128, 256)
-    assert {t.kinds for t in gpt2} == {((0, None),)}
+    assert {t.kinds for t in gpt2[:4]} == {((0, None),)}
+    assert gpt2.bwd[:4] == (1024, 1024, 256, 256)
     # mistral7b-l2-train-s4096: s4096, head 128, bf16 (q and k float32
     # until PR 29); laguna-xs2-train-s8192's full layers: tiles on the
     # diagonal, walked, and tiles under it, one piece each
@@ -629,25 +640,26 @@ def test_tile_plan_of_the_benchmark_cells():
                  fa.tile_plan(4096, 4096, 128, 4, 4, 2),
                  fa.tile_plan(8192, 8192, 128, 2, 2, 2)):
         assert sides(plan) == want(512, 128, 256)
-        assert {t.kinds for t in plan} == {((0, None), (None, None))}
+        assert {t.kinds for t in plan[:4]} == {((0, None), (None, None))}
+        assert plan.bwd[:4] == (1024, 1024, 256, 256)
     # what the plan returns is what is lowered: a call that is neither
     # causal nor padded has nothing to walk, a padded one has
     plain = fa.tile_plan(4096, 4096, 128, 2, 2, 2, causal=False)
-    assert [t for t in plain] == [(1024, 1024, 1024, 1024, ((None, None),))
-                                  ] * 3
+    assert list(plain[:4]) == [(1024, 1024, 1024, 1024,
+                                ((None, None),))] * 4
     padded = fa.tile_plan(1000, 1000, 64, 2, 2, 2, causal=False)
     assert sides(padded) == want(128, 128, 256)
-    assert {t.kinds for t in padded} == {((None, 1000),)}
+    assert {t.kinds for t in padded[:4]} == {((None, 1000),)}
 
 
 def test_tile_plan_shrinks_under_a_tight_budget():
     roomy = fa.tile_plan(4096, 4096, 128, 4, 4, 2)
     tight = fa.tile_plan(4096, 4096, 128, 4, 4, 2, vmem_budget=2 << 20)
-    for (bq, bk, *_), (tq, tk, *_) in zip(roomy, tight):
+    for (bq, bk, *_), (tq, tk, *_) in zip(roomy[:4], tight[:4]):
         assert tq * tk < bq * bk
     least = (128, 128, 128, 128)
     assert [t[:4] for t in fa.tile_plan(
-        4096, 4096, 128, 4, 4, 2, vmem_budget=1)] == [least] * 3
+        4096, 4096, 128, 4, 4, 2, vmem_budget=1)[:4]] == [least] * 4
 
 
 def test_tile_plan_event_and_tally_once_per_lowering():
@@ -673,9 +685,10 @@ def test_tile_plan_event_and_tally_once_per_lowering():
     finally:
         tracing.disable_tracing()
         tracing.reset_tracing()
-    want = {"flash_fwd": plan.fwd, "flash_bwd_dq": plan.dq,
-            "flash_bwd_dkv": plan.dkv}
-    assert len(again) == len(events) == 3
+    want = {fa._KERNEL_NAMES[kernel]: tile
+            for kernel, tile in plan.lowered().items()}
+    assert set(want) == {"flash_fwd", "flash_bwd"}
+    assert len(again) == len(events) == 2
     for ev in events:
         a = ev["args"]
         bq, bk, sub_q, sub_k, _ = want.pop(a["kernel"])
@@ -690,6 +703,8 @@ def test_tile_plan_event_and_tally_once_per_lowering():
             1 for i in range(nq) for j in range(nk)
             if j * bk > i * bq + bq - 1)
         assert 0 < a["vmem_bytes"] <= fa._VMEM_BUDGET
+        if a["kernel"] == "flash_bwd":   # 2 q heads, each its own kv head
+            assert a["dq_resident_bytes"] == 512 * 128 * 4
         key = (a["kernel"], bq, bk, sub_q, sub_k)
         assert fa.TILE_PLAN_TALLY[key] == before.get(key, 0) + 1
     assert not want
@@ -707,8 +722,8 @@ def test_explicit_blocks_win_over_the_plan():
                 if n > before.get(key, 0)}
 
     def keys(bq, bk):
-        return {(name, bq, bk) + fa._sub_tile(kernel, bq, bk, 64)
-                for kernel, name in fa._KERNEL_NAMES.items()}
+        return {(fa._KERNEL_NAMES[kernel], bq, bk) +
+                fa._sub_tile(kernel, bq, bk, 64) for kernel in ("fwd", "bwd")}
     assert lowered(256, 128) == keys(256, 128)
     assert lowered(4096, 4096) == keys(512, 512)        # min(block, S)
     with pytest.raises(ValueError, match="together"):
@@ -901,7 +916,7 @@ _COUNT_CASES = {
 }
 
 
-@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv"])
+@pytest.mark.parametrize("kernel", ["fwd", "dq", "dkv", "bwd"])
 @pytest.mark.parametrize("case", list(_COUNT_CASES))
 def test_sub_tile_counts_equal_brute_force(case, kernel):
     """The arithmetic that lays out the walks enters exactly the sub-tiles
@@ -956,7 +971,7 @@ def test_tile_plan_event_carries_the_sub_tile_counts(monkeypatch):
     for sub in ((128, 256), (512, 512)):
         entered, masked = _brute_force(1024, 1024, *sub, **edges)
         got = events(sub)
-        assert set(got) == {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"}
+        assert set(got) == {"flash_fwd", "flash_bwd"}
         for a in got.values():
             assert (a["sub_q"], a["sub_k"]) == sub
             assert a["sub_tiles"] == b * hq * entered
@@ -970,3 +985,166 @@ def test_tile_plan_event_carries_the_sub_tile_counts(monkeypatch):
         assert (a["sub_q"], a["sub_k"]) == (a["bq"], a["bk"]) == (512, 512)
         assert (a["sub_tiles"], a["sub_tiles_masked"],
                 a["sub_tiles_skipped"]) == (b * hq * 4, 0, 0)
+
+
+# ---------------------------------------------------------------------------
+# the one backward kernel: dq summed inside the dkv kernel's sweep. Its dq, dk
+# and dv against the split kernels (the same products of the same ds, summed
+# in another order) and against plain masked attention; which calls take it.
+# ---------------------------------------------------------------------------
+
+def _split(monkeypatch):
+    """Every call keeps the dq and the dkv kernel apart."""
+    monkeypatch.setattr(fa, "backward_rule",
+                        lambda *a, **kw: (None, "vmem"))
+
+
+# name: (b, sq, sk, hq, hk, d, causal, blocks, extra)
+_ONE_KERNEL_CASES = {
+    "mha_causal": (1, 512, 512, 2, 2, 32, True, 128, None),
+    "mha_not_causal": (1, 384, 384, 2, 2, 32, False, 128, None),
+    "gqa_rep4": (1, 512, 512, 8, 2, 32, True, 128, None),
+    "gqa_rep6_not_causal": (1, 256, 256, 6, 1, 32, False, 128, None),
+    "gqa_rep8": (1, 512, 512, 8, 1, 32, True, 256, None),
+    "padded_keys": (1, 1000, 1000, 4, 2, 32, True, 256, None),
+    "offset_diagonal": (1, 256, 512, 4, 2, 32, True, 128, None),
+    "segment_ids": (1, 1000, 1000, 2, 2, 32, True, 256, "segments"),
+    "dropout": (1, 512, 512, 4, 2, 32, True, 128, "dropout"),
+    # a bias on grouped heads expands K and V: the kernel sees rep 1
+    "broadcast_bias_gqa": (2, 256, 256, 4, 2, 32, True, 128, "bias"),
+}
+
+
+@pytest.mark.parametrize("case", list(_ONE_KERNEL_CASES))
+def test_one_backward_kernel_matches_the_split_kernels(case, monkeypatch):
+    b, sq, sk, hq, hk, d, causal, blk, extra = _ONE_KERNEL_CASES[case]
+    q, k, v = _mk(b, sq, sk, hq, hk, d, seed=41)
+    scale = 1.0 / math.sqrt(d)
+    rng = np.random.RandomState(42)
+    ct = jnp.asarray(rng.standard_normal((b, sq, hq, d)), jnp.float32)
+    bias = seg = keep = None
+    rate, seed = 0.0, _SEED0
+    if extra == "segments":
+        seg = jnp.asarray(np.repeat(np.arange(4, dtype=np.int32),
+                                    [300, 212, 420, 68])[None, :])
+    elif extra == "dropout":
+        rate, seed = 0.2, jnp.asarray([99], jnp.int32)
+        keep = dropout_keep_mask(seed, b * hq, sq, sk, rate).reshape(
+            b, hq, sq, sk)
+    elif extra == "bias":
+        bias = jnp.asarray(rng.standard_normal((1, 1, sq, sk)),
+                           jnp.float32) * 0.5
+
+    def grads(fn):
+        return jax.jit(jax.grad(lambda q, k, v: jnp.sum(fn(q, k, v) * ct),
+                                (0, 1, 2)))(q, k, v)
+
+    def flash(q, k, v):
+        return flash_attention_ext(q, k, v, bias, seed, seg, seg, causal,
+                                   scale, rate, blk, blk, True)
+
+    def oracle(q, k, v):
+        return _dense_oracle(
+            q, k, v, scale, bias=_segment_bias(seg, seg) if seg is not None
+            else bias, keep=keep, rate=rate, causal=causal)
+    plan = fa._blocks(blk, blk, q, k, v, bias, seg is not None, rate,
+                      causal)
+    assert plan.split_by is None and plan.bwd[:2] == plan.dkv[:2]
+    one = grads(flash)
+    want = grads(oracle)
+    _split(monkeypatch)
+    assert fa._blocks(blk, blk, q, k, v, bias, seg is not None, rate,
+                      causal).bwd is None
+    split = grads(flash)
+    for a, s_, w, name in zip(one, split, want, "qkv"):
+        assert a.dtype == s_.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(a), np.asarray(s_), rtol=1e-5,
+                                   atol=1e-5, err_msg=f"{case}: d{name}")
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), rtol=3e-4,
+                                   atol=3e-4, err_msg=f"{case}: d{name}")
+
+
+@pytest.mark.parametrize("causal", [True, False], ids=["diagonal", "whole"])
+def test_one_backward_kernel_in_a_chunk_with_an_offset(causal, monkeypatch):
+    """``flash_chunk_bwd`` with the global statistics of a query chunk that
+    follows a longer stretch of keys (the diagonal 256 to the right): the
+    one kernel and the split kernels agree, and the chunk's gradients are
+    those of plain attention over it."""
+    b, sq, sk, hq, hk, d = 1, 256, 512, 4, 2, 32
+    q, k, v = _mk(b, sq, sk, hq, hk, d, seed=43)
+    do = jnp.asarray(np.random.RandomState(44).standard_normal(
+        (b, sq, hq, d)), jnp.float32)
+    scale = 1.0 / math.sqrt(d)
+    out, lse = fa.flash_chunk_fwd(q, k, v, causal, scale, 128, 128, True)
+    delta = jnp.einsum("bshd,bshd->bhs", do, out)
+
+    def chunk():
+        return fa.flash_chunk_bwd(q, k, v, do, lse, delta, causal, scale,
+                                  128, 128, True)
+    one = chunk()
+    _, vjp = jax.vjp(lambda q, k, v: _dense_oracle(q, k, v, scale,
+                                                   causal=causal), q, k, v)
+    want = vjp(do)
+    _split(monkeypatch)
+    split = chunk()
+    for a, s_, w, name in zip(one, split, want, "qkv"):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(s_), rtol=1e-5,
+                                   atol=1e-5, err_msg=f"d{name}")
+        np.testing.assert_allclose(np.asarray(a), np.asarray(w), rtol=3e-4,
+                                   atol=3e-4, err_msg=f"d{name}")
+
+
+def test_bf16_gradients_keep_their_dtype_through_the_one_kernel():
+    q, k, v = _mk(1, 512, 512, 4, 2, 64, dtype=jnp.bfloat16, seed=45)
+    g = jax.grad(lambda q, k, v: flash_attention_ext(
+        q, k, v, None, _SEED0, None, None, True, 0.125, 0.0, 256, 256,
+        True).astype(jnp.float32).sum(), (0, 1, 2))(q, k, v)
+    assert [x.dtype for x in g] == [jnp.bfloat16] * 3
+
+
+# which calls take the one kernel, at which tile, holding how much dq:
+# (sq, sk, d, rep, window, dbias) -> (bwd tile or None, split_by, dq bytes)
+_RULE_TABLE = {
+    "gpt2s-train-s1024": ((1024, 1024, 64, 1, None, False),
+                          ((1024, 1024, 256, 256), None, 512 << 10)),
+    "mistral7b-l2-train-s4096": ((4096, 4096, 128, 4, None, False),
+                                 ((1024, 1024, 256, 256), None, 8 << 20)),
+    "glm47-flash-train-s8192": ((8192, 8192, 256, 1, None, False),
+                                ((1024, 1024, 256, 256), None, 8 << 20)),
+    "laguna-xs2-train-s8192.full": ((8192, 8192, 128, 6, None, False),
+                                    ((1024, 1024, 256, 256), None, 24 << 20)),
+    "laguna-xs2-train-s8192.window": ((8192, 8192, 128, 8, 512, False),
+                                      ((1024, 1024, 256, 256), None,
+                                       32 << 20)),
+    # a full bias: its dbias tiles come out of the dq kernel's grid
+    "full_bias": ((1024, 1024, 64, 1, None, True), (None, "dbias", 0)),
+    # 8 q heads of 16k rows over one kv head: 64 MiB of dq
+    "dq_past_the_limit": ((16384, 16384, 128, 8, None, False),
+                          (None, "vmem", 0)),
+    # a window on more queries than keys: the band's grid never visits the
+    # first query blocks
+    "window_rows_unseen": ((2048, 1024, 64, 1, 256, False),
+                           (None, "rows_unseen", 0)),
+}
+
+
+@pytest.mark.parametrize("case", list(_RULE_TABLE))
+def test_backward_rule_table(case):
+    (sq, sk, d, rep, window, dbias), (tile, split_by, dq_bytes) = \
+        _RULE_TABLE[case]
+    plan = fa.tile_plan(sq, sk, d, rep=rep, window=window, dbias=dbias,
+                        bias_bytes=4 if dbias else 0)
+    assert plan.split_by == split_by
+    if tile is None:
+        assert plan.bwd is None
+        assert set(plan.lowered()) == {"fwd", "dq", "dkv"}
+        return
+    assert plan.bwd[:4] == tile and plan.bwd.kinds == plan.dkv.kinds
+    assert set(plan.lowered()) == {"fwd", "bwd"}
+    assert fa._dq_acc_bytes(rep * -(-sq // tile[0]) * tile[0], d) == \
+        dq_bytes
+    # the same rule whether the plan's tiles or explicit blocks are asked
+    q = jax.ShapeDtypeStruct((1, sq, rep, d), jnp.bfloat16)
+    kv = jax.ShapeDtypeStruct((1, sk, 1, d), jnp.bfloat16)
+    assert fa._blocks(None, None, q, kv, kv, None, False, 0.0, True,
+                      window) == plan

@@ -107,9 +107,8 @@ def test_recomputed_blocks_hold_the_forward_kernel_only_under_full(
     # the forward pass runs each layer's kernel once, whatever is kept
     assert count(False, *fwd) == layers
     assert count(True, *fwd) == (layers if policy == "full" else 0)
-    # the backward kernels are the same under both
-    assert count(True, "flash_bwd_dq", "flash_win_bwd_dq") == layers
-    assert count(True, "flash_bwd_dkv", "flash_win_bwd_dkv") == layers
+    # the backward kernel is the same under both: one a layer
+    assert count(True, "flash_bwd", "flash_win_bwd") == layers
     # everything else in the block is replayed as under "full"
     assert count(True, "moe_gmm_fwd") == count(False, "moe_gmm_fwd") > 0
 
@@ -217,7 +216,7 @@ def test_outside_a_checkpoint_the_names_lower_to_nothing(
     the text with ``checkpoint_name`` made the identity."""
     before = sum(TILE_PLAN_TALLY.values())
     with_names = _tiny_dense_step(family)
-    # two layers' forward, dq and dkv: the step took the kernels
-    assert sum(TILE_PLAN_TALLY.values()) == before + 6
+    # two layers' forward and backward kernel: the step took the kernels
+    assert sum(TILE_PLAN_TALLY.values()) == before + 4
     monkeypatch.setattr(rc, "checkpoint_name", lambda x, name: x)
     assert _tiny_dense_step(family) == with_names

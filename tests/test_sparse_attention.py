@@ -196,8 +196,9 @@ def test_mean_attended_keys_by_hand():
 
 
 # the attention calls of the four cells the benchmark had before this one:
-# (B, S, Hq, Hkv, D, window) -> the plan and route they get (read on the
-# parent commit, PR 33; ops/pallas/flash_attention.py is not edited here)
+# (B, S, Hq, Hkv, D, window) -> the plan and route they get: the tiles read
+# on the commit before this family came, with the one backward kernel on
+# dkv's tile where dq and dkv were
 _CELL_CALLS = {
     "gpt2s": (32, 1024, 12, 12, 64, None),
     "mistral": (4, 4096, 32, 8, 128, None),
@@ -206,20 +207,21 @@ _CELL_CALLS = {
     "glm": (2, 8192, 20, 20, 256, None),
 }
 _CELL_PLANS = {
-    "gpt2s": "fwd=1024x1024/128x128 dq=1024x1024/128x128 dkv=1024x1024/256x256",
-    "mistral": "fwd=1024x1024/512x512 dq=1024x1024/128x128 dkv=1024x1024/256x256",
-    "laguna_full": "fwd=1024x1024/512x512 dq=1024x1024/128x128 dkv=1024x1024/256x256",
-    "laguna_window": "fwd=1024x1024/512x512 dq=1024x1024/128x128 dkv=1024x1024/256x256",
-    "glm": "fwd=1024x1024/512x512 dq=1024x1024/128x128 dkv=1024x1024/256x256",
+    "gpt2s": "fwd=1024x1024/128x128 bwd=1024x1024/256x256",
+    "mistral": "fwd=1024x1024/512x512 bwd=1024x1024/256x256",
+    "laguna_full": "fwd=1024x1024/512x512 bwd=1024x1024/256x256",
+    "laguna_window": "fwd=1024x1024/512x512 bwd=1024x1024/256x256",
+    "glm": "fwd=1024x1024/512x512 bwd=1024x1024/256x256",
 }
 
 
 @pytest.mark.parametrize("cell", sorted(_CELL_CALLS))
 def test_flash_plan_and_route_of_the_other_cells_are_unchanged(cell):
     b, s, hq, hkv, d, window = _CELL_CALLS[cell]
-    plan = fa.tile_plan(s, s, d, 2, 2, 2, causal=True, window=window)
+    plan = fa.tile_plan(s, s, d, 2, 2, 2, causal=True, window=window,
+                        rep=hq // hkv)
     got = " ".join(f"{n}={t.bq}x{t.bk}/{t.sub_q}x{t.sub_k}"
-                   for n, t in plan._asdict().items())
+                   for n, t in plan.lowered().items())
     assert got == _CELL_PLANS[cell]
     q = jax.ShapeDtypeStruct((b, s, hq, d), jnp.bfloat16)
     k = jax.ShapeDtypeStruct((b, s, hkv, d), jnp.bfloat16)

@@ -298,7 +298,7 @@ def test_lowered_train_step_names_optimizer_and_attention():
     assert opt_lines and not any("jvp(" in ln for ln in opt_lines)
 
 
-_KERNEL_NAMES = {"fa_mha": {"flash_fwd", "flash_bwd_dq", "flash_bwd_dkv"},
+_KERNEL_NAMES = {"fa_mha": {"flash_fwd", "flash_bwd"},
                  "rms_norm": {"rms_norm_fwd"},
                  "layer_norm": {"layer_norm_fwd"},
                  "softmax_ce": {"softmax_ce_fwd", "softmax_ce_bwd"}}

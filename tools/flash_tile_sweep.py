@@ -1,10 +1,11 @@
-"""flash_tile_sweep.py -- device time of the three flash kernels over tiles.
+"""flash_tile_sweep.py -- device time of the flash kernels over tiles.
 
     chiprun -- python3 tools/flash_tile_sweep.py            # needs a TPU
     python3 tools/flash_tile_sweep.py --compile-only        # here: does Mosaic
                                                             # take each tile?
 
-Times ``flash_fwd``, ``flash_bwd_dq`` and ``flash_bwd_dkv`` of
+Times ``flash_fwd``, ``flash_bwd_dq``, ``flash_bwd_dkv`` and the one backward
+kernel ``flash_bwd`` (``--kernels bwd``: dq, dk and dv from one sweep) of
 ``paddle_tpu/ops/pallas/flash_attention.py`` at the benchmark cells' own
 attention shapes, one layer's call each, over a grid of (block_q, block_k)
 tiles and of the (sub_q, sub_k) sub-tiles a grid step walks its tile in
@@ -27,6 +28,7 @@ times): what the chip's compiler refuses, it refuses here.
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import os
 import statistics
@@ -61,7 +63,7 @@ TILES = tuple((1024, 1024) + sub for sub in (
 PR26_TILES = tuple(t + t for t in (
     (128, 128), (256, 256), (256, 512), (512, 256), (512, 512), (256, 1024),
     (1024, 256), (512, 1024), (1024, 512), (1024, 1024)))
-KERNELS = ("fwd", "dq", "dkv")
+KERNELS = ("fwd", "dq", "dkv", "bwd")
 
 
 def build(shape, kernel, tile):
@@ -87,7 +89,8 @@ def build(shape, kernel, tile):
             return fa._fwd(q, k, v, None, None, hq, hk, True, scale, 0, s,
                            tile, maps, False, window=window)
         return fn, (q, k, v)
-    impl = fa._bwd_dq if kernel == "dq" else fa._bwd_dkv
+    impl = fa._bwd_dq if kernel == "dq" else functools.partial(
+        fa._bwd_dkv, with_dq=kernel == "bwd")
 
     def fn(q, k, v, do, lse, delta):
         return impl(q, k, v, do, lse, delta, None, None, True, scale, 0, s,
@@ -160,7 +163,8 @@ def measure(shapes, tiles, reps, kernels=KERNELS):
         lse3 = lse[..., None]
         del out
         args = {"fwd": (q, k, v), "dq": (q, k, v, do, lse3, delta),
-                "dkv": (q, k, v, do, lse3, delta)}
+                "dkv": (q, k, v, do, lse3, delta),
+                "bwd": (q, k, v, do, lse3, delta)}
         jitted = {}
         for kernel in kernels:
             for tile in tiles:
@@ -242,9 +246,9 @@ def main(argv=None):
     ap.add_argument("--shapes", default="gpt2,mistral")
     ap.add_argument("--tiles", default=",".join(_label(t) for t in TILES),
                     help="BQxBK[/SQxSK],... or pr26: PR 26's tile grid")
-    ap.add_argument("--kernels", default=",".join(KERNELS),
-                    help="of fwd,dq,dkv: a dozen programs a process is what "
-                    "one trace holds, so a long list of tiles wants one "
+    ap.add_argument("--kernels", default="fwd,bwd",
+                    help="of fwd,dq,dkv,bwd: a dozen programs a process is "
+                    "what one trace holds, so a long list of tiles wants one "
                     "kernel a call")
     ap.add_argument("--reps", type=int, default=5)
     ap.add_argument("--compile-only", action="store_true")
