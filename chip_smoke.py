@@ -135,6 +135,8 @@ CHIP = {
     "glm": {"tiny": False, "batch": 2, "seq": 8192, "steps": 3, "lr": 3e-4},
     "sala": {"tiny": False, "batch": 1, "seq": 12288, "steps": 3,
              "lr": 3e-4},
+    "qwen3_next": {"tiny": False, "batch": 1, "seq": 16384, "steps": 3,
+                   "lr": 3e-4},
     "train": {"model": "gpt2_small", "batch": 8, "seq": 1024, "steps": 8,
               "lr": 3e-4},
     "serve": {"model": "gpt2_small", "max_slots": 4, "page_len": 128,
@@ -164,6 +166,8 @@ TINY = {
     "glm": {"tiny": True, "batch": 2, "seq": 32, "steps": 3, "lr": 1e-2},
     # 64 tokens: past the tiny preset's dense_len of 32
     "sala": {"tiny": True, "batch": 2, "seq": 64, "steps": 3, "lr": 1e-2},
+    "qwen3_next": {"tiny": True, "batch": 2, "seq": 64, "steps": 3,
+                   "lr": 1e-2},
     "train": {"model": "gpt2_tiny", "batch": 2, "seq": 128, "steps": 4,
               "lr": 1e-2},
     "serve": {"model": "gpt2_tiny", "max_slots": 4, "page_len": 16,
@@ -518,33 +522,26 @@ SALA_KERNELS = ("sparse_attn_fwd", "sparse_attn_bwd_dq", "sparse_attn_bwd_dkv",
                 "linear_attn_fwd", "linear_attn_bwd")
 
 
-def leg_sala(p) -> dict:
-    """The decoder of minicpm-sala-train-s12288 (BENCHMARK.json) through the
-    trainer: published widths, one period of the mixers, an eighth of the
-    vocabulary, the layer body recomputed. The step is lowered and compiled
-    once and that program runs the steps."""
+def _lowered_step_run(name, model, p, plan_names, kernels):
+    """One bf16 training step of ``model`` lowered (tracing on, the trace-time
+    events named ``plan_names`` kept), compiled once, and run for
+    ``p["steps"]`` steps on one repeated batch. Returns the plans, the
+    lowered text's count of each of ``kernels``, the compiler's memory count
+    and the losses; raises where a kernel is missing on the chip, a step
+    compiles again, or the loss is not finite and falling."""
     import jax
     import jax.numpy as jnp
 
     import paddle_tpu as paddle
-    from paddle_tpu.models import (MiniCPMSALAConfig, MiniCPMSALAForCausalLM,
-                                   create_train_step, minicpm_sala_tiny,
-                                   run_steps)
+    from paddle_tpu.models import create_train_step, run_steps
     from paddle_tpu.profiler import tracing
 
-    paddle.seed(SEED)
-    if p["tiny"]:
-        cfg = minicpm_sala_tiny(use_recompute=True)
-    else:
-        cfg = MiniCPMSALAConfig(
-            vocab_size=9216, mixer_types=MiniCPMSALAConfig.mixer_types[:4],
-            use_recompute=True)
-    model = MiniCPMSALAForCausalLM(cfg).bfloat16()
+    model = model.bfloat16()
     model.train()
     opt = paddle.optimizer.AdamW(learning_rate=p["lr"], weight_decay=0.01,
                                  parameters=model.parameters())
     rng = np.random.RandomState(SEED)
-    ids = jnp.asarray(rng.randint(0, cfg.vocab_size,
+    ids = jnp.asarray(rng.randint(0, model.cfg.vocab_size,
                                   (p["batch"], p["seq"] + 1)), jnp.int32)
     x, y = ids[:, :-1], ids[:, 1:]
     step, params, opt_state = create_train_step(model, opt,
@@ -558,17 +555,12 @@ def leg_sala(p) -> dict:
     finally:
         tracing.disable_tracing()
         tracing.reset_tracing()
-    plans = {name: [e["args"] for e in events if e["name"] == name]
-             for name in ("sparse_attn::plan", "linear_attn::plan",
-                          "recompute::plan")}
-    sparse_layers = sum(k == "minicpm4" for k in cfg.mixer_types)
-    if len(plans["sparse_attn::plan"]) < sparse_layers or \
-            len(plans["linear_attn::plan"]) < cfg.num_layers - sparse_layers:
-        raise AssertionError(f"sala: plans {plans}")
+    plans = {n: [e["args"] for e in events if e["name"] == n]
+             for n in plan_names}
     text = lowered.as_text()
-    names = {n: text.count(n) for n in SALA_KERNELS}
+    names = {n: text.count(n) for n in kernels}
     if on_chip() and not all(names.values()):
-        raise AssertionError(f"sala: kernels missing from the lowered "
+        raise AssertionError(f"{name}: kernels missing from the lowered "
                              f"step: {names}")
     with compile_watch() as first:
         compiled = lowered.compile()
@@ -582,21 +574,96 @@ def leg_sala(p) -> dict:
             lr=p["lr"])
     losses = [float(v) for v in losses]
     if watch["compiles"]:
-        raise AssertionError(f"sala: {watch['compiles']} compile(s) after "
+        raise AssertionError(f"{name}: {watch['compiles']} compile(s) after "
                              "the step was compiled")
     if not (np.isfinite(losses).all() and losses[-1] < losses[0]
             and max(losses[1:]) < losses[0]):
-        raise AssertionError(f"sala: loss not finite and falling on one "
+        raise AssertionError(f"{name}: loss not finite and falling on one "
                              f"repeated batch: {losses}")
-    return {"losses": [round(v, 4) for v in losses],
-            "kernels_in_step": names,
+    return {"plans": plans, "kernels_in_step": names,
             "mosaic_calls_in_step": text.count("tpu_custom_call"),
+            "compiler_memory": memory, "losses": losses,
+            "compile_requests": first["compiles"]}
+
+
+def leg_sala(p) -> dict:
+    """The decoder of minicpm-sala-train-s12288 (BENCHMARK.json) through the
+    trainer: published widths, one period of the mixers, an eighth of the
+    vocabulary, the layer body recomputed. The step is lowered and compiled
+    once and that program runs the steps."""
+    import paddle_tpu as paddle
+    from paddle_tpu.models import (MiniCPMSALAConfig, MiniCPMSALAForCausalLM,
+                                   minicpm_sala_tiny)
+
+    paddle.seed(SEED)
+    if p["tiny"]:
+        cfg = minicpm_sala_tiny(use_recompute=True)
+    else:
+        cfg = MiniCPMSALAConfig(
+            vocab_size=9216, mixer_types=MiniCPMSALAConfig.mixer_types[:4],
+            use_recompute=True)
+    out = _lowered_step_run(
+        "sala", MiniCPMSALAForCausalLM(cfg), p,
+        ("sparse_attn::plan", "linear_attn::plan", "recompute::plan"),
+        SALA_KERNELS)
+    plans = out["plans"]
+    sparse_layers = sum(k == "minicpm4" for k in cfg.mixer_types)
+    if len(plans["sparse_attn::plan"]) < sparse_layers or \
+            len(plans["linear_attn::plan"]) < cfg.num_layers - sparse_layers:
+        raise AssertionError(f"sala: plans {plans}")
+    return {"losses": [round(v, 4) for v in out["losses"]],
+            "kernels_in_step": out["kernels_in_step"],
+            "mosaic_calls_in_step": out["mosaic_calls_in_step"],
             "sparse_plan": plans["sparse_attn::plan"][-1],
             "linear_plan": plans["linear_attn::plan"][-1],
             "recompute_plan": plans["recompute::plan"][0],
             "recompute_policy": cfg.recompute_policy,
-            "compiler_memory": memory,
-            "compile_requests": first["compiles"]}
+            "compiler_memory": out["compiler_memory"],
+            "compile_requests": out["compile_requests"]}
+
+
+# -- leg: qwen3_next --------------------------------------------------------
+
+QWEN3_NEXT_KERNELS = ("gated_delta_fwd", "gated_delta_bwd", "flash_fwd",
+                      "flash_bwd")
+
+
+def leg_qwen3_next(p) -> dict:
+    """The decoder of qwen3-next-train-s16384 (BENCHMARK.json) through the
+    trainer: published widths, one period (three DeltaNet layers and a full
+    one), 32 of 512 experts and an eighth of the vocabulary held, the layer
+    body recomputed. The step is lowered and compiled once and that program
+    runs the steps; prints the DeltaNet plan, the kernels of the lowered step,
+    the compiler's memory count and the device's peak."""
+    import jax
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models import (Qwen3NextConfig, Qwen3NextForCausalLM,
+                                   qwen3_next_tiny)
+
+    paddle.seed(SEED)
+    if p["tiny"]:
+        cfg = qwen3_next_tiny(experts_held=(0, 8), use_recompute=True)
+    else:
+        cfg = Qwen3NextConfig(vocab_size=18992, num_layers=4,
+                              experts_held=(0, 32), use_recompute=True)
+    out = _lowered_step_run("qwen3_next", Qwen3NextForCausalLM(cfg), p,
+                            ("gdn::plan", "moe::plan"), QWEN3_NEXT_KERNELS)
+    plans = out["plans"]
+    deltanets = sum(k == "linear_attention" for k in cfg.layer_types)
+    if len(plans["gdn::plan"]) < deltanets or \
+            len(plans["moe::plan"]) < cfg.num_layers:
+        raise AssertionError(f"qwen3_next: plans {plans}")
+    peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
+    return {"losses": [round(v, 4) for v in out["losses"]],
+            "kernels_in_step": out["kernels_in_step"],
+            "mosaic_calls_in_step": out["mosaic_calls_in_step"],
+            "gdn_plan": plans["gdn::plan"][-1],
+            "moe_plan": plans["moe::plan"][-1],
+            "recompute_policy": cfg.recompute_policy,
+            "compiler_memory": out["compiler_memory"],
+            "peak_hbm_gib": peak / 2 ** 30 if peak else None,
+            "compile_requests": out["compile_requests"]}
 
 
 # -- leg: train -------------------------------------------------------------
@@ -881,7 +948,7 @@ def leg_four_chip(p) -> dict:
 # -- driver -----------------------------------------------------------------
 
 LEGS = {"kernels": leg_kernels, "laguna": leg_laguna, "glm": leg_glm,
-        "sala": leg_sala, "train": leg_train,
+        "sala": leg_sala, "qwen3_next": leg_qwen3_next, "train": leg_train,
         "serve": leg_serve, "four_chip": leg_four_chip}
 
 

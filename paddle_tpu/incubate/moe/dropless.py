@@ -137,8 +137,11 @@ class DroplessMoE(Layer):
             weighted by their scores alone ("noaux_tc"). The rule that
             moves the bias from the routing loads during training is not
             here.
+        score: ``"sigmoid"`` (each expert's score alone) or ``"softmax"``
+            (the float32 softmax over all ``num_experts`` logits, Qwen's
+            routers).
 
-    The router's scores are a sigmoid of ``router_input @ router_weight`` in
+    The router's scores are ``score`` of ``router_input @ router_weight`` in
     float32; the k largest are chosen and their scores normalised to sum to
     one over the chosen k. ``forward(x, router_input=None)``: ``router_input``
     is x in float32 where the caller has it (a top-k is a comparison: an
@@ -152,8 +155,10 @@ class DroplessMoE(Layer):
                  top_k: int, held: Optional[Tuple[int, int]] = None,
                  shared: Optional[Layer] = None, routed_scale: float = 1.0,
                  init_std: float = 0.02, row_tile: int = ROW_TILE,
-                 score_bias: bool = False):
+                 score_bias: bool = False, score: str = "sigmoid"):
         super().__init__()
+        if score not in ("sigmoid", "softmax"):
+            raise ValueError(f"score {score!r}: 'sigmoid' or 'softmax'")
         first, count = held if held is not None else (0, num_experts)
         if not (0 <= first and count >= 1 and first + count <= num_experts):
             raise ValueError(f"held {held!r} is not a range of the "
@@ -165,6 +170,7 @@ class DroplessMoE(Layer):
         self.first, self.held = first, count
         self.routed_scale = float(routed_scale)
         self.row_tile = row_tile
+        self.score = score
         init = Normal(0.0, init_std)
         self.router_weight = self.create_parameter(
             [d_model, num_experts], default_initializer=init)
@@ -191,7 +197,8 @@ class DroplessMoE(Layer):
         logits = jnp.dot(x32.astype(jnp.float32),
                          router_weight.astype(jnp.float32),
                          precision=_HIGHEST)
-        scores = jax.nn.sigmoid(logits)
+        scores = jax.nn.softmax(logits, axis=-1) if self.score == "softmax" \
+            else jax.nn.sigmoid(logits)
         if bias is None:
             top_s, top_i = jax.lax.top_k(scores, self.top_k)
         else:
@@ -212,6 +219,8 @@ class DroplessMoE(Layer):
             expected_rows=tokens * k * self.held // self.num_experts,
             buffer_rows=padded_rows(tokens * k, self.held, tm), row_tile=tm,
             score_bias=self.e_score_correction_bias is not None,
+            score=self.score, shared=type(self.shared).__name__
+            if self.shared is not None else None,
             gate_up_tile="%dx%d" % gmm_tiles(d, 2 * f),
             down_tile="%dx%d" % gmm_tiles(f, d))
 

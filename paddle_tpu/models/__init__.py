@@ -20,6 +20,8 @@ from .glm_moe_lite import (GlmMoeLiteConfig, GlmMoeLiteForCausalLM,  # noqa: F40
                            MLAttention, glm_moe_lite_tiny)
 from .minicpm_sala import (MiniCPMSALAConfig, MiniCPMSALAForCausalLM,  # noqa: F401
                            LightningMixer, SparseMixer, minicpm_sala_tiny)
+from .qwen3_next import (Qwen3NextConfig, Qwen3NextForCausalLM,  # noqa: F401
+                         qwen3_next_tiny)
 from .decode import (ContiguousKV, decode_attention,  # noqa: F401
                      init_contiguous_cache)
 from .trainer import (create_multistep_train_step,  # noqa: F401

@@ -88,6 +88,21 @@ def test_leg_glm_tiny(interpret_kernels):
     assert out["flash_fwd_calls_in_step"] == 4
 
 
+def test_leg_qwen3_next_tiny(interpret_kernels):
+    out = chip_smoke.leg_qwen3_next(chip_smoke.TINY["qwen3_next"])
+    assert out["losses"][-1] < out["losses"][0]
+    # interpreted kernels leave no name in the lowered step; the plan says
+    # that the DeltaNet layers took the kernels' path
+    assert set(out["kernels_in_step"]) == set(chip_smoke.QWEN3_NEXT_KERNELS)
+    assert (out["gdn_plan"]["path"], out["gdn_plan"]["chunk"],
+            out["gdn_plan"]["heads"]) == ("kernel", 64, 4)
+    assert (out["moe_plan"]["score"], out["moe_plan"]["shared"]) == (
+        "softmax", "GatedSharedExpert")
+    assert out["recompute_policy"] == "flash_saveable"
+    assert out["compiler_memory"]["argument_size_in_bytes"] > 0
+    assert out["compile_requests"] == 1
+
+
 def test_leg_sala_tiny(interpret_kernels):
     out = chip_smoke.leg_sala(chip_smoke.TINY["sala"])
     assert out["losses"][-1] < out["losses"][0]

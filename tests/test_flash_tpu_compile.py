@@ -19,6 +19,7 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from paddle_tpu.ops.pallas import flash_attention as fa
+from paddle_tpu.ops.pallas import gated_delta as gd
 from paddle_tpu.ops.pallas import grouped_matmul as gm
 from paddle_tpu.ops.pallas import linear_attention as la
 from paddle_tpu.ops.pallas import sparse_attention as sa
@@ -352,6 +353,27 @@ def test_linear_attention_compiles_for_v5e(s, chunk, one_chip,
     text = jax.jit(jax.grad(loss, (0, 1, 2))).lower(
         x, x, x, arg((32,), "float32")).compile().as_text()
     assert "linear_attn_fwd" in text and "linear_attn_bwd" in text
+
+
+@pytest.mark.parametrize("s,chunk", [(16384, None), (16384, 64),
+                                     (1100, None)])
+def test_gated_delta_compiles_for_v5e(s, chunk, one_chip,
+                                      production_numerics):
+    """The delta rule's two kernels at qwen3-next-train-s16384's shape: 32
+    value heads over 16 key heads of 128, the plan's chunk and the other one
+    swept, and a ragged length."""
+    def arg(shape, dtype="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    def loss(q, k, v, g, beta):
+        return gd.gated_delta(q, k, v, g, beta, chunk, False).astype(
+            jnp.float32).sum()
+    qk = arg((1, s, 16, 128))
+    text = jax.jit(jax.grad(loss, (0, 1, 2, 3, 4))).lower(
+        qk, qk, arg((1, s, 32, 128)), arg((1, s, 32), "float32"),
+        arg((1, s, 32), "float32")).compile().as_text()
+    assert "gated_delta_fwd" in text and "gated_delta_bwd" in text
 
 
 @pytest.mark.parametrize("policy", ["sala_saveable", "full"])
