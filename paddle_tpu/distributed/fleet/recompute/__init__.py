@@ -65,8 +65,17 @@ _POLICIES = {
     # a quarter of the contracted width: past a few thousand keys the
     # forward kernel is the dearest thing in the block to replay. The names
     # are given in ops/pallas/flash_attention.py::_fa_fwd: ``out`` [B, S,
-    # Hq, D] and the softmax statistics ``lse`` [B x Hq, S] float32.
-    "flash_saveable": ("flash_out", "flash_lse"),
+    # Hq, D] and the softmax statistics ``lse`` [B x Hq, S] float32. The
+    # gated delta rule's forward kernel is spared the same way (names given
+    # in ops/pallas/gated_delta.py::_gdn_fwd): its output ``gdn_out`` [B, S,
+    # Hv, dv] in the activations' dtype and the chunk states it parks for
+    # the backward kernel, ``gdn_states`` [B x Hv, chunks, dk, dv] float32,
+    # B x S x Hv x dv x itemsize + 4 x B x Hv x chunks x dk x dv bytes a
+    # call: at S 16384, 32 value heads of 128 in bfloat16, 128 MiB + 256 MiB
+    # a layer against a forward kernel of 15.02 ms on a TPU v5e (PERF.md).
+    # A block that makes neither call names nothing and lowers as under
+    # "full".
+    "flash_saveable": ("flash_out", "flash_lse", "gdn_out", "gdn_states"),
     # a block whose mixer is attention over chosen key blocks
     # (F.block_sparse_attention): what is dear and small there is the choice,
     # ``sparse_choice`` [B, Hkv, S, blocks] bool (a byte a block, token and kv
@@ -140,9 +149,10 @@ def _remat_functional(function, args, kwargs, policy=None):
     recompute saves everything-at-boundaries; 'dots_saveable'/'selective'
     keep matmul outputs so the backward re-runs only elementwise work;
     'flash_saveable' replays everything but the flash forward kernel, whose
-    output and statistics it keeps; 'sala_saveable' likewise for a block
-    of chosen-block attention, and keeps the choice). Leaves a
-    ``recompute::plan`` event."""
+    output and statistics it keeps, and the gated delta rule's forward
+    kernel, whose output and chunk states it keeps; 'sala_saveable'
+    likewise for a block of chosen-block attention, and keeps the choice).
+    Leaves a ``recompute::plan`` event."""
     tensor_idx = [i for i, a in enumerate(args) if isinstance(a, Tensor)]
     arrays = [args[i]._data for i in tensor_idx]
     sg = [args[i].stop_gradient for i in tensor_idx]
@@ -183,7 +193,8 @@ def recompute(function, *args, **kwargs):
     ``out`` and ``lse`` kept (B x S x Hq x D x itemsize + 4 x B x Hq x S
     bytes a call), so the forward kernel is not run a second time: worth
     it from a few thousand keys on, since a byte of ``out`` saves as many
-    FLOPs as a query sees keys. The eager tape path always replays the
+    FLOPs as a query sees keys; it keeps the gated delta rule's forward
+    output and chunk states likewise. The eager tape path always replays the
     whole segment (the reference behavior)."""
     kwargs.pop("use_reentrant", None)
     preserve_rng = kwargs.pop("preserve_rng_state", True)

@@ -101,8 +101,10 @@ class Qwen3NextConfig:
     rms_norm_eps: float = 1e-6
     max_position_embeddings: int = 262144
     use_recompute: bool = False
-    # the full layer's flash forward kernel is the dearest thing in its block
-    # to replay (fleet.recompute's policies; "full" replays it too)
+    # the forward kernels are the dearest things in a block to replay: the
+    # full layer's flash call and the DeltaNet layers' delta rule keep their
+    # outputs (and the flash statistics, the delta rule's chunk states) for
+    # the backward pass (fleet.recompute's policies; "full" replays them)
     recompute_policy: str = "flash_saveable"
     lm_ce: str = "blockwise"
     layer_types: Tuple[str, ...] = field(init=False)

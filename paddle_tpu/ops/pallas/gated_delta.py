@@ -406,7 +406,12 @@ def _gdn_fwd(q, k, v, gam, beta, c, interpret):
     rep = v.shape[2] // q.shape[2]
     ops = _operands(q, k, v, gam, beta, c)
     out3, states = _fwd(*ops, c=c, rep=rep, interpret=interpret)
-    return _heads_last(out3, b, s), (q, k, v, gam, beta, states)
+    # a recomputed block may keep these two (recompute's "flash_saveable")
+    # and spare this kernel's second run; elsewhere a name is the identity
+    from ...distributed.fleet.recompute import keep
+    out = keep(_heads_last(out3, b, s), "gdn_out")
+    states = keep(states, "gdn_states")
+    return out, (q, k, v, gam, beta, states)
 
 
 def _gdn_bwd(c, interpret, res, dout):

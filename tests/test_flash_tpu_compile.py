@@ -422,6 +422,54 @@ def test_a_replayed_sala_block_keeps_its_choice_and_its_sweep(
         "linear_attn_bwd": 1}
 
 
+@pytest.mark.parametrize("policy", ["flash_saveable", "full"])
+def test_a_replayed_deltanet_block_keeps_its_forward_kernels_results(
+        policy, one_chip, production_numerics, monkeypatch):
+    """A step of the Qwen3-Next family (a DeltaNet layer, 4 value heads of
+    128 over 2 key heads, and a gated full layer of 4 heads, without GQA so
+    that its attention is a flash call at 1,024 tokens; layer bodies
+    recomputed) compiled for the described chip: under the family's default
+    each forward kernel is lowered once, the delta rule's output and chunk
+    states and the flash call's output and statistics kept for the backward
+    kernels; under "full" the replay runs both again."""
+    import re
+
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu import models
+    from paddle_tpu.ops.pallas import common
+
+    monkeypatch.setattr(common, "on_tpu", lambda: True)
+    paddle.seed(0)
+    cfg = models.qwen3_next_tiny(
+        vocab_size=512, hidden_size=256, num_layers=2,
+        full_attention_interval=2, num_attention_heads=4,
+        num_key_value_heads=4, head_dim=128, linear_num_key_heads=2,
+        linear_num_value_heads=4, linear_key_head_dim=128,
+        linear_value_head_dim=128, max_position_embeddings=1024,
+        lm_ce="blockwise", use_recompute=True, recompute_policy=policy)
+    model = models.Qwen3NextForCausalLM(cfg).bfloat16()
+    model.train()
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                 parameters=model.parameters())
+    step, params, opt_state = models.create_train_step(model, opt)
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    ids = described(np.zeros((1, 1024), np.int32))
+    text = step.lower(
+        jax.tree.map(described, params), jax.tree.map(described, opt_state),
+        described(jax.random.key(0)), ids, ids, 1e-3).compile().as_text()
+    calls = collections.Counter(
+        re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*tpu_custom_call", text))
+    twice = 1 if policy == "flash_saveable" else 2
+    assert {k: n for k, n in calls.items()
+            if k.startswith(("gated_delta_", "flash_"))} == {
+        "gated_delta_fwd": twice, "gated_delta_bwd": 1, "flash_fwd": twice,
+        "flash_bwd": 1}
+
+
 def test_a_matrix_update_is_no_epilogue_of_its_weight_gradient(
         one_chip, production_numerics):
     """The step of one 1024-wide SwiGLU block (bf16 weights, AdamW) compiled
