@@ -137,6 +137,8 @@ CHIP = {
              "lr": 3e-4},
     "qwen3_next": {"tiny": False, "batch": 1, "seq": 16384, "steps": 3,
                    "lr": 3e-4},
+    "nemotron_h": {"tiny": False, "batch": 1, "seq": 16384, "steps": 3,
+                   "lr": 3e-4},
     "train": {"model": "gpt2_small", "batch": 8, "seq": 1024, "steps": 8,
               "lr": 3e-4},
     "serve": {"model": "gpt2_small", "max_slots": 4, "page_len": 128,
@@ -167,6 +169,9 @@ TINY = {
     # 64 tokens: past the tiny preset's dense_len of 32
     "sala": {"tiny": True, "batch": 2, "seq": 64, "steps": 3, "lr": 1e-2},
     "qwen3_next": {"tiny": True, "batch": 2, "seq": 64, "steps": 3,
+                   "lr": 1e-2},
+    # 64 tokens: two chunks of the tiny preset's 32
+    "nemotron_h": {"tiny": True, "batch": 2, "seq": 64, "steps": 3,
                    "lr": 1e-2},
     "train": {"model": "gpt2_tiny", "batch": 2, "seq": 128, "steps": 4,
               "lr": 1e-2},
@@ -666,6 +671,53 @@ def leg_qwen3_next(p) -> dict:
             "compile_requests": out["compile_requests"]}
 
 
+# -- leg: nemotron_h --------------------------------------------------------
+
+NEMOTRON_H_KERNELS = ("ssd_fwd", "ssd_bwd", "flash_fwd", "flash_bwd",
+                      "moe_gmm")
+
+
+def leg_nemotron_h(p) -> dict:
+    """The decoder of nemotron3-nano-train-s16384 (BENCHMARK.json) through the
+    trainer: published widths, the pattern's first attention period
+    ``MEMEM*EME``, 8 of 128 experts and an eighth of the vocabulary held, the
+    layer body recomputed. The step is lowered and compiled once and that
+    program runs the steps; prints the SSD and expert plans, the kernels of
+    the lowered step, the compiler's memory count (arguments, temporaries)
+    and the device's peak."""
+    import jax
+
+    import paddle_tpu as paddle
+    from paddle_tpu.models import (NemotronHConfig, NemotronHForCausalLM,
+                                   nemotron_h_tiny)
+
+    paddle.seed(SEED)
+    if p["tiny"]:
+        # one layer of each kind
+        cfg = nemotron_h_tiny(experts_held=(0, 8), use_recompute=True,
+                              hybrid_override_pattern="ME*")
+    else:
+        cfg = NemotronHConfig(vocab_size=16384,
+                              hybrid_override_pattern="MEMEM*EME",
+                              experts_held=(0, 8), use_recompute=True)
+    out = _lowered_step_run("nemotron_h", NemotronHForCausalLM(cfg), p,
+                            ("ssd::plan", "moe::plan"), NEMOTRON_H_KERNELS)
+    plans = out["plans"]
+    if len(plans["ssd::plan"]) < cfg.layer_types.count("M") or \
+            len(plans["moe::plan"]) < cfg.layer_types.count("E"):
+        raise AssertionError(f"nemotron_h: plans {plans}")
+    peak = (jax.devices()[0].memory_stats() or {}).get("peak_bytes_in_use")
+    return {"losses": [round(v, 4) for v in out["losses"]],
+            "kernels_in_step": out["kernels_in_step"],
+            "mosaic_calls_in_step": out["mosaic_calls_in_step"],
+            "ssd_plan": plans["ssd::plan"][-1],
+            "moe_plan": plans["moe::plan"][-1],
+            "recompute_policy": cfg.recompute_policy,
+            "compiler_memory": out["compiler_memory"],
+            "peak_hbm_gib": peak / 2 ** 30 if peak else None,
+            "compile_requests": out["compile_requests"]}
+
+
 # -- leg: train -------------------------------------------------------------
 
 def leg_train(p) -> dict:
@@ -948,7 +1000,8 @@ def leg_four_chip(p) -> dict:
 # -- driver -----------------------------------------------------------------
 
 LEGS = {"kernels": leg_kernels, "laguna": leg_laguna, "glm": leg_glm,
-        "sala": leg_sala, "qwen3_next": leg_qwen3_next, "train": leg_train,
+        "sala": leg_sala, "qwen3_next": leg_qwen3_next,
+        "nemotron_h": leg_nemotron_h, "train": leg_train,
         "serve": leg_serve, "four_chip": leg_four_chip}
 
 

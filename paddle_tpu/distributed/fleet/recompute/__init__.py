@@ -73,9 +73,13 @@ _POLICIES = {
     # B x S x Hv x dv x itemsize + 4 x B x Hv x chunks x dk x dv bytes a
     # call: at S 16384, 32 value heads of 128 in bfloat16, 128 MiB + 256 MiB
     # a layer against a forward kernel of 15.02 ms on a TPU v5e (PERF.md).
-    # A block that makes neither call names nothing and lowers as under
-    # "full".
-    "flash_saveable": ("flash_out", "flash_lse", "gdn_out", "gdn_states"),
+    # Mamba-2's SSD scan likewise (ops/pallas/ssd.py::_ssd_fwd): ``ssd_out``
+    # [B, S, H, P] and ``ssd_states`` [B x H, chunks, P, N] float32, 128 MiB
+    # + 256 MiB a layer at S 16384, 64 heads of 64, state 128.
+    # A block that makes none of these calls names nothing and lowers as
+    # under "full".
+    "flash_saveable": ("flash_out", "flash_lse", "gdn_out", "gdn_states",
+                       "ssd_out", "ssd_states"),
     # a block whose mixer is attention over chosen key blocks
     # (F.block_sparse_attention): what is dear and small there is the choice,
     # ``sparse_choice`` [B, Hkv, S, blocks] bool (a byte a block, token and kv
@@ -107,8 +111,17 @@ def _resolve_policy(policy):
 
 
 # the residuals named inside each block being traced (innermost last):
-# [name, bytes] pairs, read by ``_remat_functional`` for its plan
+# [name, bytes] pairs, read by ``_remat_functional`` for its plan; beside
+# them the names each block's policy keeps
 _NAMED = []
+_KEEPS = []
+
+
+def replayed(name) -> bool:
+    """Whether the backward pass of the block being traced replays the value
+    that ``keep`` names ``name``: inside a block whose policy does not keep
+    it by name. Outside any block nothing is replayed."""
+    return bool(_KEEPS) and name not in _KEEPS[-1]
 
 
 def keep(x, name):
@@ -121,6 +134,12 @@ def keep(x, name):
     return checkpoint_name(x, name)
 
 
+def _kept_names(policy) -> tuple:
+    """The names a policy keeps by name (none for a callable one)."""
+    entry = None if callable(policy) else _POLICIES.get(policy)
+    return entry if isinstance(entry, tuple) else ()
+
+
 def _record_plan(policy, named):
     """One ``recompute::plan`` event and one count in RECOMPUTE_PLAN_TALLY
     for a block lowered through ``_remat_functional``: the policy, the
@@ -128,11 +147,11 @@ def _record_plan(policy, named):
     of what ``keep`` named inside; a callable policy keeps what only it
     knows: None)."""
     from ....profiler.tracing import trace_event
+    names = _kept_names(policy)
     if callable(policy):
-        label, names, kept = getattr(policy, "__name__", "callable"), (), None
+        label, kept = getattr(policy, "__name__", "callable"), None
     else:
-        label, entry = policy or "full", _POLICIES[policy]
-        names = entry if isinstance(entry, tuple) else ()
+        label = policy or "full"
         kept = sum(b for n, b in named if n in names)
     trace_event("recompute::plan", cat="model", policy=label,
                 names=",".join(names), kept_bytes=kept,
@@ -157,6 +176,7 @@ def _remat_functional(function, args, kwargs, policy=None):
     arrays = [args[i]._data for i in tensor_idx]
     sg = [args[i].stop_gradient for i in tensor_idx]
     meta, named = {}, []
+    keeps = _kept_names(policy)
 
     def pure(*arrs):
         call = list(args)
@@ -165,10 +185,12 @@ def _remat_functional(function, args, kwargs, policy=None):
         # only while the block's own body is traced: the custom_vjp rules
         # of what is inside are traced later, outside this frame
         _NAMED.append(named)
+        _KEEPS.append(keeps)
         try:
             out = function(*call, **kwargs)
         finally:
             _NAMED.pop()
+            _KEEPS.pop()
         single = not isinstance(out, (tuple, list))
         meta["single"] = single
         outs = (out,) if single else tuple(out)

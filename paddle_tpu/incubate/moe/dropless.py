@@ -1,4 +1,5 @@
-"""DroplessMoE: top-k SwiGLU experts with no capacity, over a grouped matmul.
+"""DroplessMoE: top-k experts (SwiGLU, or non-gated squared ReLU) with no
+capacity, over a grouped matmul.
 
 The expert layer of today's fine-grained mixtures (hundreds of narrow SwiGLU
 experts, sigmoid scores, the chosen k renormalised and scaled, a shared
@@ -8,12 +9,12 @@ the layer is told which experts it holds, ``(first, held)`` of
 chosen expert's weight as normalised over all k chosen, and computes the
 part of the result that its own experts give: the assignments that land on
 a held expert are sorted by expert into one buffer, two grouped matrix
-products (gate and up in one, then down; ``ops/pallas/grouped_matmul.py``)
-run over the held experts, and the rows are combined back by weight. What
-the absent experts would add is left out, and nothing stands in for the
-other chips or their exchange. No token is dropped and there is no
-capacity: the buffers take any routing, and the grouped products skip the
-tiles that hold no rows. With ``held == num_experts`` it is the whole layer.
+products (gate and up in one, then down; ``ops/pallas/grouped_matmul.py``;
+up alone for a squared-ReLU expert) run over the held experts, and the rows
+are combined back by weight. What the absent experts would add is left out,
+and nothing stands in for the other chips or their exchange. No token is
+dropped and there is no capacity: the buffers take any routing, and the
+grouped products skip the tiles that hold no rows. With ``held == num_experts`` it is the whole layer.
 
 ``MoELayer`` (capacity gates over dense one-hot dispatch) keeps its place
 beside it.
@@ -116,8 +117,7 @@ _combine.defvjp(_combine_fwd, _combine_bwd)
 
 
 class DroplessMoE(Layer):
-    """Top-k SwiGLU experts, dropless, on the chip that holds ``held`` of
-    them.
+    """Top-k experts, dropless, on the chip that holds ``held`` of them.
 
     Args:
         d_model, d_expert: token and expert widths.
@@ -140,6 +140,10 @@ class DroplessMoE(Layer):
         score: ``"sigmoid"`` (each expert's score alone) or ``"softmax"``
             (the float32 softmax over all ``num_experts`` logits, Qwen's
             routers).
+        activation: ``"swiglu"`` (``down(silu(gate x) * up x)``: leaves
+            ``gate_proj``, ``up_proj``, ``down_proj``) or ``"relu2"``
+            (``down(relu(up x)^2)``, Nemotron-H's non-gated experts: no
+            ``gate_proj`` leaf is made).
 
     The router's scores are ``score`` of ``router_input @ router_weight`` in
     float32; the k largest are chosen and their scores normalised to sum to
@@ -155,10 +159,14 @@ class DroplessMoE(Layer):
                  top_k: int, held: Optional[Tuple[int, int]] = None,
                  shared: Optional[Layer] = None, routed_scale: float = 1.0,
                  init_std: float = 0.02, row_tile: int = ROW_TILE,
-                 score_bias: bool = False, score: str = "sigmoid"):
+                 score_bias: bool = False, score: str = "sigmoid",
+                 activation: str = "swiglu"):
         super().__init__()
         if score not in ("sigmoid", "softmax"):
             raise ValueError(f"score {score!r}: 'sigmoid' or 'softmax'")
+        if activation not in ("swiglu", "relu2"):
+            raise ValueError(f"activation {activation!r}: 'swiglu' or "
+                             f"'relu2'")
         first, count = held if held is not None else (0, num_experts)
         if not (0 <= first and count >= 1 and first + count <= num_experts):
             raise ValueError(f"held {held!r} is not a range of the "
@@ -171,11 +179,13 @@ class DroplessMoE(Layer):
         self.routed_scale = float(routed_scale)
         self.row_tile = row_tile
         self.score = score
+        self.activation = activation
         init = Normal(0.0, init_std)
         self.router_weight = self.create_parameter(
             [d_model, num_experts], default_initializer=init)
-        self.gate_proj = self.create_parameter(
-            [count, d_model, d_expert], default_initializer=init)
+        self.gate_proj = None if activation == "relu2" else \
+            self.create_parameter([count, d_model, d_expert],
+                                  default_initializer=init)
         self.up_proj = self.create_parameter(
             [count, d_model, d_expert], default_initializer=init)
         self.down_proj = self.create_parameter(
@@ -219,9 +229,11 @@ class DroplessMoE(Layer):
             expected_rows=tokens * k * self.held // self.num_experts,
             buffer_rows=padded_rows(tokens * k, self.held, tm), row_tile=tm,
             score_bias=self.e_score_correction_bias is not None,
-            score=self.score, shared=type(self.shared).__name__
+            score=self.score, activation=self.activation,
+            shared=type(self.shared).__name__
             if self.shared is not None else None,
-            gate_up_tile="%dx%d" % gmm_tiles(d, 2 * f),
+            gate_up_tile="%dx%d" % gmm_tiles(
+                d, f if self.gate_proj is None else 2 * f),
             down_tile="%dx%d" % gmm_tiles(f, d))
 
     def forward(self, x, router_input=None):
@@ -232,8 +244,12 @@ class DroplessMoE(Layer):
         k, f, tm = self.top_k, self.d_expert, self.row_tile
         first, held = self.first, self.held
         self._plan(t)
+        experts = (self.up_proj, self.down_proj) if self.gate_proj is None \
+            else (self.gate_proj, self.up_proj, self.down_proj)
+        n_w = len(experts)
 
-        def fn(xt, x32, wr, wg, wu, wd, *bias):
+        def fn(xt, x32, wr, *rest):
+            wd, bias = rest[n_w - 1], rest[n_w:]
             tok = xt.reshape(t, self.d_model)
             top_i, w = self.route(x32.reshape(t, self.d_model), wr, *bias)
             lay = group_layout(top_i.reshape(-1), first, held, tm)
@@ -242,10 +258,15 @@ class DroplessMoE(Layer):
             dest = lay.dest.reshape(t, k)
             here = dest < rows
             xp = _dispatch(tok, row_tok, dest, here)
-            gu = grouped_matmul(xp, jnp.concatenate([wg, wu], axis=-1),
-                                lay.tile_group, lay.n_tiles)
-            act = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
-                   * gu[:, f:].astype(jnp.float32)).astype(xt.dtype)
+            if n_w == 2:
+                up = grouped_matmul(xp, rest[0], lay.tile_group, lay.n_tiles)
+                act = jnp.square(jax.nn.relu(up.astype(jnp.float32))
+                                 ).astype(xt.dtype)
+            else:
+                gu = grouped_matmul(xp, jnp.concatenate(rest[:2], axis=-1),
+                                    lay.tile_group, lay.n_tiles)
+                act = (jax.nn.silu(gu[:, :f].astype(jnp.float32))
+                       * gu[:, f:].astype(jnp.float32)).astype(xt.dtype)
             yp = grouped_matmul(act, wd, lay.tile_group, lay.n_tiles)
             routed = _combine(yp, w, row_tok, lay.row_src, dest, here)
             return (routed * self.routed_scale).reshape(shape), lay.sizes, \
@@ -255,8 +276,7 @@ class DroplessMoE(Layer):
         routed, load, choice = run_op(
             "moe_dropless", fn,
             (x, x if router_input is None else router_input,
-             self.router_weight, self.gate_proj, self.up_proj,
-             self.down_proj) + (() if bias is None else (bias,)),
+             self.router_weight) + experts + (() if bias is None else (bias,)),
             num_nondiff_outputs=2)
         self.expert_load, self.expert_choice = load, choice
         if self.shared is None:

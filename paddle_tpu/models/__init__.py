@@ -22,6 +22,8 @@ from .minicpm_sala import (MiniCPMSALAConfig, MiniCPMSALAForCausalLM,  # noqa: F
                            LightningMixer, SparseMixer, minicpm_sala_tiny)
 from .qwen3_next import (Qwen3NextConfig, Qwen3NextForCausalLM,  # noqa: F401
                          qwen3_next_tiny)
+from .nemotron_h import (NemotronHConfig, NemotronHForCausalLM,  # noqa: F401
+                         nemotron_h_tiny)
 from .decode import (ContiguousKV, decode_attention,  # noqa: F401
                      init_contiguous_cache)
 from .trainer import (create_multistep_train_step,  # noqa: F401

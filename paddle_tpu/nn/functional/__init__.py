@@ -13,6 +13,7 @@ from .block_sparse_attention import (  # noqa: F401
     block_sparse_attention, lightning_attention, select_attention_blocks,
 )
 from .gated_delta_rule import gated_delta_rule  # noqa: F401
+from .ssd import ssd_scan  # noqa: F401
 from ..decode import gather_tree  # noqa: F401
 from ...tensor.creation import diag_embed  # noqa: F401
 from ...tensor.math import pdist  # noqa: F401

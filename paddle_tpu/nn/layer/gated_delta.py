@@ -25,21 +25,12 @@ import numpy as np
 
 from ...core.dispatch import run_op
 from .. import functional as F
+from ..functional.causal_conv import causal_depthwise_conv
 from ..initializer import Constant, Normal
 from .common import Linear
 from .layers import Layer
 
 __all__ = ["GatedDeltaNet"]
-
-
-def _causal_depthwise_conv(x, w):
-    """x [B, S, C], w [C, K] -> [B, S, C] float32: ``y_t = sum_j w[:, j]
-    x_(t - K + 1 + j)``, zeros before the first token."""
-    k = w.shape[1]
-    s = x.shape[1]
-    xp = jnp.pad(x.astype(jnp.float32), ((0, 0), (k - 1, 0), (0, 0)))
-    wf = w.astype(jnp.float32)
-    return sum(xp[:, j:j + s] * wf[:, j] for j in range(k))
 
 
 class GatedDeltaNet(Layer):
@@ -111,7 +102,7 @@ class GatedDeltaNet(Layer):
             mixed = jnp.concatenate([q.reshape(b, s, hk * dk),
                                      k.reshape(b, s, hk * dk),
                                      v.reshape(b, s, hv * dv)], axis=-1)
-            mixed = jax.nn.silu(_causal_depthwise_conv(mixed, conv_w)
+            mixed = jax.nn.silu(causal_depthwise_conv(mixed, conv_w)
                                 ).astype(qkvz.dtype)
             q = mixed[..., :hk * dk].reshape(b, s, hk, dk)
             k = mixed[..., hk * dk:2 * hk * dk].reshape(b, s, hk, dk)

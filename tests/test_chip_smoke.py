@@ -88,6 +88,20 @@ def test_leg_glm_tiny(interpret_kernels):
     assert out["flash_fwd_calls_in_step"] == 4
 
 
+def test_leg_nemotron_h_tiny(interpret_kernels):
+    out = chip_smoke.leg_nemotron_h(chip_smoke.TINY["nemotron_h"])
+    assert out["losses"][-1] < out["losses"][0]
+    # interpreted kernels leave no name in the lowered step; the plan says
+    # that the Mamba-2 layers took the kernels' path, their forward kept
+    assert set(out["kernels_in_step"]) == set(chip_smoke.NEMOTRON_H_KERNELS)
+    assert (out["ssd_plan"]["path"], out["ssd_plan"]["chunk"],
+            out["ssd_plan"]["chunks"], out["ssd_plan"]["forward"]) == (
+        "kernel", 32, 2, "kept")
+    assert (out["moe_plan"]["activation"], out["moe_plan"]["shared"],
+            out["moe_plan"]["score_bias"]) == ("relu2", "Relu2MLP", True)
+    assert out["recompute_policy"] == "flash_saveable"
+
+
 def test_leg_qwen3_next_tiny(interpret_kernels):
     out = chip_smoke.leg_qwen3_next(chip_smoke.TINY["qwen3_next"])
     assert out["losses"][-1] < out["losses"][0]

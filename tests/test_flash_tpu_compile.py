@@ -509,3 +509,74 @@ def test_a_matrix_update_is_no_epilogue_of_its_weight_gradient(
     assert len(products) >= 3 * 7 and len(updates) >= matrices
     assert not products & updates, {
         k: held[k]["out"] for k in products & updates}
+
+
+# nemotron3-nano-train-s16384's Mamba-2 scan at the cell's widths (64 heads
+# of 64 over 8 groups of state 128, chunk 128) and a sequence the chunk does
+# not divide
+@pytest.mark.parametrize("s", [2048, 1000])
+def test_the_ssd_kernels_compile_for_v5e(s, one_chip, production_numerics):
+    from paddle_tpu.ops.pallas import ssd as sd
+
+    def arg(shape, dtype="bfloat16"):
+        return jax.ShapeDtypeStruct(shape, jnp.dtype(dtype),
+                                    sharding=one_chip)
+
+    def loss(x, dt, a, b, c):
+        return sd.ssd(x, dt, a, b, c, 128, False).astype(jnp.float32).sum()
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3, 4))).lower(
+        arg((1, s, 64, 64)), arg((1, s, 64), "float32"),
+        arg((64,), "float32"), arg((1, s, 8, 128)),
+        arg((1, s, 8, 128))).compile()
+    text = compiled.as_text()
+    for name in ("ssd_fwd", "ssd_bwd"):
+        assert name in text, name
+    # dt and G ride as lane-dense rows: the parked states (64 heads x 16
+    # chunks x 32 KiB = 32 MiB at 2,048 tokens) are most of the temporaries
+    assert compiled.memory_analysis().temp_size_in_bytes < 80 * 2 ** 20
+
+
+@pytest.mark.parametrize("policy", ["flash_saveable", "full"])
+def test_a_replayed_mamba_block_keeps_its_forward_kernels_results(
+        policy, one_chip, production_numerics, monkeypatch):
+    """A step of the Nemotron-H family (a Mamba-2 layer of 8 heads of 64
+    over one group of state 128, and an attention layer of 4 heads of 128,
+    without GQA so that it is a flash call at 1,024 tokens; layer bodies
+    recomputed) compiled for the described chip: under the family's default
+    each forward kernel is lowered once, the scan's output and chunk states
+    and the flash call's output and statistics kept for the backward
+    kernels; under "full" the replay runs both again."""
+    import re
+
+    import numpy as np
+
+    import paddle_tpu as paddle
+    from paddle_tpu import models
+    from paddle_tpu.ops.pallas import common
+
+    monkeypatch.setattr(common, "on_tpu", lambda: True)
+    paddle.seed(0)
+    cfg = models.nemotron_h_tiny(
+        vocab_size=512, hidden_size=256, hybrid_override_pattern="M*",
+        num_attention_heads=4, num_key_value_heads=4, head_dim=128,
+        mamba_num_heads=8, mamba_head_dim=64, n_groups=1,
+        ssm_state_size=128, chunk_size=128, lm_ce="blockwise",
+        use_recompute=True, recompute_policy=policy)
+    model = models.NemotronHForCausalLM(cfg).bfloat16()
+    model.train()
+    opt = paddle.optimizer.AdamW(learning_rate=1e-3,
+                                 parameters=model.parameters())
+    step, params, opt_state = models.create_train_step(model, opt)
+
+    def described(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip)
+    ids = described(np.zeros((1, 1024), np.int32))
+    text = step.lower(
+        jax.tree.map(described, params), jax.tree.map(described, opt_state),
+        described(jax.random.key(0)), ids, ids, 1e-3).compile().as_text()
+    calls = collections.Counter(
+        re.findall(r"%(\w+?)(?:\.\d+)? = [^\n]*tpu_custom_call", text))
+    twice = 1 if policy == "flash_saveable" else 2
+    got = {k: n for k, n in calls.items() if k.startswith(("ssd_", "flash_"))}
+    assert got == {"ssd_fwd": twice, "ssd_bwd": 1, "flash_fwd": twice,
+                   "flash_bwd": 1}

@@ -22,7 +22,7 @@ import sys
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 WORKER = """
-import os, signal, sys
+import os, signal, sys, time
 from paddle_tpu.distributed.store import TCPStore
 
 rank = int(os.environ["PADDLE_TRAINER_ID"])
@@ -31,12 +31,20 @@ node = os.environ["PADDLE_NODE_RANK"]
 marker_dir = os.environ["MARKER_DIR"]
 
 # Fault injection: on the first deploy of the designated pod, die by
-# SIGKILL (a real kill, exit code -9) before touching the store.
+# SIGKILL (a real kill, exit code -9) before touching the store. Each
+# worker marks itself, then waits for its sibling's mark before it dies:
+# a sibling torn down by the pod's restart before it marked would die on
+# the relaunch instead, after this worker had joined the barrier, and that
+# stale arrival would leave the relaunched pod's barrier one short forever.
 if os.environ.get("FAIL_NODE") == node:
-    marker = os.path.join(
-        marker_dir, "ran_%s_%s" % (node, os.environ["PADDLE_LOCAL_RANK"]))
+    local = int(os.environ["PADDLE_LOCAL_RANK"])
+    marker = os.path.join(marker_dir, "ran_%s_%d" % (node, local))
+    sibling = os.path.join(marker_dir, "ran_%s_%d" % (node, 1 - local))
     if not os.path.exists(marker):
         open(marker, "w").close()
+        deadline = time.monotonic() + 300
+        while not os.path.exists(sibling) and time.monotonic() < deadline:
+            time.sleep(0.05)
         os.kill(os.getpid(), signal.SIGKILL)
 
 # env contract

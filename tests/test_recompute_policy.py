@@ -274,7 +274,8 @@ def test_plan_is_recorded_once_a_lowered_block(family, policy,
     assert {e["policy"] for e in events} == {policy}
     assert {e["names"] for e in events} == {{
         "full": "",
-        "flash_saveable": "flash_out,flash_lse,gdn_out,gdn_states"}[policy]}
+        "flash_saveable": "flash_out,flash_lse,gdn_out,gdn_states,ssd_out,"
+                          "ssd_states"}[policy]}
     # one flash call or delta rule a block, two values named in it, kept
     # or not
     assert [e["named_values"] for e in events] == [2] * len(blocks)
